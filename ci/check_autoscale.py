@@ -40,6 +40,10 @@ import threading
 import time
 
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
+# phase 4 compares a cold compile with a prewarmed start: give this run a
+# compile cache of its own so an earlier run's entries never warm "cold"
+os.environ["JAX_COMPILATION_CACHE_DIR"] = tempfile.mkdtemp(
+    prefix="mxtpu_autoscale_ci_cache_")
 os.environ["MXTPU_PS_HEARTBEAT"] = "0"
 os.environ["MXTPU_PS_LOCAL"] = "0"       # the drill is about the wire
 os.environ["MXTPU_PS_RETRIES"] = "2"

@@ -7,8 +7,8 @@
 #   ci/run_ci.sh nightly   - full suite + example sweep + graft entry
 #
 # Env: JAX_PLATFORMS=cpu is forced for test tiers (tests/conftest.py
-# re-asserts it); the TPU measurement path is tools/run_tpu_checks.py,
-# run out-of-band when the chip answers.
+# sets it); on the chip the program is checked by chip_smoke.py
+# (docs/testing.md).
 set -euo pipefail
 cd "$(dirname "$0")/.."
 

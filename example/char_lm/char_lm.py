@@ -137,7 +137,7 @@ def main(argv=None):
     it = mx.io.NDArrayIter(feed, {"softmax_label": Y},
                            batch_size=args.batch_size, shuffle=True)
     mod = mx.mod.Module(train_symbol(D, args.heads, args.layers, T),
-                        context=mx.cpu(), data_names=sorted(feed),
+                        data_names=sorted(feed),
                         label_names=["softmax_label"])
     import contextlib
     train_scope = contextlib.nullcontext()

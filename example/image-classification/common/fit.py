@@ -170,6 +170,8 @@ def fit(args, network, data_loader, **kwargs):
     args : parsed CLI args
     network : Symbol (engine=module) or Gluon block (engine=sharded)
     data_loader : fn(args, kv) -> (train_iter, val_iter)
+
+    Returns the trained Module (None for the sharded engine / test-io).
     """
     kv = mx.kvstore.create(args.kv_store)
     if args.gc_type != "none":
@@ -178,6 +180,8 @@ def fit(args, network, data_loader, **kwargs):
     head = "%(asctime)-15s Node[" + str(kv.rank) + "] %(message)s"
     logging.basicConfig(level=logging.DEBUG, format=head)
     logging.info("start with arguments %s", args)
+    logging.getLogger("jax").setLevel(logging.INFO)  # keep DEBUG for our own lines
+    logging.info("device: %s", mx.context.describe_device())
 
     train, val = data_loader(args, kv)
     if args.test_io:
@@ -197,7 +201,7 @@ def fit(args, network, data_loader, **kwargs):
 
     checkpoint = _save_model(args, kv.rank)
     lr, lr_scheduler = _get_lr_scheduler(args, kv)
-    model = mx.mod.Module(context=mx.cpu(), symbol=network)
+    model = mx.mod.Module(symbol=network)
 
     optimizer_params = {"learning_rate": lr, "wd": args.wd,
                         "lr_scheduler": lr_scheduler,
@@ -228,6 +232,7 @@ def fit(args, network, data_loader, **kwargs):
               epoch_end_callback=checkpoint,
               allow_missing=True,
               monitor=monitor)
+    return model
 
 
 # -- TPU-first engine ------------------------------------------------------

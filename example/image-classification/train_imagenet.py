@@ -10,18 +10,19 @@ throughput mode the reference also ships.
 from __future__ import annotations
 
 import argparse
-import logging
 import os
 import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", ".."))
 sys.path.insert(0, os.path.dirname(__file__))
 
-logging.basicConfig(level=logging.DEBUG)
-
 from common import data, fit  # noqa: E402
 
-if __name__ == "__main__":
+
+def main(argv=None, **fit_kwargs):
+    """Parse ``argv`` (default: the command line) and train; returns the
+    trained Module (or None for ``--engine sharded`` / ``--test-io``).
+    ``fit_kwargs`` reach ``common.fit.fit`` (e.g. ``batch_end_callback``)."""
     parser = argparse.ArgumentParser(
         description="train imagenet-1k",
         formatter_class=argparse.ArgumentDefaultsHelpFormatter)
@@ -40,7 +41,7 @@ if __name__ == "__main__":
         lr_step_epochs="30,60",
         dtype="float32",
     )
-    args = parser.parse_args()
+    args = parser.parse_args(argv)
 
     from importlib import import_module
     if args.engine == "sharded":
@@ -51,4 +52,8 @@ if __name__ == "__main__":
         net = import_module("symbols." + args.network).get_symbol(
             **vars(args))
 
-    fit.fit(args, net, data.get_rec_iter)
+    return fit.fit(args, net, data.get_rec_iter, **fit_kwargs)
+
+
+if __name__ == "__main__":
+    main()

@@ -120,8 +120,7 @@ def train(mesh=None, rules=None, epochs=6):
         feed["vc%d" % li] = np.zeros((len(X), T, D), "f")
     it = mx.io.NDArrayIter(feed, {"softmax_label": Y}, batch_size=32,
                            shuffle=True)
-    mod = mx.mod.Module(build_model(T), context=mx.cpu(),
-                        data_names=sorted(feed),
+    mod = mx.mod.Module(build_model(T), data_names=sorted(feed),
                         label_names=["softmax_label"])
     if mesh is not None:
         mod.set_sharding(mesh, rules)

@@ -13,11 +13,24 @@ __version__ = "0.1.0"
 
 import os as _os
 
-if _os.environ.get("JAX_PLATFORMS"):
-    # honour the env var even when a sitecustomize has already pinned the
-    # platform list via jax.config (the env var must win for users)
+
+
+def _place_compile_cache():
+    # The one place the persistent compile cache is set. A directory given
+    # from outside (JAX_COMPILATION_CACHE_DIR) is jax's to read and is left
+    # alone; otherwise the cache lives at a FIXED path in the checkout —
+    # the path is part of the cache key, so a directory that moves (a
+    # mkdtemp) never hits twice.
+    if _os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        return
     import jax as _jax
-    _jax.config.update("jax_platforms", _os.environ["JAX_PLATFORMS"])
+    _jax.config.update("jax_compilation_cache_dir", _os.path.join(
+        _os.path.dirname(_os.path.dirname(_os.path.abspath(__file__))),
+        ".jax_cache"))
+
+
+_place_compile_cache()
+
 
 def _join_process_group():
     # launched by tools/launch.py: join the process group NOW, before any

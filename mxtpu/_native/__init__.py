@@ -1,8 +1,10 @@
 """ctypes bindings for the native IO library (libmxtpu_io.so).
 
-The native layer is optional: mxtpu auto-builds it with make on first
-import when a toolchain is present, and every consumer has a pure-Python
-fallback. ``available()`` reports whether the .so is loaded.
+The native layer is optional: on first use mxtpu runs ``make`` for it
+when a toolchain is present — every time, so make's own mtime check
+rebuilds a git-ignored binary that is older than its source instead of
+loading it — and every consumer has a pure-Python fallback.
+``available()`` reports whether the .so is loaded.
 """
 from __future__ import annotations
 
@@ -17,8 +19,8 @@ _lib = None
 
 def _try_build():
     try:
-        subprocess.run(["make", "-C", _DIR, "-s"], check=True,
-                       capture_output=True, timeout=120)
+        subprocess.run(["make", "-C", _DIR, "-s", os.path.basename(_SO)],
+                       check=True, capture_output=True, timeout=120)
         return True
     except Exception:
         return False
@@ -28,8 +30,7 @@ def _load():
     global _lib
     if _lib is not None:
         return _lib
-    if not os.path.exists(_SO) and \
-            os.environ.get("MXTPU_NO_NATIVE_BUILD", "0") != "1":
+    if os.environ.get("MXTPU_NO_NATIVE_BUILD", "0") != "1":
         _try_build()
     if not os.path.exists(_SO):
         return None

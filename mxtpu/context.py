@@ -3,7 +3,13 @@
 Capability parity with ``include/mxnet/base.h:142-168`` (Context: kCPU/kGPU/
 kCPUPinned/kCPUShared) re-designed for TPU: a Context names a JAX device.
 ``tpu`` is the first-class accelerator type; ``gpu`` is accepted as an alias
-for the default accelerator so reference-written scripts keep running.
+for it so reference-written scripts keep running.
+
+The one rule for where arrays go: with no context argument, everything
+lives on device 0 of JAX's default backend — the TPU on a machine that has
+one, the CPU under ``JAX_PLATFORMS=cpu``. ``mx.cpu()`` is for what is meant
+to live on the host; ``mx.tpu(i)``/``mx.gpu(i)`` name a chip and raise when
+there is none. Nothing here substitutes one kind of device for another.
 
 Unlike MXNet there is no per-device stream/engine pair to manage: XLA owns
 scheduling. A Context resolves lazily to a ``jax.Device`` so that importing
@@ -15,7 +21,8 @@ import threading
 
 import jax
 
-__all__ = ["Context", "cpu", "gpu", "tpu", "current_context", "num_gpus", "num_tpus"]
+__all__ = ["Context", "cpu", "gpu", "tpu", "current_context", "num_gpus",
+           "num_tpus", "describe_device"]
 
 
 class Context:
@@ -57,17 +64,14 @@ class Context:
 
     # -- jax resolution ---------------------------------------------------
     def jax_device(self):
-        """Resolve to a concrete jax.Device (lazy; raises if out of range)."""
+        """Resolve to a concrete jax.Device (lazy; raises when the process
+        has no device of this type)."""
         devs = _devices_for(self.device_type)
-        if not devs:
-            raise RuntimeError("no %s devices available" % self.device_type)
         return devs[self.device_id % len(devs)]
 
     # -- scope protocol (with mx.Context(...):) ---------------------------
     def __enter__(self):
-        if not hasattr(Context._default_ctx, "value"):
-            Context._default_ctx.value = Context("cpu", 0)
-        self._old_ctx = Context._default_ctx.value
+        self._old_ctx = Context.default_ctx()
         Context._default_ctx.value = self
         return self
 
@@ -76,32 +80,41 @@ class Context:
 
     @classmethod
     def default_ctx(cls):
+        """The thread's default context: device 0 of JAX's default
+        backend until a ``with ctx:`` scope says otherwise."""
         if not hasattr(cls._default_ctx, "value"):
-            cls._default_ctx.value = Context("cpu", 0)
+            cls._default_ctx.value = Context(
+                "tpu" if jax.default_backend() == "tpu" else "cpu", 0)
         return cls._default_ctx.value
 
 
 def _devices_for(device_type):
-    """Best-effort mapping from a device-type string to jax devices.
+    """The jax devices a device-type string names; raises when the
+    process has none.
 
     Uses *local* devices: in a multi-process (jax.distributed) run,
     jax.devices() lists every process's devices and only this process's
     are addressable — a Context must never resolve to a peer's device
     (caught by tests/nightly/dist_worker.py on rank 1)."""
-    if device_type in ("cpu", "cpu_pinned", "cpu_shared"):
-        try:
-            return jax.local_devices(backend="cpu")
-        except RuntimeError:
-            # cpu backend unavailable under some platform pinnings; fall back
-            # to the default backend so code still runs.
-            return jax.local_devices()
-    # accelerator types: tpu preferred, then whatever the default backend is.
+    backend = "cpu" if device_type.startswith("cpu") else "tpu"
     try:
-        return jax.local_devices(backend="tpu")
-    except RuntimeError:
-        pass
-    devs = jax.local_devices()
-    return [d for d in devs if d.platform != "cpu"] or devs
+        return jax.local_devices(backend=backend)
+    except RuntimeError as e:
+        raise RuntimeError(
+            "mx.%s(): this process has no %s backend (JAX_PLATFORMS=%r, "
+            "default backend %r): %s" % (
+                device_type, backend, jax.config.jax_platforms,
+                jax.default_backend(), e)) from None
+
+
+def describe_device(ctx=None):
+    """One line naming where ``ctx`` (default: the current context)
+    puts arrays — what the entry points log once at start-up."""
+    ctx = ctx or current_context()
+    dev = ctx.jax_device()
+    return "%s: platform %s, device_kind %r, %d local device(s)" % (
+        ctx, dev.platform, dev.device_kind,
+        len(jax.local_devices(backend=dev.platform)))
 
 
 def cpu(device_id=0):
@@ -110,7 +123,7 @@ def cpu(device_id=0):
 
 
 def gpu(device_id=0):
-    """Alias for the default accelerator (API parity with mx.gpu)."""
+    """Alias for :func:`tpu` (API parity with mx.gpu)."""
     return Context("gpu", device_id)
 
 
@@ -124,8 +137,10 @@ def num_gpus():
 
 
 def num_tpus():
-    devs = _devices_for("tpu")
-    return len([d for d in devs if d.platform != "cpu"])
+    try:
+        return len(_devices_for("tpu"))
+    except RuntimeError:
+        return 0
 
 
 def current_context():

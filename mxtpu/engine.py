@@ -32,10 +32,7 @@ _BULK_SIZE = int(os.environ.get("MXNET_EXEC_BULK_EXEC_MAX_NODE_TRAIN", "15"))
 
 def waitall():
     """Block until all async device work completes (Engine::WaitForAll)."""
-    try:
-        jax.effects_barrier()
-    except (AttributeError, RuntimeError):
-        pass   # older jax without effects_barrier / no effects pending
+    jax.effects_barrier()
     for d in jax.live_arrays():
         try:
             d.block_until_ready()

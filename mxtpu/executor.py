@@ -11,6 +11,7 @@ explicit PRNG-key input refreshed per forward.
 from __future__ import annotations
 
 import functools
+import logging
 
 import numpy as _np
 import jax
@@ -482,9 +483,14 @@ class Executor:
             shape = tuple(self.arg_dict[n].shape)
             if n in batch_set:
                 dsize = mesh.axis_size(AXIS_DATA)
-                out.append(mesh.batch_sharding()
-                           if shape and dsize > 1
-                           and shape[0] % dsize == 0 else repl)
+                split = bool(shape) and dsize > 1 and shape[0] % dsize == 0
+                if not split and mesh.num_devices > 1:
+                    logging.warning(
+                        "mesh %s: batch input %r %s is replicated, so all "
+                        "%d devices compute the whole batch (needs a "
+                        "'%s' axis whose size divides dim 0)",
+                        mesh.shape, n, shape, mesh.num_devices, AXIS_DATA)
+                out.append(mesh.batch_sharding() if split else repl)
             elif rules is not None:
                 out.append(rules.sharding_for(mesh, n, shape))
             else:

@@ -45,13 +45,9 @@ def auto_layout_enabled(default=None):
 
 
 def auto_format():
-    """The AUTO-layout in/out sharding marker, across jax spellings."""
-    try:        # jax >= 0.5: Format wraps the tiling Layout
-        from jax.experimental.layout import Format, Layout
-        return Format(Layout.AUTO)
-    except ImportError:  # 0.4.x spelling of the same
-        from jax.experimental.layout import DeviceLocalLayout, Layout
-        return Layout(DeviceLocalLayout.AUTO)
+    """The AUTO-layout in/out sharding marker."""
+    from jax.experimental.layout import Format, Layout
+    return Format(Layout.AUTO)
 
 
 class AutoLayoutStep:
@@ -107,9 +103,7 @@ class AutoLayoutStep:
         # first one's outputs carry, and with donate=False the step's
         # outputs never adopt the input formats at all — both used to
         # raise layout-mismatch on the second call.
-        fmts = (self._compiled.input_formats    # jax >= 0.5
-                if hasattr(self._compiled, "input_formats")
-                else self._compiled.input_layouts)[0]
+        fmts = self._compiled.input_formats[0]
         args = list(args)
         for i in self._state_argnums:
             args[i] = jax.device_put(args[i], fmts[i])
@@ -153,7 +147,10 @@ class MeshStep:
         return jax.device_put(val, sh)
 
     def lower(self, *args):  # compiled_step() parity with plain jit
-        return self._jit.lower(*args)
+        # abstract avals: the jit's in_shardings place every argument,
+        # wherever the caller's concrete arrays happen to sit
+        return self._jit.lower(*jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), args))
 
     def __call__(self, *args):
         args = list(args)

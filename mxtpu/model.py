@@ -41,7 +41,13 @@ def _create_kvstore(kvstore, num_device, arg_params):
     if kvstore is None:
         kv = None
     elif isinstance(kvstore, kvs.KVStore):
-        kv = kvstore
+        # a single-process store over one device with no compression set
+        # has nothing to reduce: same as the string form below, so the
+        # Module step stays fused (example/image-classification passes
+        # the instance it read rank/num_workers from)
+        plain = type(kvstore) is kvs.KVStore \
+            and kvstore.gradient_compression is None
+        kv = None if num_device == 1 and plain else kvstore
     elif isinstance(kvstore, str):
         if num_device == 1 and "dist" not in kvstore:
             kv = None

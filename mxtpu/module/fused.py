@@ -63,7 +63,7 @@ verdict in-program: an overflow step is skipped (local modes: every
 donated buffer held at its pre-step value; dist mode: zero gradients
 ship, a server no-op) with the skip count readable via
 ``FusedGroupState.amp_overflow_skips()``. AMP-ineligible setups (non-
-fp32 parameters) log their reason once at debug level and keep the
+fp32 parameters) log their reason once at warning level and keep the
 fp32 fused path — never a silent wrong-dtype step.
 
 ``MXTPU_AUTO_LAYOUT=1`` (shared with ShardedTrainer via
@@ -93,7 +93,7 @@ Escape hatch: anything the one-program contract can't honor — a
 sparse parameters off the server-managed dist path, multi-context
 groups, ``inputs_need_grad`` — falls
 back to the eager path (warning once for monitor / custom updaters;
-every silent fallback logs its reason once at debug level, see
+every silent fallback logs its reason once at warning level, see
 ``_fused_eligible``). ``MXTPU_MODULE_FUSED=0`` disables the whole
 mechanism (``docs/env_vars.md``).
 """
@@ -787,6 +787,59 @@ class FusedModuleTrainer:
 
         return tuple(fix(t) for t in state_trees)
 
+    def _local_program(self, data_batch):
+        """The local-mode program for ``data_batch``'s signature and the
+        store arguments it runs on: ``(fn, cache_hit, states_nd,
+        (train_vals, state_trees, aux_vals, other_vals))``. Loads the
+        batch into the executor; touches no step counter."""
+        mod = self._module
+        fs = self._group
+        exec_group = mod._exec_group
+        exec_ = exec_group.execs[0]
+        key = (self._shape_sig(data_batch.data),
+               self._shape_sig(data_batch.label), fs.metric_key)
+        metric_fn = fs.metric_fn if fs.metric_key is not None else None
+        # state trees are gathered BEFORE the program build: the mesh
+        # plan places optimizer-state leaves by their actual shapes
+        train_vals = tuple(exec_.arg_dict[n]._data
+                           for n in self._train_names)
+        states_nd = [fs.updater.ensure_state(slot, exec_.arg_dict[name])
+                     for slot, name in zip(self._opt_slots,
+                                           self._train_names)]
+        state_trees = self._dedupe_donated(
+            train_vals, tuple(state_to_tree(s) for s in states_nd))
+        entry, hit = self._cache.get(
+            key, lambda: exec_.make_fused_train_step(
+                self._train_names, fs.optimizer, self._opt_slots,
+                metric_fn=metric_fn,
+                compute_dtype=fs.compute_dtype,
+                loss_scale=fs.loss_scale,
+                cast_exclude=tuple(mod._label_names),
+                auto_layout=fs.auto_layout,
+                mesh=fs.mesh, rules=fs.rules,
+                state_trees=state_trees,
+                batch_names=self._batch_names()))
+        fn, other_names = entry
+        exec_group.load_batch(data_batch)
+        aux_vals = tuple(exec_.aux_dict[n]._data for n in exec_._aux_names)
+        other_vals = tuple(exec_.arg_dict[n]._data for n in other_names)
+        if fs.metric_acc is None:
+            fs.metric_acc = fs._zero_acc()
+        return fn, hit, states_nd, (train_vals, state_trees, aux_vals,
+                                    other_vals)
+
+    def compiled_step(self, data_batch):
+        """The compiled local-mode train step for ``data_batch``'s
+        signature — ``as_text()``, ``cost_analysis()``,
+        ``input_shardings`` (``ShardedTrainer.compiled_step`` parity).
+        Runs nothing and advances nothing; costs one compile unless the
+        persistent compile cache already holds the program."""
+        fs = self._group
+        fn, _hit, _states, store_args = self._local_program(data_batch)
+        key_dev, t_dev, lr_dev = fs.device_state()
+        return fn.lower(*store_args, key_dev, t_dev, lr_dev,
+                        fs.metric_acc).compile()
+
     def step(self, data_batch):
         """Run one fused forward+backward[+update][+metric] step.
         Returns False (after disabling, where appropriate) when the
@@ -834,35 +887,9 @@ class FusedModuleTrainer:
 
         fs.note_step()
         self._begin_step_trace()
-        key = (self._shape_sig(data_batch.data),
-               self._shape_sig(data_batch.label), fs.metric_key)
-        metric_fn = fs.metric_fn if fs.metric_key is not None else None
-        # state trees are gathered BEFORE the program build: the mesh
-        # plan places optimizer-state leaves by their actual shapes
-        train_vals = tuple(exec_.arg_dict[n]._data
-                           for n in self._train_names)
-        states_nd = [fs.updater.ensure_state(slot, exec_.arg_dict[name])
-                     for slot, name in zip(self._opt_slots,
-                                           self._train_names)]
-        state_trees = self._dedupe_donated(
-            train_vals, tuple(state_to_tree(s) for s in states_nd))
-        entry, hit = self._cache.get(
-            key, lambda: exec_.make_fused_train_step(
-                self._train_names, fs.optimizer, self._opt_slots,
-                metric_fn=metric_fn,
-                compute_dtype=fs.compute_dtype,
-                loss_scale=fs.loss_scale,
-                cast_exclude=tuple(mod._label_names),
-                auto_layout=fs.auto_layout,
-                mesh=fs.mesh, rules=fs.rules,
-                state_trees=state_trees,
-                batch_names=self._batch_names()))
+        fn, hit, states_nd, store_args = self._local_program(data_batch)
         fs.stats["cache_hits" if hit else "compiles"] += 1
-        fn, other_names = entry
-
-        exec_group.load_batch(data_batch)
-        aux_vals = tuple(exec_.aux_dict[n]._data for n in exec_._aux_names)
-        other_vals = tuple(exec_.arg_dict[n]._data for n in other_names)
+        train_vals, state_trees, aux_vals, other_vals = store_args
         key_dev, t_dev, _ = fs.device_state()
         if fs.optimizer.num_update > fs.num_update:
             # eager update() calls interleaved with fused steps (mixed
@@ -873,8 +900,6 @@ class FusedModuleTrainer:
                 _np.asarray(fs.num_update, _np.int32), fs.scalar_target())
         fs.num_update += 1
         lr_dev = fs.refresh_lr()
-        if fs.metric_acc is None:
-            fs.metric_acc = fs._zero_acc()
 
         (new_vals, new_states, new_aux, outs, new_key, new_t,
          new_acc) = fn(train_vals, state_trees, aux_vals, other_vals,
@@ -1153,7 +1178,7 @@ def _fused_eligible(module):
     optimizer), ``'dist'`` (server-side update via the kvstore),
     ``'dist_local'`` (kvstore-merged gradients + fused local apply) or
     ``None`` with the human-readable fallback reason — logged once at
-    debug level so fallbacks are diagnosable instead of silent."""
+    warning level so fallbacks are diagnosable instead of silent."""
     from ..ndarray.sparse import RowSparseNDArray, CompactRowSparseNDArray
     if not _module_fused_enabled():
         return None, "MXTPU_MODULE_FUSED=0"
@@ -1220,13 +1245,13 @@ def _fused_eligible(module):
 
 
 def _log_fallback(module, reason):
-    """One-shot debug log naming why the fused path disengaged (the
-    diagnosable half of the silent-fallback contract)."""
+    """One-shot warning naming why the fused path disengaged: the
+    eager per-parameter loop costs speed, so the drop is never silent."""
     if getattr(module, "_fused_fallback_logged", None) == reason:
         return
     module._fused_fallback_logged = reason
     logger = getattr(module, "logger", None) or logging
-    logger.debug(
+    logger.warning(
         "Module fused train step not engaged: %s — eager path "
         "(eligibility matrix: docs/perf_analysis.md "
         "'Distributed Module fast path')", reason)
@@ -1235,7 +1260,7 @@ def _log_fallback(module, reason):
 def _amp_eligible(module):
     """The AMP-mode eligibility predicate (``MXTPU_AMP=bf16``): returns
     ``(amp, reason)``. An ineligible combination NAMES its reason —
-    logged once at debug level, like the PR-10 fallback matrix — and
+    logged once at warning level, like the PR-10 fallback matrix — and
     keeps the fp32 fused path: never a silent wrong-dtype step. The
     custom-updater/monitor outs are handled upstream (they leave the
     fused path entirely)."""
@@ -1255,13 +1280,13 @@ def _amp_eligible(module):
 
 
 def _log_amp_fallback(module, reason):
-    """One-shot debug log naming why AMP stayed off while the fused
+    """One-shot warning naming why AMP stayed off while the fused
     path engaged (the wrong-dtype half of the fallback contract)."""
     if getattr(module, "_amp_fallback_logged", None) == reason:
         return
     module._amp_fallback_logged = reason
     logger = getattr(module, "logger", None) or logging
-    logger.debug("Module AMP mode not engaged: %s — fp32 fused step "
+    logger.warning("Module AMP mode not engaged: %s — fp32 fused step "
                  "(docs/perf_analysis.md 'Mixed precision')", reason)
 
 
@@ -1284,7 +1309,7 @@ def maybe_create(module):
         group.set_mesh(mesh, rules)
     elif mesh_reason is not None:
         logger = getattr(module, "logger", None) or logging
-        logger.debug("Module mesh sharding not engaged: %s — "
+        logger.warning("Module mesh sharding not engaged: %s — "
                      "single-device fused step (docs/sharding.md)",
                      mesh_reason)
     if mode != "local":

@@ -26,7 +26,7 @@ push+pull on the store's worker pool under a bounded-inflight window
 (``MXTPU_MODULE_PUSH_INFLIGHT``); the default ``sync`` matches the
 eager dist loop bit-for-bit. Monitors, custom updaters, sparse
 parameters, ``inputs_need_grad`` and multi-context groups still fall
-back to the eager path, logging the reason once at debug level
+back to the eager path, logging the reason once at warning level
 (``fused._fused_eligible``).
 
 Mixed precision (``MXTPU_AMP=bf16``, ISSUE 12): a MODE of the fused
@@ -36,7 +36,7 @@ cast-in/cast-out happens inside the one program, gradients apply in
 fp32, and on the dist modes the emitted gradients ship bf16 (half the
 ``pushpull`` wire bytes; the server's fp32 master table upcasts on
 apply). ``MXTPU_AMP_LOSS_SCALE`` adds an in-program overflow skip.
-AMP-ineligible setups (non-fp32 params) log once at debug level and
+AMP-ineligible setups (non-fp32 params) log once at warning level and
 keep the fp32 fused step (``module/fused.py`` docstring, "Mixed
 precision" in docs/perf_analysis.md).
 """

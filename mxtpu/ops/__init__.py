@@ -23,7 +23,7 @@ from . import extra_ops      # noqa: F401
 
 @register("_contrib_flash_attention", aliases=("flash_attention",))
 def _flash_attention_op(q, k, v, causal=False, scale=None, q_offset=0,
-                        k_offset=0, block_q=512, block_k=1024):
+                        k_offset=0, block_q=None, block_k=None):
     """Pallas flash attention (see ops/pallas_attention.py). Lazy import:
     pallas/mosaic cost ~2s, which `import mxtpu` must not pay."""
     from .pallas_attention import flash_attention

@@ -28,8 +28,8 @@ def _nhwc_enabled():
     set, each conv/pool transposes NCHW->NHWC at entry and back at exit.
     Adjacent pairs cancel in XLA's algebraic simplifier (and elementwise
     ops commute through), so a conv-net chain effectively runs NHWC end to
-    end while the public API stays NCHW (MXNet default). Measured by
-    tools/run_tpu_checks.py bench variants; read at trace time."""
+    end while the public API stays NCHW (MXNet default). Read at trace
+    time."""
     return os.environ.get("MXTPU_CONV_LAYOUT", "").upper() == "NHWC"
 
 # ---------------------------------------------------------------------------

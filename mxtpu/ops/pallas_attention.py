@@ -20,21 +20,10 @@ it to hand-written Pallas TPU kernels:
   ``mxtpu.parallel.ring_attention`` (impl="flash") calls;
 * fully-masked tiles (above the causal diagonal) are skipped outright.
 
-On non-TPU backends the same kernels run through the Pallas interpreter
-(tests), so numerics are identical everywhere. Measured on a real
-v5e (the ``flash_attention`` stage of ``tools/run_tpu_checks.py``,
-artifact ``tpu_checks_report.json``, 2026-08-01 window; honest
-difference-timed host-fetch sync): 8k causal bf16, B=1 H=8, best block
-sizes (1024, 1024) —
-
-* d=64:  forward 1.46 ms vs 277.9 ms for the einsum+softmax XLA path
-  (which materializes the 8192^2 score matrix); fwd+bwd 5.05 ms.
-* d=128: forward 1.60 ms vs 225.2 ms XLA; fwd+bwd 5.08 ms.
-
-That forward lands at ~47 (d64) / ~86 (d128) TFLOP/s of attention
-FLOPs — the XLA ratio is large because the naive path is HBM-thrashing
-at this length, not because XLA is broken; the kernel's own absolute
-rate is the number that matters.
+Lowered for TPU the kernels compile through Mosaic; lowered for any
+other platform the same kernels run through the Pallas interpreter
+(tests), so numerics are identical everywhere (``pallas_util``). No
+timing of these kernels has been taken on the current machine.
 
 Pallas itself is imported lazily on first use — `import mxtpu` stays
 cheap; the op registry registration in ops/__init__ binds a thin
@@ -46,6 +35,9 @@ import functools
 
 import jax
 import jax.numpy as jnp
+
+from ..base import MXNetError
+from .pallas_util import SCOPED_VMEM_LIMIT
 
 __all__ = ["flash_attention", "flash_attention_with_lse",
            "flash_attention_reference"]
@@ -60,9 +52,7 @@ def _kernels():
     imports cost ~2s, which `import mxtpu` must not pay)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
-
-    def interpret():
-        return jax.default_backend() != "tpu"
+    from .pallas_util import per_platform
 
     def vspec(shape, index_map):
         return pl.BlockSpec(shape, index_map, memory_space=pltpu.VMEM)
@@ -133,13 +123,15 @@ def _kernels():
             lse_ref[0] = jnp.where(l_ref[:] == 0.0, _NEG,
                                    m_ref[:] + jnp.log(l_safe))
 
+    @functools.partial(jax.jit, static_argnums=(4, 5, 6))
     def fwd(q, k, v, offs, causal, block_q, block_k):
         bh, tq, d = q.shape
         tk = k.shape[1]
         nq, nk = tq // block_q, tk // block_k
         kern = functools.partial(fwd_kernel, causal=causal,
                                  block_q=block_q, block_k=block_k, nk=nk)
-        return pl.pallas_call(
+        return per_platform(functools.partial(
+            pl.pallas_call,
             kern,
             grid=(bh, nq, nk),
             in_specs=[
@@ -161,8 +153,7 @@ def _kernels():
                 pltpu.VMEM((block_q, 1), jnp.float32),
                 pltpu.VMEM((block_q, 1), jnp.float32),
             ],
-            interpret=interpret(),
-        )(offs, q, k, v)
+        ), offs, q, k, v)
 
     # -- backward -----------------------------------------------------------
     # Gradient w.r.t. the scaled scores s̃: dL/ds̃ = p*(dp - delta + dlse)
@@ -225,6 +216,7 @@ def _kernels():
             dk_ref[0] = dk_acc[:].astype(dk_ref.dtype)
             dv_ref[0] = dv_acc[:].astype(dv_ref.dtype)
 
+    @functools.partial(jax.jit, static_argnums=(8, 9, 10))
     def bwd(q, k, v, o, lse, do, dlse, offs, causal, block_q, block_k):
         bh, tq, d = q.shape
         tk = k.shape[1]
@@ -233,7 +225,8 @@ def _kernels():
         delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
                         axis=-1, keepdims=True) - dlse
 
-        dq = pl.pallas_call(
+        dq = per_platform(functools.partial(
+            pl.pallas_call,
             functools.partial(bwd_dq_kernel, causal=causal,
                               block_q=block_q, block_k=block_k, nk=nk),
             grid=(bh, nq, nk),
@@ -249,10 +242,10 @@ def _kernels():
             out_specs=vspec((1, block_q, d), lambda b, i, j: (b, i, 0)),
             out_shape=jax.ShapeDtypeStruct((bh, tq, d), q.dtype),
             scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-            interpret=interpret(),
-        )(offs, q, k, v, do, lse, delta)
+        ), offs, q, k, v, do, lse, delta)
 
-        dk, dv = pl.pallas_call(
+        dk, dv = per_platform(functools.partial(
+            pl.pallas_call,
             functools.partial(bwd_dkv_kernel, causal=causal,
                               block_q=block_q, block_k=block_k, nq=nq),
             grid=(bh, nk, nq),
@@ -275,8 +268,7 @@ def _kernels():
             ],
             scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
                             pltpu.VMEM((block_k, d), jnp.float32)],
-            interpret=interpret(),
-        )(offs, q, k, v, do, lse, delta)
+        ), offs, q, k, v, do, lse, delta)
         return dq, dk, dv
 
     return fwd, bwd
@@ -339,8 +331,39 @@ def _flash_with_lse(q, k, v, offs, causal, block_q, block_k):
 _flash_with_lse.defvjp(_flash_fwd, _flash_bwd)
 
 
+def _max_block(d, dtype):
+    """Largest block edge whose (edge, edge) tile pair compiles inside
+    Mosaic's 16 MiB scoped-VMEM limit, forward and backward, for rows of
+    ``d`` elements of ``dtype`` — read off AOT compiles against v5e
+    (tests/test_kernels_compile_v5e.py keeps the table honest): the f32
+    score-tile temporaries cost ~10 bytes per (q, k) pair, the rest is
+    the double-buffered q/k/v/do blocks."""
+    row_bytes = d * jnp.dtype(dtype).itemsize
+    if row_bytes <= 512:        # bf16 d<=256, f32 d<=128
+        return 1024
+    if row_bytes <= 2048:       # up to f32 d=512
+        return 512
+    raise MXNetError(
+        "flash_attention: head dim %d in %s (%d bytes a row) has no block "
+        "size known to fit the %d MiB scoped-VMEM limit; use the XLA "
+        "attention path" % (d, jnp.dtype(dtype).name, row_bytes,
+                            SCOPED_VMEM_LIMIT >> 20))
+
+
 def _prep(q, k, v, causal, scale, q_offset, k_offset, block_q, block_k):
     d = q.shape[-1]
+    limit = _max_block(d, q.dtype)
+    if block_q is None:
+        block_q = min(512, limit)
+    if block_k is None:
+        block_k = limit
+    if max(block_q, block_k) > limit:
+        raise MXNetError(
+            "flash_attention: blocks (%d, %d) for head dim %d in %s exceed "
+            "the %d MiB scoped-VMEM limit (Mosaic refuses e.g. (1024, "
+            "2048) at 19-25 MiB); the largest block edge that compiles "
+            "is %d" % (block_q, block_k, d, jnp.dtype(q.dtype).name,
+                       SCOPED_VMEM_LIMIT >> 20, limit))
     if scale is None:
         scale = 1.0 / float(d) ** 0.5
     if not isinstance(scale, (int, float)):
@@ -365,7 +388,7 @@ def _prep(q, k, v, causal, scale, q_offset, k_offset, block_q, block_k):
 
 
 def flash_attention(q, k, v, causal=False, scale=None, q_offset=0,
-                    k_offset=0, block_q=512, block_k=1024):
+                    k_offset=0, block_q=None, block_k=None):
     """Flash attention via Pallas TPU kernels. q,k,v: [B, H, T, D].
 
     ``q_offset``/``k_offset`` are the global sequence positions of the
@@ -373,7 +396,9 @@ def flash_attention(q, k, v, causal=False, scale=None, q_offset=0,
     stay correct when T is a shard of a longer sequence (ring/Ulysses
     sequence parallelism). ``scale`` may also be traced. Differentiable
     (custom VJP, flash-attention-2 style recompute backward); one HBM
-    pass per tensor per kernel. Block defaults tuned on v5e.
+    pass per tensor per kernel. The default blocks are (512, 1024)
+    shrunk to what :func:`_max_block` says fits VMEM for this head dim
+    and dtype; an explicit pair over that limit raises.
     """
     q, offs, causal, block_q, block_k = _prep(q, k, v, causal, scale,
                                               q_offset, k_offset,
@@ -384,7 +409,7 @@ def flash_attention(q, k, v, causal=False, scale=None, q_offset=0,
 
 
 def flash_attention_with_lse(q, k, v, causal=False, scale=None, q_offset=0,
-                             k_offset=0, block_q=512, block_k=1024):
+                             k_offset=0, block_q=None, block_k=None):
     """Like :func:`flash_attention` but also returns the per-row
     log-sum-exp ``lse`` [B, H, T] (float32; ``-1e30`` for fully-masked
     rows). Partial attention results over disjoint K/V shards combine

@@ -12,9 +12,11 @@ Differentiation: custom VJP whose backward recomputes through the
 mathematically identical ``lax.scan`` formulation — residuals stay tiny
 (the inputs), matching the rematerialization discipline used elsewhere.
 
-Non-TPU backends run the same kernel through the Pallas interpreter, so
-tests cover it everywhere; ``ops.rnn`` routes LSTM and GRU through
-these kernels on TPU (override with ``mxtpu.ops.rnn.USE_PALLAS_RNN``).
+Lowered for any platform but TPU the same kernel runs through the Pallas
+interpreter, so tests cover it everywhere (``pallas_util``). ``ops.rnn``
+takes the ``lax.scan`` route unless ``mxtpu.ops.rnn.USE_PALLAS_RNN`` is
+set: the kernels keep the whole recurrent weight in VMEM, so they only
+hold hidden sizes whose ``wh`` fits the scoped-VMEM limit.
 """
 from __future__ import annotations
 
@@ -23,13 +25,37 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from ..base import MXNetError
+from .pallas_util import SCOPED_VMEM_LIMIT
+
 __all__ = ["lstm_scan", "gru_scan"]
+
+
+def _require_fit(cell, x_proj, *weights):
+    """The kernels keep every recurrent weight resident in VMEM (upcast
+    to f32 for the matmul) next to the double-buffered x_proj/ys blocks,
+    the f32 gates and the carries. The estimate is an upper bound checked
+    against Mosaic's own refusals for v5e in
+    tests/test_kernels_compile_v5e.py."""
+    _, N, G = x_proj.shape
+    H = weights[0].shape[0]
+    need = (4 * sum(w.size for w in weights)
+            + 2 * N * (G + H) * x_proj.dtype.itemsize
+            + 4 * N * G + 24 * N * H)
+    if need > SCOPED_VMEM_LIMIT:
+        raise MXNetError(
+            "The Pallas %s kernel at hidden size %d, batch %d needs about "
+            "%.1f MiB of VMEM; the scoped-VMEM limit is %d MiB. Use the "
+            "lax.scan route (mxtpu.ops.rnn.USE_PALLAS_RNN = False, the "
+            "default), which has no such limit."
+            % (cell, H, N, need / 2 ** 20, SCOPED_VMEM_LIMIT >> 20))
 
 
 @functools.cache
 def _fwd_call():
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
+    from .pallas_util import per_platform
 
     def kernel(xp_ref, wh_ref, h0_ref, c0_ref, ys_ref, ht_ref, ct_ref,
                h_s, c_s, *, T, H):
@@ -58,10 +84,13 @@ def _fwd_call():
             ht_ref[:] = h.astype(ht_ref.dtype)
             ct_ref[:] = c.astype(ct_ref.dtype)
 
+    @jax.jit
     def call(x_proj, h0, c0, wh_t):
         T, N, G = x_proj.shape
         H = h0.shape[-1]
-        return pl.pallas_call(
+        _require_fit("LSTM", x_proj, wh_t)
+        return per_platform(functools.partial(
+            pl.pallas_call,
             functools.partial(kernel, T=T, H=H),
             grid=(T,),
             in_specs=[
@@ -84,8 +113,7 @@ def _fwd_call():
             ],
             scratch_shapes=[pltpu.VMEM((N, H), jnp.float32),
                             pltpu.VMEM((N, H), jnp.float32)],
-            interpret=jax.default_backend() != "tpu",
-        )(x_proj, wh_t, h0, c0)
+        ), x_proj, wh_t, h0, c0)
 
     return call
 
@@ -94,6 +122,7 @@ def _fwd_call():
 def _gru_fwd_call():
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
+    from .pallas_util import per_platform
 
     def kernel(xp_ref, whrz_ref, whn_ref, bhn_ref, h0_ref, ys_ref, ht_ref,
                h_s, *, T, H):
@@ -122,10 +151,13 @@ def _gru_fwd_call():
         def _fin():
             ht_ref[:] = h.astype(ht_ref.dtype)
 
+    @jax.jit
     def call(x_proj, h0, whrz_t, whn_t, bhn):
         T, N, G = x_proj.shape
         H = h0.shape[-1]
-        return pl.pallas_call(
+        _require_fit("GRU", x_proj, whrz_t, whn_t)
+        return per_platform(functools.partial(
+            pl.pallas_call,
             functools.partial(kernel, T=T, H=H),
             grid=(T,),
             in_specs=[
@@ -146,8 +178,7 @@ def _gru_fwd_call():
                 jax.ShapeDtypeStruct((N, H), h0.dtype),
             ],
             scratch_shapes=[pltpu.VMEM((N, H), jnp.float32)],
-            interpret=jax.default_backend() != "tpu",
-        )(x_proj, whrz_t, whn_t, bhn, h0)
+        ), x_proj, whrz_t, whn_t, bhn, h0)
 
     return call
 

@@ -22,20 +22,13 @@ from .registry import register, next_rng_key
 
 _GATES = {"rnn_relu": 1, "rnn_tanh": 1, "lstm": 4, "gru": 3}
 
-# LSTM/GRU time-loop backend: None = auto (Pallas kernels on TPU,
-# lax.scan elsewhere); True/False force. Read at TRACE time — set it
+# LSTM/GRU time-loop backend: lax.scan unless set to True, which routes
+# through the Pallas kernels of ops/pallas_rnn.py (they hold only hidden
+# sizes whose recurrent weight fits VMEM, and no measurement on the
+# current machine says they are faster). Read at TRACE time — set it
 # before the first forward of a model; already-jit-cached traces keep
-# whichever backend they were traced with. See ops/pallas_rnn.py.
-# (USE_PALLAS_LSTM is the historical name; both names are honored.)
-USE_PALLAS_RNN = None
-USE_PALLAS_LSTM = None
-
-
-def _pallas_lstm_enabled():
-    for flag in (USE_PALLAS_RNN, USE_PALLAS_LSTM):
-        if flag is not None:
-            return flag
-    return jax.default_backend() == "tpu"
+# whichever backend they were traced with.
+USE_PALLAS_RNN = False
 
 
 def rnn_blob_blocks(mode, input_size, state_size, num_layers, num_dir):
@@ -127,7 +120,7 @@ def _run_direction(xs, h0, c0, wi, wh, bi, bh, mode, reverse):
         # split h2h so the candidate gate sees r * (h @ Whn + bhn)
         wh_rz, wh_n = wh[:2 * H], wh[2 * H:]
         bh_rz, bh_n = bh[:2 * H], bh[2 * H:]
-        if _pallas_lstm_enabled():
+        if USE_PALLAS_RNN:
             from .pallas_rnn import gru_scan
             # fold the r/z recurrent bias into the hoisted projection
             xp = x_proj.at[:, :, :2 * H].add(bh_rz)
@@ -144,7 +137,7 @@ def _run_direction(xs, h0, c0, wi, wh, bi, bh, mode, reverse):
             n = jnp.tanh(xp[:, 2 * H:] + r * (h @ wh_n.T + bh_n))
             new_h = (1 - z) * n + z * h
             return (new_h, new_h), new_h
-    elif mode == "lstm" and _pallas_lstm_enabled():
+    elif mode == "lstm" and USE_PALLAS_RNN:
         from .pallas_rnn import lstm_scan
         ys, hT, cT = lstm_scan(x_proj + bh, h0, c0, wh.T)
         if reverse:

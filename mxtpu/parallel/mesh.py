@@ -28,47 +28,14 @@ import numpy as _np
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
-try:                                 # jax >= 0.4.35 top-level export
-    from jax import shard_map as _shard_map_impl
-except ImportError:                  # older jax: experimental home
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-
-import inspect as _inspect
-
-if "check_vma" in _inspect.signature(_shard_map_impl).parameters:
-    shard_map = _shard_map_impl
-else:
-    def shard_map(*args, **kwargs):
-        """Compat wrapper: newer jax renamed check_rep -> check_vma;
-        callers use the new spelling, old jax gets the translation."""
-        if "check_vma" in kwargs:
-            kwargs["check_rep"] = kwargs.pop("check_vma")
-        return _shard_map_impl(*args, **kwargs)
-
-# Mesh-as-context API drift (same shape as the shard_map shim above):
-# older jax only has `with mesh:` (Mesh IS the context manager), newer
-# jax adds jax.sharding.use_mesh and deprecates/removes Mesh.__enter__.
-# Callers go through use_mesh() and get whichever this jax provides.
-try:                                 # jax >= 0.5 explicit-context API
-    from jax.sharding import use_mesh as _use_mesh_impl
-except ImportError:                  # older jax: Mesh is the manager
-    _use_mesh_impl = None
+from jax import shard_map
 
 
 def use_mesh(mesh):
-    """Context manager installing ``mesh`` as the ambient mesh for
-    pjit/sharding resolution — accepts a ``Mesh`` or ``MeshContext``.
-    Prefers the classic ``with mesh:`` resource-env semantics when the
-    Mesh context manager still exists, else ``jax.sharding.use_mesh``."""
-    if isinstance(mesh, MeshContext):
-        mesh = mesh.mesh
-    if hasattr(type(mesh), "__enter__"):
-        return mesh
-    if _use_mesh_impl is not None:
-        return _use_mesh_impl(mesh)
-    raise RuntimeError(
-        "this jax version has neither Mesh.__enter__ nor "
-        "jax.sharding.use_mesh")
+    """Context manager installing ``mesh`` (a ``Mesh`` or ``MeshContext``)
+    as the ambient mesh for pjit/sharding resolution: ``with mesh:``."""
+    return mesh.mesh if isinstance(mesh, MeshContext) else mesh
+
 
 __all__ = ["AXIS_DATA", "AXIS_MODEL", "AXIS_PIPE", "AXIS_SEQ", "AXIS_EXPERT",
            "make_mesh", "MeshContext", "ShardingRules", "PartitionSpec",

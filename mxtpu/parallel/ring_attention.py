@@ -21,6 +21,7 @@ wrappers bind them to a MeshContext.
 from __future__ import annotations
 
 import functools
+import warnings
 
 import jax
 import jax.numpy as jnp
@@ -41,6 +42,21 @@ def _alibi_slopes(h, dtype=jnp.float32):
                        dtype)
 
 
+def _resolve_impl(impl, alibi, t):
+    """Settle what can be settled from static facts: "auto" stays "auto"
+    only where flash is possible (the platform then decides at
+    lowering); ALiBi has no Pallas kernel, so it takes the dense einsum
+    — said (once per call site) when the caller asked for flash."""
+    if alibi and impl == "flash":
+        warnings.warn(
+            "ring_attention: impl='flash' with alibi=True runs the dense "
+            "einsum attention (the Pallas kernels carry no ALiBi bias)",
+            RuntimeWarning, stacklevel=3)
+    if alibi or (impl == "auto" and t < 128):
+        return "xla"
+    return impl
+
+
 def local_attention(q, k, v, causal=False, scale=None, q_offset=0,
                     k_offset=0, impl="auto", alibi=False):
     """Softmax attention on local shards. q,k,v: [B, H, T, D].
@@ -48,14 +64,21 @@ def local_attention(q, k, v, causal=False, scale=None, q_offset=0,
     ``q_offset``/``k_offset`` give the global positions of the local rows
     for causal masking under sequence sharding. ``impl``: "flash" lowers
     to the Pallas flash-attention kernels (ops/pallas_attention.py),
-    "xla" is the plain einsum+softmax path, "auto" picks flash on TPU
-    for sequences long enough to tile. ``alibi=True`` subtracts the
-    per-head linear distance bias from the scores (the Pallas kernels
-    do not carry the bias, so alibi forces the xla path)."""
+    "xla" is the plain einsum+softmax path, "auto" picks flash when the
+    computation is lowered for TPU and the sequences are long enough to
+    tile. ``alibi=True`` subtracts the per-head linear distance bias
+    from the scores (the Pallas kernels do not carry the bias, so alibi
+    takes the xla path — with a warning when "flash" was asked for)."""
+    impl = _resolve_impl(impl, alibi, min(q.shape[2], k.shape[2]))
     if impl == "auto":
-        impl = ("flash" if jax.default_backend() == "tpu" and not alibi
-                and q.shape[2] >= 128 and k.shape[2] >= 128 else "xla")
-    if impl == "flash" and not alibi:
+        def run(q, k, v, q_offset, k_offset, impl):
+            return local_attention(q, k, v, causal, scale, q_offset,
+                                   k_offset, impl)
+        return lax.platform_dependent(
+            q, k, v, jnp.asarray(q_offset), jnp.asarray(k_offset),
+            tpu=functools.partial(run, impl="flash"),
+            default=functools.partial(run, impl="xla"))
+    if impl == "flash":
         from ..ops.pallas_attention import flash_attention
         return flash_attention(q, k, v, causal=causal, scale=scale,
                                q_offset=q_offset, k_offset=k_offset)
@@ -89,7 +112,8 @@ def ring_attention(q, k, v, axis_name=AXIS_SEQ, causal=False, scale=None,
     ``impl="flash"`` computes each ring step with the Pallas flash
     kernels (ops/pallas_attention.py): per-step (out, lse) pairs merge
     online via logaddexp, so the whole ring is one flash pass per K/V
-    block — "auto" picks flash on TPU for local shards >= 128 rows.
+    block — "auto" picks flash when lowered for TPU and the local shard
+    has >= 128 rows.
 
     ``alibi=True`` subtracts the per-head linear distance bias from
     every block's scores; the absolute ring positions (``my*t + i`` vs
@@ -99,10 +123,16 @@ def ring_attention(q, k, v, axis_name=AXIS_SEQ, causal=False, scale=None,
     n = lax.psum(1, axis_name)
     my = lax.axis_index(axis_name)
     b, h, t, d = q.shape
+    impl = _resolve_impl(impl, alibi, t)
     if impl == "auto":
-        impl = ("flash" if jax.default_backend() == "tpu" and t >= 128
-                and not alibi else "xla")
-    if impl == "flash" and not alibi:
+        return lax.platform_dependent(
+            q, k, v,
+            tpu=functools.partial(ring_attention, axis_name=axis_name,
+                                  causal=causal, scale=scale, impl="flash"),
+            default=functools.partial(ring_attention, axis_name=axis_name,
+                                      causal=causal, scale=scale,
+                                      impl="xla"))
+    if impl == "flash":
         return _ring_attention_flash(q, k, v, axis_name, causal, scale,
                                      n, my)
     if scale is None:

@@ -233,19 +233,23 @@ class Counter:
     def set_value(self, value):
         with self._vlock:
             self._value = value
+        self._emit_value(value)
+
+    def _emit_value(self, value):
         if is_active():
             _emit({"name": self.name, "ph": "C", "ts": _now_us(),
                    "pid": _PID, "args": {"value": value}})
 
     def increment(self, delta=1):
+        # read-modify-write under ONE hold of the lock: releasing between
+        # the read and the write loses updates under contention
         with self._vlock:
-            value = self._value + delta
-        self.set_value(value)
+            self._value += delta
+            value = self._value
+        self._emit_value(value)
 
     def decrement(self, delta=1):
-        with self._vlock:
-            value = self._value - delta
-        self.set_value(value)
+        self.increment(-delta)
 
     def __iadd__(self, v):
         self.increment(v)

@@ -78,10 +78,7 @@ class PallasModule:
         import jax
         import jax.numpy as jnp
         from jax.experimental import pallas as pl
-        try:
-            from jax.experimental.pallas import tpu as pltpu
-        except ImportError:  # pragma: no cover
-            pltpu = None
+        from jax.experimental.pallas import tpu as pltpu
         # the source executes in a namespace pre-loaded with the kernel
         # vocabulary, mirroring how NVRTC sources assume the CUDA headers
         ns = {"jax": jax, "jnp": jnp, "pl": pl, "pltpu": pltpu,
@@ -171,13 +168,14 @@ class PallasKernel:
                            for p in tensor_params]
                 return fn(*ordered, **kw)
 
-            call = jax.jit(pl.pallas_call(
+            from .ops.pallas_util import per_platform
+            call = jax.jit(functools.partial(per_platform, functools.partial(
+                pl.pallas_call,
                 shim,
                 grid=grid,
                 out_shape=[jax.ShapeDtypeStruct(d.shape, d.dtype)
                            for _, d in out_arrays],
-                interpret=jax.default_backend() != "tpu",
-            ))
+            )))
             self._cache[key] = call
         import jax.numpy as jnp
         svals = [jnp.asarray(traced[nme]).reshape(1)

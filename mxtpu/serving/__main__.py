@@ -81,6 +81,10 @@ def main():
         buckets=parse_buckets(buckets), warm=False)
     srv = ModelServer(engine, port=port,
                       model_name=os.path.basename(prefix))
+    devs = engine.store_devices()
+    print("mxtpu serving replica weight store on %s (%s): %s"
+          % (devs[0].platform, devs[0].device_kind,
+             ",".join(str(d) for d in devs)), flush=True)
 
     # the prewarm contract (docs/autoscaling.md): the FIRST replica
     # pays the cold compile and publishes its AOT program menu; every

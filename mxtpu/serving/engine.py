@@ -71,7 +71,7 @@ from jax import lax
 
 from ..base import canonical_dtype
 from ..checkpoint import weight_digest
-from ..context import cpu
+from ..context import current_context
 from ..module.fused import ProgramCache, mesh_spec
 from ..symbol import eval_graph
 from ..ops.registry import rng_scope
@@ -145,7 +145,7 @@ class InferenceEngine:
                  buckets=(1, 2, 4, 8, 16, 32), ctx=None, dtype="float32",
                  warm=True, version=0, mesh=None, rules=None):
         self._symbol = symbol
-        self._ctx = ctx if ctx is not None else cpu()
+        self._ctx = ctx if ctx is not None else current_context()
         self._dev = self._ctx.jax_device()
         self._mesh, self._rules = self._resolve_mesh(mesh, rules)
         self._buckets = parse_buckets(
@@ -269,15 +269,15 @@ class InferenceEngine:
             else self._mesh.replicated()
 
     def _abs(self, shape, dtype, sharding=None):
-        """Abstract aval for AOT lowering. Single-device mode carries
-        no placement (lowering stays device-agnostic, unchanged from
-        the pre-mesh engine); sharded mode rides the placement along —
-        ``AutoLayoutStep._abstract``'s trick one level up — so the
-        compiled program IS the SPMD partition the real calls
-        dispatch. Default placement on the mesh is replicated."""
+        """Abstract aval for AOT lowering, placement included — the
+        compiled program IS the one the real calls dispatch: on the
+        engine's own device in single-device mode (so N engines in one
+        process can each own a chip), the SPMD partition in sharded
+        mode (``AutoLayoutStep._abstract``'s trick one level up).
+        Default placement on a mesh is replicated."""
         if self._mesh is None:
-            return jax.ShapeDtypeStruct(shape, dtype)
-        if sharding is None or not hasattr(sharding, "mesh"):
+            sharding = jax.sharding.SingleDeviceSharding(self._dev)
+        elif sharding is None or not hasattr(sharding, "mesh"):
             sharding = self._mesh.replicated()
         return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
@@ -313,6 +313,14 @@ class InferenceEngine:
         if self._gen is not None:
             sig["generate"] = self.generate_spec()
         return sig
+
+    def store_devices(self):
+        """The devices that hold the stable weight store, read from the
+        arrays themselves (not from the context the engine was given)."""
+        devs = set()
+        for v in self._param_vals + self._aux_vals:
+            devs |= v.devices()
+        return sorted(devs, key=lambda d: d.id)
 
     def stats(self):
         with self._stats_lock:

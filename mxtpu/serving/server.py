@@ -854,10 +854,9 @@ class ModelServer:
                     daemon=True).start()
                 if entry.scheduler is not None:
                     threading.Thread(
-                        target=entry.scheduler.drain, kwargs={
-                            "timeout": float(msg[1]) if len(msg) > 1
-                            else 30.0},
-                        daemon=True).start()
+                        daemon=True, target=entry.scheduler.drain,
+                        kwargs={"timeout": float(msg[1])
+                                if len(msg) > 1 else 30.0}).start()
             return ("ok", {"draining": True})
         if cmd == "resume":
             # the zero-downtime hot-swap exit: drain → swap → resume

@@ -1,0 +1,152 @@
+"""AOT-compile the Pallas kernels for a TPU v5e without a device.
+
+libtpu is installed in the sandbox, so
+``jax.experimental.topologies.get_topology_desc("v5e:2x2", "tpu")`` gives a
+compile target with no chip. This is the check that found, before any chip
+time was spent, that Mosaic refuses flash-attention blocks (1024, 2048) and
+the Pallas LSTM at H >= 1024 (its 16 MiB scoped-VMEM limit) — kept so the
+next kernel change meets the compiler here first. It pins three things: the
+defaults compile, the limits the framework enforces are real, and a shape
+over the limit raises the framework's own error instead of a Mosaic dump.
+"""
+import functools
+import re
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
+
+from mxtpu.base import MXNetError
+from mxtpu.ops import pallas_attention, pallas_rnn, rnn as rnn_ops
+from mxtpu.ops.pallas_attention import flash_attention
+
+pytestmark = pytest.mark.slow
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc("v5e:2x2", "tpu")
+    except (NotImplementedError, RuntimeError) as e:
+        pytest.skip("no libtpu AOT target here: %s" % e)
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compile(fn, *avals):
+    """Lower for TPU and compile; returns the lowered text. A Mosaic
+    refusal surfaces as the compile error it is."""
+    lowered = jax.jit(fn).trace(*avals).lower(lowering_platforms=("tpu",))
+    lowered.compile()
+    return lowered.as_text()
+
+
+def _refused(fn, *avals):
+    with pytest.raises(Exception) as err:
+        _compile(fn, *avals)
+    m = re.search(r"Scoped allocation with size ([\d.]+)M and limit "
+                  r"([\d.]+)M", str(err.value))
+    assert m, str(err.value)[:500]
+    assert float(m.group(1)) > float(m.group(2)) == 16.0
+    return float(m.group(1))
+
+
+def _attn_train(**kw):
+    def f(q, k, v):
+        return jax.grad(lambda *a: flash_attention(*a, causal=True, **kw)
+                        .astype(jnp.float32).sum(), argnums=(0, 1, 2))(
+            q, k, v)
+    return f
+
+
+# -- flash attention -------------------------------------------------------------
+
+@pytest.mark.parametrize("d", [64, 128])
+def test_flash_default_blocks_compile_at_8k(v5e, d):
+    a = jax.ShapeDtypeStruct((1, 8, 8192, d), jnp.bfloat16, sharding=v5e)
+    text = _compile(_attn_train(), a, a, a)
+    assert "tpu_custom_call" in text       # Mosaic, not the interpreter
+
+
+@pytest.mark.parametrize("dtype,d", [
+    (jnp.bfloat16, 64), (jnp.bfloat16, 256), (jnp.float32, 128),
+    (jnp.float32, 256), (jnp.bfloat16, 512), (jnp.float32, 512)])
+def test_flash_largest_allowed_blocks_compile(v5e, dtype, d):
+    """``_max_block`` is a table read off this compiler: keep it true."""
+    edge = pallas_attention._max_block(d, dtype)
+    a = jax.ShapeDtypeStruct((1, 2, 8192, d), dtype, sharding=v5e)
+    _compile(_attn_train(block_q=edge, block_k=edge), a, a, a)
+
+
+def test_flash_over_limit_blocks_raise_the_frameworks_error(v5e):
+    q = jnp.zeros((1, 8, 8192, 64), jnp.bfloat16)
+    with pytest.raises(MXNetError, match="scoped-VMEM limit"):
+        flash_attention(q, q, q, causal=True, block_q=1024, block_k=2048)
+    with pytest.raises(MXNetError, match="head dim 1024"):
+        flash_attention(jnp.zeros((1, 1, 256, 1024), jnp.float32),
+                        jnp.zeros((1, 1, 256, 1024), jnp.float32),
+                        jnp.zeros((1, 1, 256, 1024), jnp.float32))
+    # and the limit is the compiler's, not ours: past the check, Mosaic
+    # refuses the same pair
+    a = jax.ShapeDtypeStruct((1, 8, 8192, 64), jnp.bfloat16, sharding=v5e)
+    offs = jax.ShapeDtypeStruct((4,), jnp.float32, sharding=v5e)
+    size = _refused(
+        lambda q, k, v, o: pallas_attention._flash_with_lse(
+            q, k, v, o, True, 1024, 2048)[0], a, a, a, offs)
+    assert 19.0 <= size <= 26.0
+
+
+# -- recurrent kernels -------------------------------------------------------------
+
+@pytest.mark.parametrize("mode,gates", [("lstm", 4), ("gru", 3)])
+def test_default_rnn_path_compiles_at_ptb_sizes(v5e, mode, gates):
+    """The default op path (lax.scan) at T=35, N=32 for the PTB small,
+    medium and large hidden sizes."""
+    assert rnn_ops.USE_PALLAS_RNN is False
+    for h in (200, 650, 1500):
+        psize = rnn_ops.rnn_param_size(mode, h, h, 1, False)
+        S = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.float32,
+                              sharding=v5e)
+        args = [S((35, 32, h)), S((psize,)), S((1, 32, h))]
+        if mode == "lstm":
+            args.append(S((1, 32, h)))
+        text = _compile(functools.partial(rnn_ops.rnn, state_size=h,
+                                          mode=mode), *args)
+        assert "tpu_custom_call" not in text
+
+
+def test_pallas_rnn_fits_or_raises(v5e):
+    def S(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=v5e)
+
+    def lstm(h, n=32):
+        return S(35, n, 4 * h), S(n, h), S(n, h), S(h, 4 * h)
+
+    def gru(h, n=32):
+        return S(35, n, 3 * h), S(n, h), S(h, 2 * h), S(h, h), S(h)
+
+    # what the estimate admits, Mosaic compiles
+    assert "tpu_custom_call" in _compile(pallas_rnn.lstm_scan, *lstm(650))
+    assert "tpu_custom_call" in _compile(pallas_rnn.lstm_scan, *lstm(896))
+    assert "tpu_custom_call" in _compile(pallas_rnn.gru_scan, *gru(1024))
+    # what it cannot hold raises the framework's error, naming the limit
+    for fn, avals in ((pallas_rnn.lstm_scan, lstm(1500)),
+                      (pallas_rnn.lstm_scan, lstm(1024)),
+                      (pallas_rnn.lstm_scan, lstm(896, n=128)),
+                      (pallas_rnn.gru_scan, gru(2048))):
+        with pytest.raises(MXNetError, match="limit is 16 MiB"):
+            jax.jit(fn).trace(*avals)
+
+
+def test_pallas_rnn_limit_is_the_compilers(v5e, monkeypatch):
+    monkeypatch.setattr(pallas_rnn, "_require_fit", lambda *a: None)
+    pallas_rnn._fwd_call.cache_clear()       # drop jit caches built above
+    try:
+        S = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.float32,
+                              sharding=v5e)
+        _refused(pallas_rnn.lstm_scan, S((35, 32, 4096)), S((32, 1024)),
+                 S((32, 1024)), S((1024, 4096)))
+    finally:
+        pallas_rnn._fwd_call.cache_clear()

@@ -145,7 +145,7 @@ def test_fused_dist_local_mode_parity(monkeypatch):
 
 def test_fused_dist_kill_switch_logs_reason(monkeypatch, caplog):
     """MXTPU_MODULE_FUSED_DIST=0 keeps kvstore modules eager, and the
-    silent fallback names its reason ONCE at debug level."""
+    silent fallback names its reason ONCE at warning level."""
     with caplog.at_level(logging.DEBUG):
         _, _, _, engaged = _dist_fit(monkeypatch, False, "sync")
     assert engaged is None
@@ -158,7 +158,7 @@ def test_fused_dist_kill_switch_logs_reason(monkeypatch, caplog):
 
 def test_fallback_reasons_are_named(monkeypatch, caplog):
     """The narrowed predicate: every silent fallback (inputs_need_grad
-    here) is diagnosable through the debug log."""
+    here) is diagnosable through the warning log."""
     monkeypatch.setenv("MXTPU_MODULE_FUSED", "1")
     x, y = _toy_problem()
     it = mx.io.NDArrayIter(x, y, batch_size=32,

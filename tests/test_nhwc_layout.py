@@ -1,6 +1,5 @@
 """MXTPU_CONV_LAYOUT=NHWC — the channels-last experiment knob must be
-bit-compatible with the default NCHW path (tools/run_tpu_checks.py
-measures its perf effect on hardware)."""
+bit-compatible with the default NCHW path."""
 import numpy as np
 import pytest
 

@@ -67,12 +67,12 @@ def test_rnn_op_pallas_path(bidirectional):
                          state_outputs=True)
 
     try:
-        rnn_ops.USE_PALLAS_LSTM = False
+        rnn_ops.USE_PALLAS_RNN = False
         ref = [o.asnumpy() for o in run()]
-        rnn_ops.USE_PALLAS_LSTM = True
+        rnn_ops.USE_PALLAS_RNN = True
         got = [o.asnumpy() for o in run()]
     finally:
-        rnn_ops.USE_PALLAS_LSTM = None
+        rnn_ops.USE_PALLAS_RNN = False
     for a, b in zip(got, ref):
         np.testing.assert_allclose(a, b, atol=1e-5, rtol=1e-5)
 
@@ -95,12 +95,12 @@ def test_gluon_lstm_layer_pallas_path():
         return out.asnumpy(), w.grad().asnumpy()
 
     try:
-        rnn_ops.USE_PALLAS_LSTM = False
+        rnn_ops.USE_PALLAS_RNN = False
         out_ref, g_ref = fwd_and_grad()
-        rnn_ops.USE_PALLAS_LSTM = True
+        rnn_ops.USE_PALLAS_RNN = True
         out_p, g_p = fwd_and_grad()
     finally:
-        rnn_ops.USE_PALLAS_LSTM = None
+        rnn_ops.USE_PALLAS_RNN = False
     np.testing.assert_allclose(out_p, out_ref, atol=1e-5, rtol=1e-5)
     np.testing.assert_allclose(g_p, g_ref, atol=1e-5, rtol=1e-5)
 
@@ -180,11 +180,11 @@ def test_rnn_op_gru_pallas_path(bidirectional):
                          bidirectional=bidirectional, state_outputs=True)
 
     try:
-        rnn_ops.USE_PALLAS_LSTM = False
+        rnn_ops.USE_PALLAS_RNN = False
         ref = [o.asnumpy() for o in run()]
-        rnn_ops.USE_PALLAS_LSTM = True
+        rnn_ops.USE_PALLAS_RNN = True
         got = [o.asnumpy() for o in run()]
     finally:
-        rnn_ops.USE_PALLAS_LSTM = None
+        rnn_ops.USE_PALLAS_RNN = False
     for a, b in zip(got, ref):
         np.testing.assert_allclose(a, b, atol=1e-5, rtol=1e-5)

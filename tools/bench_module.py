@@ -120,7 +120,7 @@ def _steady_state_rate(mx, sym, x, y, batch_size, batches, warmup,
     """img/sec of the fit() hot loop after warmup, current env."""
     it = mx.io.NDArrayIter(x, y, batch_size=batch_size,
                            label_name="softmax_label")
-    mod = mx.mod.Module(sym, context=mx.cpu())
+    mod = mx.mod.Module(sym)
     if mesh is not None:
         mod.set_sharding(mesh)
     mod.bind(it.provide_data, it.provide_label)
@@ -162,7 +162,7 @@ def _dist_rate(mx, sym, x, y, batch_size, batches, warmup):
     MXTPU_MODULE_DIST_MODE select the path)."""
     it = mx.io.NDArrayIter(x, y, batch_size=batch_size,
                            label_name="softmax_label")
-    mod = mx.mod.Module(sym, context=mx.cpu())
+    mod = mx.mod.Module(sym)
     mod.bind(it.provide_data, it.provide_label)
     mod.init_params(mx.initializer.Xavier())
     mod.init_optimizer(kvstore="dist_async", optimizer="sgd",
@@ -253,7 +253,7 @@ def _amp_dist_rate(mx, sym, x, y, batch_size, batches, warmup):
     from mxtpu import kvstore_async as ka
     it = mx.io.NDArrayIter(x, y, batch_size=batch_size,
                            label_name="softmax_label")
-    mod = mx.mod.Module(sym, context=mx.cpu())
+    mod = mx.mod.Module(sym)
     mod.bind(it.provide_data, it.provide_label)
     mod.init_params(mx.initializer.Xavier())
     saved_local = ka._LOCAL_ON
@@ -357,7 +357,7 @@ def _mesh_store_stats(mx, jax, sym, x, y, batch_size, mesh):
     params AND optimizer-state leaves."""
     it = mx.io.NDArrayIter(x, y, batch_size=batch_size,
                            label_name="softmax_label")
-    mod = mx.mod.Module(sym, context=mx.cpu())
+    mod = mx.mod.Module(sym)
     mod.set_sharding(mesh)
     mod.bind(it.provide_data, it.provide_label)
     mod.init_params(mx.initializer.Xavier())

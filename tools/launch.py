@@ -109,6 +109,27 @@ def _spawn_server(name, ps_port, base_env, args, role="primary",
     return proc
 
 
+def _child_platform(args, env, role):
+    """Env overrides that keep ONE process per chip on this host.
+
+    A chip belongs to one process at a time: a second child that reaches
+    for it fails or hangs. So at most one jax-computing child of a local
+    launch inherits the caller's platform (and may take the chip) — the
+    lone serving replica if the launch has one, else the lone worker.
+    Every other worker or replica is a CPU process. To use four chips,
+    run four engines in ONE process, each on its own ``mx.tpu(i)``
+    (docs/serving.md)."""
+    if env.get("JAX_PLATFORMS") == "cpu":
+        return {}           # every child is a CPU process already
+    slots = max(args.serve, args.serve_max or 0)
+    if role == "replica":
+        alone = slots == 1
+    else:
+        alone = slots == 0 and args.num_workers == 1 \
+            and not (args.scale or args.autoscale)
+    return {} if alone else {"JAX_PLATFORMS": "cpu"}
+
+
 def _spawn_serving_replica(idx, port, addrs, base_env, args):
     """One model-serving replica child (``python -m mxtpu.serving``).
     Every replica gets the FULL replica set in MXTPU_SERVE_ADDRS so its
@@ -122,7 +143,7 @@ def _spawn_serving_replica(idx, port, addrs, base_env, args):
     escalation as servers — SIGTERM is their graceful drain (stop
     admissions, flush in-flight batches, exit 0), so a clean launcher
     exit never drops admitted requests."""
-    env = dict(base_env, JAX_PLATFORMS="cpu",
+    env = dict(base_env, **_child_platform(args, base_env, "replica"),
                MXTPU_SERVE_PORT=str(port),
                MXTPU_SERVE_ADDRS=",".join(addrs),
                MXTPU_SERVE_MODEL=args.serve_model,
@@ -220,6 +241,10 @@ def _wait_port(host, port, timeout=60.0):
 def launch_local(args, command):
     procs = []
     base_env = dict(os.environ)
+    for role in ("worker", "replica") if args.serve else ("worker",):
+        if _child_platform(args, base_env, role):
+            print("launch: %s children run as CPU processes (one process "
+                  "per chip; docs/serving.md)" % role, flush=True)
     coordinator = "127.0.0.1:%d" % args.port
     if args.autoscale:
         # the closed loop needs its sensor plane: the controller's only
@@ -268,10 +293,6 @@ def launch_local(args, command):
         prewarm_dir = os.path.join(autoscale_dir, "prewarm")
         os.makedirs(prewarm_dir, exist_ok=True)
         base_env.setdefault("MXTPU_SERVE_PREWARM_DIR", prewarm_dir)
-        # persistent XLA compile cache for every child: a joiner's
-        # jit compiles become cache loads too, not just its AOT menu
-        base_env.setdefault("JAX_COMPILATION_CACHE_DIR",
-                            os.path.join(autoscale_dir, "jaxcache"))
     if args.ps_respawn and not args.ps_snapshot_dir:
         # a respawned server with no snapshot restores nothing and every
         # in-flight key 404s — auto-provision the state dir instead
@@ -397,7 +418,7 @@ def launch_local(args, command):
         print("worker state in %s" % args.worker_state_dir)
     worker_envs = []
     for rank in range(args.num_workers):
-        env = dict(base_env)
+        env = dict(base_env, **_child_platform(args, base_env, "worker"))
         env.update({
             "MXTPU_COORDINATOR": coordinator,
             "MXTPU_NUM_PROCS": str(args.num_workers),
@@ -462,7 +483,7 @@ def launch_local(args, command):
 
     def _act_add_worker(action=None):
         rank = len(procs)
-        env = dict(base_env)
+        env = dict(base_env, **_child_platform(args, base_env, "worker"))
         env.update({
             "MXTPU_NUM_PROCS": str(args.num_workers),
             "MXTPU_PROC_ID": str(rank),
