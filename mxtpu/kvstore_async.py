@@ -676,6 +676,11 @@ class _Handler(socketserver.BaseRequestHandler):
         with server._active_lock:
             server._active.add(self.request)
         try:
+            if self.server.dying:
+                # accepted before stop() but registered after it read
+                # the list of sockets to sever: a dead server serves no
+                # one (stop() sets the flag before it reads the list)
+                return
             if server._token:
                 # exact-length raw compare before any unpickling; a
                 # wrong preamble closes the socket silently

@@ -14,6 +14,7 @@ import numpy as _np
 
 from .. import metric as metric_mod
 from .. import ndarray as nd
+from .. import obs as _obs
 from ..base import string_types
 from ..initializer import Uniform
 from ..model import BatchEndParam
@@ -219,21 +220,27 @@ class BaseModule:
             batch = next(feed, None)
             nbatch = 0
             while batch is not None:
-                if monitor is not None:
-                    monitor.tic()
-                self.forward_backward(batch)
-                self.update()
-                upcoming = next(feed, None)
-                if upcoming is not None:
-                    self.prepare(upcoming,
-                                 sparse_row_id_fn=sparse_row_id_fn)
-                self.update_metric(eval_metric, batch.label)
-                if monitor is not None:
-                    monitor.toc_print()
-                if upcoming is None:   # epoch's last batch: freeze stats
-                    eval_name_vals = eval_metric.get_name_value()
-                _fire(batch_end_callback, epoch=epoch, nbatch=nbatch,
-                      eval_metric=eval_metric, locals=locals())
+                # one turn of the loop; `step` is the step driver's too
+                # while fit drives a fresh module through one epoch
+                with _obs.span("module.fit.batch", step=nbatch,
+                               epoch=epoch):
+                    if monitor is not None:
+                        monitor.tic()
+                    self.forward_backward(batch)
+                    self.update()
+                    # where an input pipeline makes fit wait
+                    with _obs.span("module.fit.next_batch", step=nbatch):
+                        upcoming = next(feed, None)
+                    if upcoming is not None:
+                        self.prepare(upcoming,
+                                     sparse_row_id_fn=sparse_row_id_fn)
+                    self.update_metric(eval_metric, batch.label)
+                    if monitor is not None:
+                        monitor.toc_print()
+                    if upcoming is None:   # epoch's last batch: freeze stats
+                        eval_name_vals = eval_metric.get_name_value()
+                    _fire(batch_end_callback, epoch=epoch, nbatch=nbatch,
+                          eval_metric=eval_metric, locals=locals())
                 batch = upcoming
                 nbatch += 1
             # one epoch of training is finished

@@ -628,7 +628,7 @@ class FusedModuleTrainer:
         # sampled step tracing: the span opens at dispatch and — in
         # the dist modes — stays open through finish_update so the
         # wire spans nest under it (one timeline per sampled step)
-        self._trace_open = False
+        self._trace_sampled = False
         self._step_span = None
         self._trace_tok = None
         # dist modes: this step's emitted gradients, awaiting update()
@@ -931,23 +931,25 @@ class FusedModuleTrainer:
 
     # -- sampled step tracing ----------------------------------------------
     def _begin_step_trace(self):
-        """Open a sampled trace for this step (MXTPU_TRACE_SAMPLE);
-        no-op — one counter tick — when sampled out."""
+        """Open this step's ``module.step`` span. It records when the
+        sampler says so (MXTPU_TRACE_SAMPLE opens a trace context that
+        rides the kvstore wire) or a jax.profiler session is live; else
+        it is one counter tick and a span that finds nothing to do."""
         self._end_step_trace()   # a step whose update never came
-        if not self._group.tracer.sample():
-            return
-        self._trace_tok = _obs.start_trace()
-        self._step_span = _obs.span("module.step", mode=self._mode)
+        self._trace_sampled = self._group.tracer.sample()
+        if self._trace_sampled:
+            self._trace_tok = _obs.start_trace()
+        self._step_span = _obs.span("module.step", mode=self._mode,
+                                    step=self._group.stats["steps"])
         self._step_span.__enter__()
-        self._trace_open = True
 
     def _end_step_trace(self):
-        if not self._trace_open:
+        if self._step_span is None:
             return
-        self._trace_open = False
         self._step_span.__exit__(None, None, None)
         self._step_span = None
-        _obs.end_trace(self._trace_tok)
+        if self._trace_sampled:
+            _obs.end_trace(self._trace_tok)
 
     # -- the dist step -----------------------------------------------------
     def _dist_step(self, data_batch, exec_group, exec_):

@@ -22,10 +22,22 @@ time went between them. This module adds the missing propagation:
   replay exactly. With the default 0 every hook is one thread-local
   read that finds nothing.
 
-Timestamps are **epoch microseconds** (``time.time()``), not
-``perf_counter`` — the one clock every process of a launch shares, so
-the merged timeline lines up without offset solving. On hosts with NTP
-the cross-process skew is far below the wire latencies being measured.
+A span records when a sampled context is open **or** a ``jax.profiler``
+session is live. Under a live session every span is also a
+``TraceAnnotation`` named ``mxtpu.<span name>``, so it lies on the host
+plane of the ``.xplane.pb`` beside the device's operations; with no
+sampled context its trace id is the thread's and its parent the
+enclosing span on that thread, and no flow pair is written (nothing
+crosses a process). With neither, a span is one thread-local read and
+one static call that finds nothing.
+
+Timestamps are the profiler's: ``perf_counter`` carried to **epoch
+microseconds** by an offset taken once per process
+(``mxtpu.profiler.EPOCH_OFFSET_US``). One process's events are
+monotonic on one clock, whichever hook stamped them, and the merged
+timeline of a launch lines up without offset solving; on hosts with
+NTP the cross-process skew is far below the wire latencies being
+measured.
 
 Each process with ``MXTPU_TRACE_DIR`` set dumps its span events at
 exit (and on demand via :func:`dump_process_trace`) to
@@ -43,6 +55,8 @@ import os
 import threading
 import time
 import uuid
+
+from jax.profiler import TraceAnnotation as _Annotation
 
 from .. import profiler as _profiler
 from . import metrics as _metrics
@@ -148,16 +162,19 @@ class Sampler:
             return self._n % period == 1 or period == 1
 
 
-def _now_us():
-    return time.time() * 1e6
+# a jax.profiler session is live (a static call, 0.02 us)
+_session_live = _Annotation.is_enabled
+ANNOTATION_PREFIX = "mxtpu."      # a span's twin in the profiler's trace
 
 
 def start_trace(name="trace"):
     """Open a sampled root context on this thread; returns a token for
     :func:`end_trace`. The root span itself is recorded by whatever
-    :func:`span` scopes the caller opens inside it."""
+    :func:`span` scopes the caller opens inside it; opened inside a
+    span of a live session (``module.fit.batch``), it hangs from that
+    span."""
     prev = getattr(_tls, "ctx", None)
-    _tls.ctx = (_new_id(), name)
+    _tls.ctx = (_new_id(), _enclosing() or name)
     _traces_started.inc()
     return prev
 
@@ -200,60 +217,113 @@ class adopt:
 
 class span:
     """``with span("kv.client.rpc", op="push"):`` — records one
-    complete ('X') chrome-trace event tagged with the active trace id,
-    plus the flow-event pair that stitches processes. A span opened
-    with no active context records nothing (the sampled-out path)."""
+    complete ('X') chrome-trace event with ``args`` ``trace``, ``span``,
+    ``parent`` and the keywords given (the identifier of its layer:
+    ``rid`` on every span of one request, ``step`` on every span of one
+    train step). Under a sampled context the trace id is the context's
+    and the flow-event pair that stitches processes rides along; under
+    a live ``jax.profiler`` session alone the trace is the thread, and
+    the span is also a ``TraceAnnotation`` in the profiler's trace.
+    With neither, nothing is recorded. A span never waits for the
+    device and never reads a value back."""
 
-    __slots__ = ("name", "args", "_t0", "_ctx", "_prev", "_sid")
+    __slots__ = ("name", "args", "_t0", "_at", "_sid", "_ann")
 
     def __init__(self, name, **args):
         self.name = name
         self.args = args
-        self._ctx = active_ctx()
-        self._t0 = None
         self._sid = None
-        self._prev = None
 
     def __enter__(self):
-        if self._ctx is None:
+        at = _recording()
+        if at is None:
             return self
-        self._t0 = _now_us()
+        self._ann = None
+        if _session_live():
+            self._ann = _Annotation(
+                ANNOTATION_PREFIX + self.name,
+                **{k: str(v) for k, v in self.args.items()})
+            self._ann.__enter__()
+        # only now is there anything to undo: children opened inside
+        # this scope parent onto this span
+        self._at = at
         self._sid = _new_id()
-        # children opened inside this scope parent onto this span
-        self._prev = _tls.ctx
-        _tls.ctx = (self._ctx[0], self._sid)
+        if at[0] is None:
+            _tls.open.append(self._sid)
+        else:
+            _tls.ctx = (at[0], self._sid)
+        self._t0 = _profiler._now_us()
         return self
 
     def __exit__(self, *exc):
-        if self._ctx is None:
+        if self._sid is None:
             return False
-        _tls.ctx = self._prev
-        if self._sid is None or \
-                _spans_recorded.value >= events_max():
-            _span_drops.inc()
-            return False
-        t1 = _now_us()
-        tid, parent = self._ctx
-        args = {"trace": tid, "span": self._sid, "parent": parent}
-        for k, v in self.args.items():
-            args[k] = str(v)
-        pid = os.getpid()
-        thr = threading.get_ident() % 100000
+        t1 = _profiler._now_us()
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+        trace_id, parent = self._at
+        if trace_id is not None:
+            _tls.ctx = self._at
+        elif self._sid in getattr(_tls, "open", ()):
+            # mostly the last; a span closed out of turn (a step whose
+            # update never came, closed once the next one begins) goes
+            # from wherever it is, and the others keep their places
+            _tls.open.remove(self._sid)
+        _land(self.name, self._t0, t1, trace_id, self._sid, parent,
+              self.args)
+        self._sid = None
+        return False
+
+
+def _recording():
+    """``(trace id, parent span)`` for a span opened now on this thread,
+    or None where it records nothing: the sampled context if one is
+    open, else ``(None, the enclosing span)`` under a live session."""
+    ctx = getattr(_tls, "ctx", None)
+    if ctx is None and _session_live():
+        return None, _enclosing()
+    return ctx
+
+
+def _enclosing():
+    """The innermost span open on this thread under a live session with
+    no sampled context (the thread's stack of them is ``_tls.open``)."""
+    stack = _tls.__dict__.setdefault("open", [])
+    return stack[-1] if stack else None
+
+
+def _land(name, t0, t1, trace_id, sid, parent, extra):
+    """One 'X' event into the profiler's bounded list; ``trace_id``
+    None is a span of a live session with no sampled context."""
+    if _spans_recorded.value >= events_max():
+        _span_drops.inc()
+        return
+    pid = os.getpid()
+    ident = threading.get_ident()
+    thr = ident % 100000
+    args = {"trace": trace_id or "thread-%x" % ident, "span": sid,
+            "parent": parent}
+    for k, v in extra.items():
+        args[k] = str(v)
+    ev = {"name": name, "cat": "trace", "ph": "X", "ts": t0,
+          "dur": max(t1 - t0, 0.01), "pid": pid, "tid": thr,
+          "args": args}
+    if trace_id is None:
+        _profiler._emit(ev)
+    else:
         # one lock acquire lands the span AND its chrome flow pair
         # (the 's'/'f' events, id = trace id, are what make
         # chrome://tracing draw arrows between the processes)
         _profiler._emit_many((
-            {"name": self.name, "cat": "trace", "ph": "X",
-             "ts": self._t0, "dur": max(t1 - self._t0, 0.01),
-             "pid": pid, "tid": thr, "args": args},
-            {"name": "t:" + tid, "cat": "trace", "ph": "s",
-             "id": tid, "ts": self._t0, "pid": pid, "tid": thr},
-            {"name": "t:" + tid, "cat": "trace", "ph": "f",
-             "bp": "e", "id": tid, "ts": t1, "pid": pid, "tid": thr},
+            ev,
+            {"name": "t:" + trace_id, "cat": "trace", "ph": "s",
+             "id": trace_id, "ts": t0, "pid": pid, "tid": thr},
+            {"name": "t:" + trace_id, "cat": "trace", "ph": "f",
+             "bp": "e", "id": trace_id, "ts": t1, "pid": pid,
+             "tid": thr},
         ))
-        _spans_recorded.inc(1)
-        _maybe_autodump()
-        return False
+    _spans_recorded.inc(1)
+    _maybe_autodump()
 
 
 _dumper_started = [False]

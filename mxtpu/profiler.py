@@ -24,7 +24,7 @@ import time
 
 __all__ = ["set_config", "profiler_set_config", "set_state",
            "profiler_set_state", "pause", "resume", "dump", "dumps",
-           "snapshot_events", "reset",
+           "snapshot_events", "reset", "EPOCH_OFFSET_US",
            "Domain", "Task", "Frame", "Event", "Counter", "Marker"]
 
 _lock = threading.Lock()
@@ -40,8 +40,17 @@ _state = {
 _PID = os.getpid()
 
 
+# ONE clock for every event of this process (scoped objects, the eager
+# operator hook, the obs spans): ``perf_counter`` carried to the epoch by
+# an offset taken once, here. Monotonic within the process, and aligned
+# across the processes of a launch to what their wall clocks agree on, so
+# ``obs.merge_traces`` needs no offset solving. A reader that wants a
+# ``ts`` back on ``perf_counter`` takes ``EPOCH_OFFSET_US`` off it.
+EPOCH_OFFSET_US = (time.time() - time.perf_counter()) * 1e6
+
+
 def _now_us():
-    return time.perf_counter() * 1e6
+    return time.perf_counter() * 1e6 + EPOCH_OFFSET_US
 
 
 def set_config(filename="profile.json", profile_all=False,

@@ -643,6 +643,11 @@ class GenerateScheduler:
         with self._cv:
             pending = list(self._queue)
             self._queue.clear()
+        if pending:
+            with _obs.span("serve.gen.admit", queued=len(pending)):
+                self._admit(pending)
+
+    def _admit(self, pending):
         keep, expired = [], []
         now = time.monotonic()
         for req in pending:
@@ -686,14 +691,17 @@ class GenerateScheduler:
 
     def _prefill_into(self, req, lane, slot):
         self._c["prefills"].inc()
+        plen = int(req.prompt.shape[0])
         try:
-            first, rows = self._engine.gen_prefill(
-                req.prompt, lane.store[0], lane.store[1])
+            with _obs.span("serve.gen.prefill", rid=req.rid, plen=plen):
+                first, rows = self._engine.gen_prefill(
+                    req.prompt, lane.store[0], lane.store[1])
         except Exception as e:
             req.resolve(("err", "prefill failed: %s: %s"
                          % (type(e).__name__, e)))
             return
-        tok0 = int(_jax.device_get(first)[0])
+        with _obs.span("serve.gen.first_read", rid=req.rid):
+            tok0 = int(_jax.device_get(first)[0])
         _GEN_TTFT_MS.observe((time.monotonic() - req.enq_t) * 1e3)
         self._c["tokens"].inc()
         req.emit(tok0)
@@ -704,8 +712,9 @@ class GenerateScheduler:
                 "eos" if req.eos_id is not None and tok0 == req.eos_id
                 else "len"))
             return
-        lane.state = self._engine.gen_adopt(
-            lane.state, first, int(req.prompt.shape[0]), rows, slot)
+        with _obs.span("serve.gen.adopt", rid=req.rid, slot=slot):
+            lane.state = self._engine.gen_adopt(
+                lane.state, first, plen, rows, slot)
         lane.slot_req[slot] = req
         lane.active += 1
         with self._cv:
@@ -727,37 +736,9 @@ class GenerateScheduler:
                         req.resolve(("err",
                                      "decode step dropped (injected)"))
                 continue
-            t0 = time.perf_counter()
-            nxt, lane.state = self._engine.gen_step(
-                lane.state, lane.store[0], lane.store[1])
-            toks = _jax.device_get(nxt)       # the ONE per-step host read
-            _GEN_STEP_MS.observe((time.perf_counter() - t0) * 1e3)
-            self._c["steps"].inc()
-            self._c["tokens"].inc(lane.active)
-            now = time.monotonic()
-            for slot, req in enumerate(lane.slot_req):
-                if req is None:
-                    continue
-                req.emit(int(toks[slot]))
-                if ((req.eos_id is not None
-                     and int(toks[slot]) == req.eos_id)
-                        or len(req.tokens_out) >= req.max_new):
-                    self._free(lane, slot)
-                    self._c["finished"].inc()
-                    req.resolve(req._finish(
-                        "eos" if req.eos_id is not None
-                        and int(toks[slot]) == req.eos_id else "len"))
-                elif req.deadline is not None and now >= req.deadline:
-                    # the mid-generation expiry fix (ISSUE 17 satellite):
-                    # a budget exhausted BETWEEN decode steps frees the
-                    # slot now instead of decoding forever
-                    self._free(lane, slot)
-                    self._c["expired"].inc()
-                    req.resolve(("expired",
-                                 {"rid": req.rid,
-                                  "generated": len(req.tokens_out),
-                                  "late_ms": round(
-                                      (now - req.deadline) * 1e3, 3)}))
+            with _obs.span("serve.gen.step", active=lane.active,
+                           version=lane.version):
+                self._step(lane)
         self._g["slots_active"].set(self._active)
         # retire empty lanes off the current stable version — a drained
         # hot-swap lane releases its store reference here
@@ -765,6 +746,47 @@ class GenerateScheduler:
         for v in [v for v, ln in self._lanes.items()
                   if ln.active == 0 and v != stable]:
             del self._lanes[v]
+
+    def _step(self, lane):
+        """One decode step of one lane: dispatch, the one host read,
+        then every live slot's token out (emit, free, resolve)."""
+        t0 = time.perf_counter()
+        with _obs.span("serve.gen.step.dispatch"):
+            nxt, lane.state = self._engine.gen_step(
+                lane.state, lane.store[0], lane.store[1])
+        with _obs.span("serve.gen.step.read"):
+            toks = _jax.device_get(nxt)   # the ONE per-step host read
+        _GEN_STEP_MS.observe((time.perf_counter() - t0) * 1e3)
+        self._c["steps"].inc()
+        self._c["tokens"].inc(lane.active)
+        now = time.monotonic()
+        with _obs.span("serve.gen.step.emit"):
+            self._emit_step(lane, toks, now)
+
+    def _emit_step(self, lane, toks, now):
+        for slot, req in enumerate(lane.slot_req):
+            if req is None:
+                continue
+            req.emit(int(toks[slot]))
+            if ((req.eos_id is not None
+                 and int(toks[slot]) == req.eos_id)
+                    or len(req.tokens_out) >= req.max_new):
+                self._free(lane, slot)
+                self._c["finished"].inc()
+                req.resolve(req._finish(
+                    "eos" if req.eos_id is not None
+                    and int(toks[slot]) == req.eos_id else "len"))
+            elif req.deadline is not None and now >= req.deadline:
+                # the mid-generation expiry fix (ISSUE 17 satellite):
+                # a budget exhausted BETWEEN decode steps frees the
+                # slot now instead of decoding forever
+                self._free(lane, slot)
+                self._c["expired"].inc()
+                req.resolve(("expired",
+                             {"rid": req.rid,
+                              "generated": len(req.tokens_out),
+                              "late_ms": round(
+                                  (now - req.deadline) * 1e3, 3)}))
 
     def _free(self, lane, slot):
         lane.slot_req[slot] = None

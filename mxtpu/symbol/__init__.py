@@ -661,7 +661,11 @@ def eval_graph(sym_outputs, feed, training=False):
                 if batch:
                     params["shape"] = tuple(batch if d == 0 else d
                                             for d in params["shape"])
-            out = node.op.fn(*in_vals, **params)
+            # every HLO operation of a compiled step carries the
+            # operator and node it came from in its op_name, forward and
+            # transposed: what a device trace's `fusion` is made of
+            with jax.named_scope("%s/%s" % (node.op.name, node.name)):
+                out = node.op.fn(*in_vals, **params)
             vals = out if isinstance(out, tuple) else (out,)
             for in_pos, out_idx in node.op.aux_update.items():
                 if in_pos < len(node.inputs):

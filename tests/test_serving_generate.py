@@ -342,6 +342,11 @@ def test_kill_mid_generation_replays_exactly_once(monkeypatch):
             seen.append((i, t, v))
             if i == 2:
                 srv0.kill()
+        # each decode step takes 20 ms, so the replica cannot have
+        # streamed all ten tokens before the third one's callback kills
+        # it (on a loaded host it sometimes had, and nothing failed over)
+        fault.install("kind=delay,point=serve.step,delay=0.02,nth=1,"
+                      "count=1000")
         toks, info = cli.generate2([3, 1, 4], max_new=10, model="lm",
                                    on_token=on_tok)
         assert toks == ref, (toks, ref)
