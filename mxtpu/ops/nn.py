@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from .. import obs as _obs
 from .registry import register, next_rng_key
 
 
@@ -701,6 +702,21 @@ def cached_attention(query, key, value, k_cache, v_cache, pos, num_heads=1,
             mesh, causal=True, data_axis=None, alibi=use_alibi)
         out = o.transpose(0, 2, 1, 3).reshape(B, T, D)
         return out.astype(query.dtype), new_k, new_v
+    if _decode_path(query, new_k, H):
+        _DECODE_PATH_NODES.inc()
+        out = _attend_decode(query, new_k, new_v, p, H, use_alibi)
+    else:
+        out = _attend_dense(query, new_k, new_v, p, H, use_alibi)
+    return out, new_k, new_v
+
+
+def _attend_dense(query, new_k, new_v, p, H, use_alibi):
+    """The dense formula: every query row against the whole cache,
+    scores ``[B, H, T, S]`` in the query's dtype, masked to ``s <= pos +
+    t``."""
+    B, T, D = query.shape
+    S = new_k.shape[1]
+    hd = D // H
     qh = query.reshape(B, T, H, hd)
     kh = new_k.astype(query.dtype).reshape(B, S, H, hd)
     vh = new_v.astype(query.dtype).reshape(B, S, H, hd)
@@ -720,7 +736,65 @@ def cached_attention(query, key, value, k_cache, v_cache, pos, num_heads=1,
                        jnp.asarray(-1e30, scores.dtype))
     att = jax.nn.softmax(scores, axis=-1)
     out = jnp.einsum("bhts,bshd->bthd", att, vh).reshape(B, T, D)
-    return out.astype(query.dtype), new_k, new_v
+    return out.astype(query.dtype)
+
+
+# One query row a sample (the decode step) attends through the Pallas
+# kernel ``pallas_attention.decode_attention``, which reads K and V in the
+# [B, S, D] tiling they are stored in and only the blocks at or below
+# pos[b]. The dense formula re-tiles both whole caches to heads-minor
+# every step (19.6 ms of BLOOM-1b7's 36.9 ms decode program on the v5e,
+# PERF.md PR 28) and attends all S rows. Which of the two a call takes is
+# read off its shapes at trace time and nothing else: T == 1, a head of
+# whole 128-lane slabs (so a head is a column block of the stored tile),
+# a block that divides S, and no ambient mesh (a kernel is one device's
+# program; under a mesh GSPMD partitions the dense formula). Prefill,
+# training and every small-head model take the dense formula as before.
+_DECODE_PATH_NODES = _obs.counter(
+    "ops.cached_attention.decode_path",
+    "cached_attention nodes traced onto the one-token decode kernel")
+
+
+def decode_path_nodes():
+    """How many ``cached_attention`` nodes this process has traced onto
+    the decode kernel so far (``InferenceEngine.stats()`` reports the
+    count per generate program)."""
+    return _DECODE_PATH_NODES.default().value
+
+
+def _decode_path(query, cache, H):
+    """Whether this call's shapes put it on the decode kernel."""
+    B, T, D = query.shape
+    if T != 1 or D % H or (D // H) % 128:
+        return False
+    from ..parallel.mesh import current_mesh
+    if _SEQ_PARALLEL or current_mesh() is not None:
+        return False
+    from .pallas_attention import decode_block
+    return decode_block(cache.shape[1], D, cache.dtype) is not None
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _attend_decode(query, new_k, new_v, p, H, use_alibi):
+    from .pallas_attention import decode_attention
+    return decode_attention(query, new_k, new_v, p, H, alibi=use_alibi)
+
+
+def _attend_decode_fwd(query, new_k, new_v, p, H, use_alibi):
+    return (_attend_decode(query, new_k, new_v, p, H, use_alibi),
+            (query, new_k, new_v, p))
+
+
+def _attend_decode_bwd(H, use_alibi, res, g):
+    # the kernel is forward only: differentiate the formula it computes
+    query, new_k, new_v, p = res
+    _, vjp = jax.vjp(
+        lambda q, k, v: _attend_dense(q, k, v, p, H, use_alibi),
+        query, new_k, new_v)
+    return vjp(g) + (None,)
+
+
+_attend_decode.defvjp(_attend_decode_fwd, _attend_decode_bwd)
 
 
 # ---------------------------------------------------------------------------

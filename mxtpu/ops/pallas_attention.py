@@ -20,10 +20,25 @@ it to hand-written Pallas TPU kernels:
   ``mxtpu.parallel.ring_attention`` (impl="flash") calls;
 * fully-masked tiles (above the causal diagonal) are skipped outright.
 
+* one-token decode over the serving engine's packed ``[B, S, D]`` cache
+  (:func:`decode_attention`): the cache is read in the tiling it is
+  stored in, a block of rows with all heads' columns at a time, the
+  heads kept apart by a block-diagonal query matrix; ``pos`` is
+  scalar-prefetched so blocks past a slot's live length are neither
+  fetched nor computed. ``ops.nn.cached_attention`` takes it whenever a
+  call has one query row a sample, heads of whole 128-lane slabs, a
+  block that divides ``S`` and no ambient mesh; every other shape keeps
+  the dense formula.
+
 Lowered for TPU the kernels compile through Mosaic; lowered for any
 other platform the same kernels run through the Pallas interpreter
-(tests), so numerics are identical everywhere (``pallas_util``). No
-timing of these kernels has been taken on the current machine.
+(tests), so numerics are identical everywhere (``pallas_util``). Timed
+on one TPU v5e (PERF.md section 6, PR 28): 24 chained layers at 16 slots
+of 40-1200 live rows on a 2048-row bfloat16 cache, 16 heads of 128, take
+6.1 ms with :func:`decode_attention` (2.9 ms of it XLA's cache write)
+where the dense formula takes 31.9 ms; inside BLOOM-1b7's decode program
+the kernel runs 2.9 ms a step. The flash kernels have not been timed on
+the current machine.
 
 Pallas itself is imported lazily on first use — `import mxtpu` stays
 cheap; the op registry registration in ops/__init__ binds a thin
@@ -40,7 +55,7 @@ from ..base import MXNetError
 from .pallas_util import SCOPED_VMEM_LIMIT
 
 __all__ = ["flash_attention", "flash_attention_with_lse",
-           "flash_attention_reference"]
+           "flash_attention_reference", "decode_attention"]
 
 _NEG = -1e30  # large-negative instead of finfo.min: exp() underflows to 0
               # without inf - inf = nan hazards in the running-max rescale
@@ -439,3 +454,176 @@ def flash_attention_reference(q, k, v, causal=False, scale=None,
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhqk,bhkd->bhqd", p,
                       v.astype(jnp.float32)).astype(q.dtype)
+
+
+# ---------------------------------------------------------------------------
+# one-token decode attention over the packed [B, S, D] cache
+# ---------------------------------------------------------------------------
+
+DECODE_BLOCK_S = 256    # cache rows a grid step reads (timed on the v5e)
+
+
+@functools.cache
+def _decode_call():
+    """Build the decode kernel's pallas_call wrapper on first use."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    from .pallas_util import per_platform
+
+    def kernel(pos_ref, q_ref, slope_ref, k_ref, v_ref, o_ref,
+               qbd_ref, acc_ref, m_ref, l_ref, *, hd, block_s, scale):
+        b, j = pl.program_id(0), pl.program_id(1)
+        p = pos_ref[b]
+        rows, d = qbd_ref.shape
+
+        def own_lanes():
+            """[rows, D]: True where a lane belongs to its row's head."""
+            head = jax.lax.broadcasted_iota(jnp.int32, (rows, d), 0)
+            lane = jax.lax.broadcasted_iota(jnp.int32, (rows, d), 1)
+            return (lane >= head * hd) & (lane < (head + 1) * hd)
+
+        @pl.when(j == 0)
+        def _init():
+            # the query as a block-diagonal [heads, D] matrix: row h
+            # holds head h's 128-lane slab and zeros elsewhere, so ONE
+            # matrix product against the cache block as it is stored
+            # gives every head's score row
+            # (selected in float32: a mask over packed bfloat16 rows is a
+            # relayout Mosaic refuses)
+            q = jnp.broadcast_to(q_ref[0].astype(jnp.float32), (rows, d))
+            qbd_ref[:] = jnp.where(own_lanes(), q, 0.0).astype(qbd_ref.dtype)
+            acc_ref[:] = jnp.zeros_like(acc_ref)
+            m_ref[:] = jnp.full_like(m_ref, _NEG)
+            l_ref[:] = jnp.zeros_like(l_ref)
+
+        @pl.when(j * block_s <= p)      # a block past pos[b] costs nothing
+        def _compute():
+            cdt = qbd_ref.dtype
+            s = jax.lax.dot_general(
+                qbd_ref[:], k_ref[0].astype(cdt), (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale
+            at = j * block_s + jax.lax.broadcasted_iota(
+                jnp.int32, s.shape, 1)
+            s = s - slope_ref[:] * (p - at).astype(jnp.float32)
+            s = jnp.where(at <= p, s, _NEG)
+            m_prev = m_ref[:]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            # row j*block_s is live here, so m_new is a real score and a
+            # masked column's exp underflows to exactly 0
+            pr = jnp.exp(s - m_new)
+            corr = jnp.exp(m_prev - m_new)
+            l_ref[:] = l_ref[:] * corr + jnp.sum(pr, axis=-1, keepdims=True)
+            m_ref[:] = m_new
+            acc_ref[:] = acc_ref[:] * corr + jax.lax.dot_general(
+                pr.astype(cdt), v_ref[0].astype(cdt),
+                (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+
+        @pl.when(j == pl.num_programs(1) - 1)
+        def _fin():
+            # row h of acc is head h's weights times ALL of V's columns;
+            # its own slab is the head's output
+            out = jnp.where(own_lanes(), acc_ref[:] / l_ref[:], 0.0)
+            o_ref[0] = jnp.sum(out, axis=0, keepdims=True).astype(o_ref.dtype)
+
+    @functools.partial(jax.jit, static_argnums=(5, 6))
+    def call(q, k_cache, v_cache, pos, slopes, hd, block_s):
+        B, S, D = k_cache.shape
+        rows = slopes.shape[0]
+        nblk = S // block_s
+
+        def kv_map(b, j, pos_ref):
+            # a dead block repeats the last live one: no new DMA
+            return (b, jnp.minimum(j, pos_ref[b] // block_s), 0)
+
+        kern = functools.partial(kernel, hd=hd, block_s=block_s,
+                                 scale=hd ** -0.5)
+        return per_platform(functools.partial(
+            pl.pallas_call,
+            kern,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1,
+                grid=(B, nblk),
+                in_specs=[
+                    pl.BlockSpec((1, 1, D), lambda b, j, pos_ref: (b, 0, 0)),
+                    pl.BlockSpec((rows, 1), lambda b, j, pos_ref: (0, 0)),
+                    pl.BlockSpec((1, block_s, D), kv_map),
+                    pl.BlockSpec((1, block_s, D), kv_map),
+                ],
+                out_specs=pl.BlockSpec((1, 1, D),
+                                       lambda b, j, pos_ref: (b, 0, 0)),
+                scratch_shapes=[
+                    pltpu.VMEM((rows, D), q.dtype),
+                    pltpu.VMEM((rows, D), jnp.float32),
+                    pltpu.VMEM((rows, 1), jnp.float32),
+                    pltpu.VMEM((rows, 1), jnp.float32),
+                ]),
+            out_shape=jax.ShapeDtypeStruct((B, 1, D), q.dtype),
+            name="decode_attention",
+        ), pos, q, slopes, k_cache, v_cache)
+
+    return call
+
+
+def decode_block(S, D, cache_dtype, block_s=None):
+    """The rows of cache one grid step of :func:`decode_attention`
+    reads, or None when no block divides ``S``: ``DECODE_BLOCK_S``,
+    halved until K's and V's double-buffered blocks fit half of the
+    scoped-VMEM limit (512 rows of 4 KiB compile, 1024 Mosaic refuses). An
+    explicit ``block_s`` over that raises."""
+    row_bytes = D * jnp.dtype(cache_dtype).itemsize
+
+    def fits(rows):
+        return 4 * rows * row_bytes <= SCOPED_VMEM_LIMIT // 2
+
+    if block_s is None:
+        block_s = min(DECODE_BLOCK_S, S)
+        while block_s > 8 and not fits(block_s):
+            block_s //= 2
+    elif not fits(block_s):
+        raise MXNetError(
+            "decode_attention: a block of %d rows of %d bytes, K and V "
+            "double-buffered (%d MiB), does not fit the %d MiB "
+            "scoped-VMEM limit" % (block_s, row_bytes,
+                                   (4 * block_s * row_bytes) >> 20,
+                                   SCOPED_VMEM_LIMIT >> 20))
+    if S % block_s or block_s % 8:
+        return None
+    return block_s
+
+
+def decode_attention(q, k_cache, v_cache, pos, num_heads, alibi=False,
+                     block_s=None):
+    """One query row a sample against the packed cache where it lies.
+
+    ``q [B, 1, D]``, caches ``[B, S, D]`` (row ``pos[b]`` already
+    written), ``pos [B]`` int32. Slot ``b`` attends rows ``s <= pos[b]``
+    of its own cache; ``alibi`` subtracts ``2^(-8(h+1)/H) * (pos[b] -
+    s)`` from head ``h``'s scores. Returns ``[B, 1, D]`` in ``q``'s
+    dtype.
+
+    The cache is read in the layout it is stored in: the grid is
+    (slot, block of rows), a block holds ALL heads' columns, and the
+    heads are kept apart by a block-diagonal query matrix, not by
+    re-tiling K and V to heads-minor. ``pos`` is scalar-prefetched, so
+    a block past a slot's live length is neither fetched nor computed.
+    Scores, the running maximum and sum and the output accumulate in
+    float32. Needs a head of whole 128-lane slabs and ``block_s``
+    dividing ``S``; forward only (``ops.nn.cached_attention`` gives it
+    the dense formula's gradient)."""
+    B, S, D = k_cache.shape
+    H = int(num_heads)
+    hd = D // H
+    blk = decode_block(S, D, k_cache.dtype, block_s)
+    if hd % 128 or blk is None:
+        raise MXNetError(
+            "decode_attention: head dim %d must be a multiple of 128 and "
+            "the block must divide the cache length %d" % (hd, S))
+    rows = -(-H // 8) * 8
+    slopes = [2.0 ** (-8.0 * (i + 1) / H) if alibi and i < H else 0.0
+              for i in range(rows)]
+    # a slot the scheduler left idle may count past the cache: it holds
+    # nothing anyone reads, only keep its block index inside the array
+    p = jnp.clip(pos.astype(jnp.int32).reshape(-1), 0, S - 1)
+    return _decode_call()(q, k_cache, v_cache, p,
+                          jnp.asarray(slopes, jnp.float32)[:, None], hd, blk)

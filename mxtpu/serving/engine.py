@@ -59,6 +59,7 @@ one flush thread.
 """
 from __future__ import annotations
 
+import contextlib
 import os
 import threading
 import warnings
@@ -207,7 +208,9 @@ class InferenceEngine:
         self._stats = {"predicts": 0, "rows": 0, "pad_rows": 0,
                        "swaps": 0, "swaps_refused": 0,
                        "version_rebinds": 0,
-                       "gen_prefills": 0, "gen_steps": 0}
+                       "gen_prefills": 0, "gen_steps": 0,
+                       "gen_decode_attn_path": 0,
+                       "gen_prefill_attn_path": 0}
         if warm:
             self.warm()
 
@@ -435,6 +438,18 @@ class InferenceEngine:
             self._gc_stores_locked()
             self._note("swaps")
         return v
+
+    @contextlib.contextmanager
+    def _noting_attn_path(self, field):
+        """Around the trace of a generate program: add to ``field`` how
+        many of its ``cached_attention`` nodes the trace put on the
+        one-token decode kernel (``ops/nn.py``): ``n_layer`` for a decode
+        program whose shapes engage it, 0 for a prefill."""
+        from ..ops.nn import decode_path_nodes
+        before = decode_path_nodes()
+        yield
+        with self._stats_lock:
+            self._stats[field] += decode_path_nodes() - before
 
     def _note(self, field):
         with self._stats_lock:
@@ -885,10 +900,12 @@ class InferenceEngine:
         with warnings.catch_warnings():
             warnings.filterwarnings(
                 "ignore", message="Some donated buffers were not usable")
-            return jitted.lower(
-                self._abs((1, L), self._dtype),
-                self._abs((1,), _np.int32),
-                param_abs, aux_abs).compile()
+            with self._noting_attn_path("gen_prefill_attn_path"):
+                lowered = jitted.lower(
+                    self._abs((1, L), self._dtype),
+                    self._abs((1,), _np.int32),
+                    param_abs, aux_abs)
+            return lowered.compile()
 
     def _build_gen_decode(self, K):
         """ONE decode step over the packed ``K``-slot batch: (current
@@ -937,10 +954,16 @@ class InferenceEngine:
         with warnings.catch_warnings():
             warnings.filterwarnings(
                 "ignore", message="Some donated buffers were not usable")
-            return jitted.lower(
-                self._abs((K, 1), self._dtype),
-                self._abs((K,), _np.int32),
-                state_abs, param_abs, aux_abs).compile()
+            # a sharded engine's mesh is ambient while the decode program
+            # is traced, so cached_attention sees it and keeps the dense
+            # formula, which GSPMD partitions (the kernel is one device's)
+            with self._mesh or contextlib.nullcontext(), \
+                    self._noting_attn_path("gen_decode_attn_path"):
+                lowered = jitted.lower(
+                    self._abs((K, 1), self._dtype),
+                    self._abs((K,), _np.int32),
+                    state_abs, param_abs, aux_abs)
+            return lowered.compile()
 
     def _build_gen_adopt(self, K):
         """Insert one prefilled sequence into decode slot ``slot`` of

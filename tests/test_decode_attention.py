@@ -1,0 +1,182 @@
+"""The one-token decode path of ``cached_attention`` (PR 28): the Pallas
+kernel ``pallas_attention.decode_attention`` against the definition and the
+dense formula, and which shapes the op puts on it. Runs through the Pallas
+interpreter on the CPU; ``tests/test_kernels_compile_v5e.py`` compiles the
+same kernel through Mosaic at the benchmark's shapes."""
+import numpy as np
+import jax
+import jax.numpy as jnp
+import pytest
+
+
+DB, DS, DH, DHD = 3, 256, 2, 128        # slots, cache rows, heads, head dim
+DD = DH * DHD
+DBLK = 64                                # so a slot's rows span 1 to 4 blocks
+
+
+def _np_decode_reference(q, kc, vc, pos, heads, alibi):
+    """float64, by the definition: slot b's one query row against rows
+    s <= pos[b] of its own cache, softmax, times V; head h of width hd."""
+    q, kc, vc = (np.asarray(a, np.float64) for a in (q, kc, vc))
+    B, S, D = kc.shape
+    hd = D // heads
+    out = np.zeros((B, 1, D))
+    for b in range(B):
+        n = int(pos[b]) + 1
+        for h in range(heads):
+            sl = slice(h * hd, (h + 1) * hd)
+            s = kc[b, :n, sl] @ q[b, 0, sl] / np.sqrt(hd)
+            if alibi:
+                s = s - 2.0 ** (-8.0 * (h + 1) / heads) * (
+                    pos[b] - np.arange(n))
+            w = np.exp(s - s.max())
+            out[b, 0, sl] = (w / w.sum()) @ vc[b, :n, sl]
+    return out
+
+
+def _decode_case(cache_dtype, seed=0):
+    rng = np.random.RandomState(seed)
+    q = jnp.asarray(rng.standard_normal((DB, 1, DD)), jnp.float32)
+    kc, vc = (jnp.asarray(rng.standard_normal((DB, DS, DD)), cache_dtype)
+              for _ in range(2))
+    return q, kc, vc
+
+
+@pytest.mark.parametrize("cache_dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("alibi", [False, True])
+@pytest.mark.parametrize("pos", [
+    (0, DBLK, DS - 1),                   # first row, a block edge, last row
+    (DBLK - 1, 2 * DBLK, 2 * DBLK + 1)])  # an edge minus 1, mid-cache
+def test_decode_kernel_matches_the_definition(pos, alibi, cache_dtype):
+    from mxtpu.ops.nn import _attend_dense
+    from mxtpu.ops.pallas_attention import decode_attention
+    q, kc, vc = _decode_case(cache_dtype)
+    p = jnp.asarray(pos, jnp.int32)
+    out = decode_attention(q, kc, vc, p, DH, alibi=alibi, block_s=DBLK)
+    assert out.shape == (DB, 1, DD) and out.dtype == q.dtype
+    ref = _np_decode_reference(q, kc, vc, pos, DH, alibi)
+    np.testing.assert_allclose(np.asarray(out), ref, atol=2e-5, rtol=2e-5)
+    # and the dense formula, called directly, reads the same
+    dense = _attend_dense(q, kc, vc, p, DH, alibi)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(dense),
+                               atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("alibi", [False, True])
+def test_decode_kernel_never_reads_rows_past_pos(alibi):
+    """Rows past pos[b] hold what a padded prefill or an earlier tenant of
+    the slot left: large values here, and none may reach the output (the
+    last live block holds live and dead rows side by side)."""
+    from mxtpu.ops.pallas_attention import decode_attention
+    q, kc, vc = _decode_case(jnp.bfloat16, seed=3)
+    pos = (0, DBLK + 5, 3 * DBLK - 1)
+    dead = np.arange(DS)[None, :, None] > np.asarray(pos)[:, None, None]
+    dirty_k = jnp.where(dead, jnp.asarray(3e4, kc.dtype), kc)
+    dirty_v = jnp.where(dead, jnp.asarray(-3e4, vc.dtype), vc)
+    p = jnp.asarray(pos, jnp.int32)
+    clean = decode_attention(q, kc, vc, p, DH, alibi=alibi, block_s=DBLK)
+    dirty = decode_attention(q, dirty_k, dirty_v, p, DH, alibi=alibi,
+                             block_s=DBLK)
+    np.testing.assert_array_equal(np.asarray(dirty), np.asarray(clean))
+    # a slot the scheduler left idle counts past the cache: no fault
+    idle = decode_attention(q, kc, vc, jnp.asarray([DS, DS + 7, 0]), DH,
+                            alibi=alibi, block_s=DBLK)
+    assert np.isfinite(np.asarray(idle)).all()
+
+
+def _op_inputs(T, heads, hd, S=DS, seed=5, dtype=jnp.bfloat16):
+    rng = np.random.RandomState(seed)
+    D = heads * hd
+    q, k, v = (jnp.asarray(rng.standard_normal((DB, T, D)), jnp.float32)
+               for _ in range(3))
+    kc, vc = (jnp.asarray(rng.standard_normal((DB, S, D)), dtype)
+              for _ in range(2))
+    return q, k, v, kc, vc
+
+
+def _frozen_dense_op(q, k, v, kc, vc, pos, heads, alibi):
+    """cached_attention as it stood before the decode path, kept here word
+    for word so that "the dense path is unchanged" compares with the past
+    and not with the code under test."""
+    p = pos.astype(jnp.int32).reshape(-1)
+    B, T, D = q.shape
+    S, H = kc.shape[1], heads
+    hd = D // H
+    write = jax.vmap(lambda cache, rows, at: jax.lax.dynamic_update_slice(
+        cache, rows, (at, 0)))
+    new_k = write(kc, k.astype(kc.dtype), p)
+    new_v = write(vc, v.astype(vc.dtype), p)
+    qh = q.reshape(B, T, H, hd)
+    kh = new_k.astype(q.dtype).reshape(B, S, H, hd)
+    vh = new_v.astype(q.dtype).reshape(B, S, H, hd)
+    scores = jnp.einsum("bthd,bshd->bhts", qh, kh) / jnp.sqrt(
+        jnp.asarray(hd, q.dtype))
+    t_idx = jnp.arange(T, dtype=jnp.int32)[None, :, None]
+    s_idx = jnp.arange(S, dtype=jnp.int32)[None, None, :]
+    q_abs = p[:, None, None] + t_idx
+    if alibi:
+        slopes = jnp.asarray([2.0 ** (-8.0 * (i + 1) / H)
+                              for i in range(H)], scores.dtype)
+        dist = (q_abs - s_idx).astype(scores.dtype)
+        scores = scores - slopes[None, :, None, None] * dist[:, None]
+    scores = jnp.where((s_idx <= q_abs)[:, None, :, :], scores,
+                       jnp.asarray(-1e30, scores.dtype))
+    att = jax.nn.softmax(scores, axis=-1)
+    out = jnp.einsum("bhts,bshd->bthd", att, vh).reshape(B, T, D)
+    return out.astype(q.dtype), new_k, new_v
+
+
+@pytest.mark.parametrize("alibi", [False, True])
+def test_cached_attention_takes_the_decode_path_at_one_token(alibi):
+    """T == 1 with heads of 128: the op counts itself onto the kernel,
+    writes the row as before and attends it; jax.grad gives what the dense
+    formula gives."""
+    from mxtpu.ops.nn import cached_attention, decode_path_nodes
+    q, k, v, kc, vc = _op_inputs(1, DH, DHD)
+    pos = jnp.asarray([0, DS // 2, DS - 1], jnp.int32)
+    before = decode_path_nodes()
+    out, nk, nv = jax.jit(lambda *a: cached_attention(
+        *a, num_heads=DH, alibi=alibi))(q, k, v, kc, vc, pos)
+    assert decode_path_nodes() == before + 1
+    want, wk, wv = _frozen_dense_op(q, k, v, kc, vc, pos, DH, alibi)
+    np.testing.assert_array_equal(np.asarray(nk), np.asarray(wk))
+    np.testing.assert_array_equal(np.asarray(nv), np.asarray(wv))
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                               atol=2e-5, rtol=2e-5)
+
+    def loss(op):
+        return lambda q, k, v: jnp.sum(
+            op(q, k, v, kc, vc, pos)[0] * jnp.cos(
+                jnp.arange(DD, dtype=jnp.float32)))
+    got = jax.grad(loss(lambda *a: cached_attention(
+        *a, num_heads=DH, alibi=alibi)), argnums=(0, 1, 2))(q, k, v)
+    ref = jax.grad(loss(lambda *a: _frozen_dense_op(*a, DH, alibi)),
+                   argnums=(0, 1, 2))(q, k, v)
+    for g, r in zip(got, ref):
+        assert float(jnp.abs(r).max()) > 0
+        np.testing.assert_allclose(np.asarray(g), np.asarray(r),
+                                   atol=1e-6, rtol=1e-6)
+
+
+@pytest.mark.parametrize("T,heads,hd,why", [
+    (4, 2, 8, "a prefill chunk of small heads"),
+    (1, 2, 8, "one token, but a head is no whole 128-lane slab"),
+    (4, 2, 128, "heads of 128, but four query rows"),
+    (1, 2, 128, "one token, heads of 128, under an ambient mesh")])
+def test_cached_attention_keeps_the_dense_path(T, heads, hd, why):
+    """Everything but the decode shape returns what it returned before,
+    bit for bit, and is not counted."""
+    import contextlib
+    from mxtpu.ops.nn import cached_attention, decode_path_nodes
+    from mxtpu.parallel import MeshContext
+    q, k, v, kc, vc = _op_inputs(T, heads, hd, S=32, dtype=jnp.float32)
+    pos = jnp.asarray([0, 5, 32 - T], jnp.int32)
+    mesh = MeshContext(jax.devices()[:1], data=1) if "mesh" in why \
+        else contextlib.nullcontext()
+    before = decode_path_nodes()
+    with mesh:
+        got = cached_attention(q, k, v, kc, vc, pos, num_heads=heads,
+                               alibi=True)
+    assert decode_path_nodes() == before, why
+    for g, w in zip(got, _frozen_dense_op(q, k, v, kc, vc, pos, heads, True)):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))

@@ -150,3 +150,64 @@ def test_pallas_rnn_limit_is_the_compilers(v5e, monkeypatch):
                  S((32, 1024)), S((1024, 4096)))
     finally:
         pallas_rnn._fwd_call.cache_clear()
+
+
+# -- decode attention (cached_attention's one-token path) ------------------------
+
+CELL = dict(B=16, S=2048, D=2048, H=16)     # bloom1b7-saturated's decode step
+
+
+def _decode_step_avals(v5e, B, S, D, cache_dtype=jnp.bfloat16):
+    S_ = functools.partial(jax.ShapeDtypeStruct, sharding=v5e)
+    row, cache = S_((B, 1, D), jnp.bfloat16), S_((B, S, D), cache_dtype)
+    return row, row, row, cache, cache, S_((B,), jnp.int32)
+
+
+def test_decode_path_at_the_cells_shapes_copies_no_cache(v5e):
+    """What ISSUE 28 is about, pinned without a chip: at the saturated
+    cell's decode shapes the op is the Mosaic kernel, both caches are still
+    written in place, and nothing cache-sized is copied or kept as a
+    temporary (the dense formula re-tiled both whole caches to heads-minor
+    every step: ``temp_size_in_bytes`` 134,411,264)."""
+    from mxtpu.ops.nn import cached_attention, decode_path_nodes
+    B, S, D, H = (CELL[k] for k in "BSDH")
+    before = decode_path_nodes()
+    compiled = jax.jit(
+        lambda *a: cached_attention(*a, num_heads=H, alibi=True),
+        donate_argnums=(3, 4)).trace(
+            *_decode_step_avals(v5e, B, S, D)).lower(
+                lowering_platforms=("tpu",)).compile()
+    assert decode_path_nodes() == before + 1
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == 2 * B * S * D * 2 == 268435456
+    assert mem.temp_size_in_bytes < 8 * 2 ** 20
+    for m in re.finditer(r"= \w+\[([\d,]+)\]\S* copy\(", text):
+        dims = [int(d) for d in m.group(1).split(",")]
+        assert functools.reduce(int.__mul__, dims) < B * S * D, m.group(0)
+
+
+@pytest.mark.parametrize("heads,d,cache_dtype", [
+    (12, 1536, jnp.bfloat16), (16, 2048, jnp.float32),
+    (2, 256, jnp.bfloat16)])
+def test_decode_kernel_compiles_at_other_widths(v5e, heads, d, cache_dtype):
+    from mxtpu.ops.pallas_attention import decode_attention
+    q, _k, _v, kc, vc, pos = _decode_step_avals(v5e, 8, 1024, d, cache_dtype)
+    assert "tpu_custom_call" in _compile(
+        lambda q, kc, vc, pos: decode_attention(q, kc, vc, pos, heads,
+                                                alibi=True), q, kc, vc, pos)
+
+
+def test_decode_over_limit_block_raises_the_frameworks_error(v5e):
+    from mxtpu.ops.pallas_attention import _decode_call, decode_attention
+    B, S, D, H = (CELL[k] for k in "BSDH")
+    q, _k, _v, kc, vc, pos = _decode_step_avals(v5e, B, S, D)
+    with pytest.raises(MXNetError, match="scoped-VMEM limit"):
+        jax.jit(lambda *a: decode_attention(*a, H, block_s=1024)).trace(
+            q, kc, vc, pos)
+    # the limit is the compiler's: past the check, Mosaic refuses the block
+    slopes = jax.ShapeDtypeStruct((H, 1), jnp.float32, sharding=v5e)
+    size = _refused(lambda q, kc, vc, pos, sl: _decode_call()(
+        q, kc, vc, pos, sl, D // H, 1024), q, kc, vc, pos, slopes)
+    assert size > 16.0

@@ -443,6 +443,110 @@ def test_live_swap_never_tears_an_inflight_sequence():
 
 
 # ---------------------------------------------------------------------------
+# heads of 128: the decode program attends through the Pallas decode kernel
+# ---------------------------------------------------------------------------
+
+def _wide_lm(n_layer=2, d=256, heads=2, cache_len=256, seed=11):
+    """A two-layer decoder whose heads are whole 128-lane slabs, so its
+    decode program (and only that one) engages the decode kernel."""
+    rng = np.random.RandomState(seed)
+    f = lambda *s: rng.randn(*s).astype(np.float32) * 0.08  # noqa: E731
+    fc = mx.sym.FullyConnected
+    data = mx.sym.Variable("data")
+    pos = mx.sym.Variable("pos", shape=(0,), dtype="int32")
+    x = mx.sym.Embedding(data=data, input_dim=V, output_dim=d, name="emb")
+    params = {"emb_weight": f(V, d) * 6}
+    nexts = []
+    for i in range(n_layer):
+        p = "l%d_" % i
+        kc = mx.sym.Variable("kc%d" % i, shape=(0, cache_len, d))
+        vc = mx.sym.Variable("vc%d" % i, shape=(0, cache_len, d))
+        q, k, v = (fc(data=x, num_hidden=d, flatten=False, name=p + n)
+                   for n in "qkv")
+        att = mx.sym.cached_attention(q, k, v, kc, vc, pos,
+                                      num_heads=heads, alibi=True,
+                                      name=p + "att")
+        x = x + fc(data=att[0], num_hidden=d, flatten=False, name=p + "o")
+        nexts += [mx.sym.identity(att[1], name="kc%d_next" % i),
+                  mx.sym.identity(att[2], name="vc%d_next" % i)]
+        for n in "qkvo":
+            params[p + n + "_weight"] = f(d, d)
+            params[p + n + "_bias"] = np.zeros(d, np.float32)
+    out = fc(data=x, num_hidden=V, flatten=False, name="proj")
+    params.update(proj_weight=f(V, d), proj_bias=np.zeros(V, np.float32))
+    return InferenceEngine(mx.sym.Group([out] + nexts), params, {},
+                           data_shapes={"data": (1,)}, buckets=(1,),
+                           warm=False)
+
+
+def _generate_all(eng, prompts, max_new):
+    """Every prompt through one GenerateScheduler at once (more sequences
+    than slots); returns the tokens per prompt and the compiles the run
+    added after its programs were built."""
+    from mxtpu.serving.batcher import GenerateScheduler
+    for length in eng.gen_prefill_menu():
+        eng.gen_prefill_program(length)
+    eng.gen_decode_program(4)
+    eng.gen_adopt_program(4)
+    compiles = eng.cache.stats()["compiles"]
+    sched = GenerateScheduler(eng, 16, slots=4)
+    try:
+        reqs = [sched.submit("r%d" % j, prompt, max_new, None)
+                for j, prompt in enumerate(prompts)]
+        replies = [req.wait(120) for req in reqs]
+    finally:
+        sched.stop()
+    assert all(r[0] == "ok" for r in replies), replies
+    tokens = [list(r[1]["tokens"]) for r in replies]
+    return tokens, eng.cache.stats()["compiles"] - compiles
+
+
+def test_wide_heads_generate_through_the_decode_kernel(monkeypatch):
+    """Prompts of unequal length on 4 slots: the tokens are those of the
+    same model traced onto the dense formula; the decode program counts
+    n_layer nodes on the kernel and the prefill programs none; nothing
+    retraces during the run."""
+    from mxtpu.ops import nn
+    monkeypatch.setenv("MXTPU_SERVE_GENERATE_PREFILL_BUCKETS", "8,32")
+    prompts = [[3, 1, 4], [1, 5, 9, 2, 6, 5, 3, 5, 8, 9, 7, 9],
+               [2], [7, 1, 8, 2, 8, 1, 8], [1, 6, 1, 8, 0, 3],
+               list(range(1, 17)) + list(range(16, 0, -1))[:13]]
+    eng = _wide_lm()
+    got, retraces = _generate_all(eng, prompts, 12)
+    assert retraces == 0
+    st = eng.stats()
+    assert st["gen_decode_attn_path"] == 2
+    assert st["gen_prefill_attn_path"] == 0
+    with monkeypatch.context() as m:
+        m.setattr(nn, "_decode_path", lambda *a: False)
+        dense_eng = _wide_lm()
+        want, _ = _generate_all(dense_eng, prompts, 12)
+    assert dense_eng.stats()["gen_decode_attn_path"] == 0
+    assert got == want
+    assert len({tuple(t) for t in got}) > 1, "degenerate model"
+
+
+def test_sharded_engine_keeps_the_dense_formula():
+    """The kernel is one device's program: an engine over a mesh makes the
+    mesh ambient while it traces its decode program, cached_attention sees
+    it and stays on the dense formula (which GSPMD partitions), and the
+    tokens are the single-device engine's."""
+    from mxtpu.parallel import MeshContext
+    prompts = [[3, 1, 4], [1, 5, 9, 2, 6, 5, 3], [2]]
+    one = _wide_lm()
+    names = one._param_names
+    params = {n: np.asarray(v) for n, v in zip(names, one._param_vals)}
+    meshed = InferenceEngine(one._symbol, params, {},
+                             data_shapes={"data": (1,)}, buckets=(1,),
+                             warm=False, mesh=MeshContext({"model": 4}))
+    want, _ = _generate_all(one, prompts, 6)
+    got, retraces = _generate_all(meshed, prompts, 6)
+    assert got == want and retraces == 0
+    assert one.stats()["gen_decode_attn_path"] == 2
+    assert meshed.stats()["gen_decode_attn_path"] == 0
+
+
+# ---------------------------------------------------------------------------
 # the example: train -> checkpoint -> serve generate, end to end
 # ---------------------------------------------------------------------------
 
