@@ -11,6 +11,7 @@ the API (MXNet default) — XLA re-layouts internally for TPU.
 """
 from __future__ import annotations
 
+import collections
 import functools
 import os
 
@@ -248,6 +249,8 @@ def activation(data, act_type="relu"):
         return jnp.logaddexp(data, 0.0)
     if act_type == "softsign":
         return data / (1 + jnp.abs(data))
+    if act_type == "silu":
+        return jax.nn.silu(data)
     raise ValueError("unknown act_type %r" % act_type)
 
 
@@ -369,6 +372,18 @@ def layer_norm(data, gamma, beta, axis=-1, eps=1e-5, output_mean_var=False):
     out = out * gamma.astype(jnp.float32).reshape(shape) \
         + beta.astype(jnp.float32).reshape(shape)
     return out.astype(data.dtype)
+
+
+@register("RMSNorm")
+def rms_norm(data, gamma, axis=-1, eps=1e-5):
+    """``data / sqrt(mean(data^2) + eps) * gamma`` over ``axis``, the mean
+    and the scaling in float32 (Zhang & Sennrich): LayerNorm without the
+    mean and without a shift."""
+    x32 = data.astype(jnp.float32)
+    out = x32 * lax.rsqrt(jnp.mean(x32 * x32, axis=axis, keepdims=True) + eps)
+    shape = [1] * data.ndim
+    shape[axis % data.ndim] = data.shape[axis % data.ndim]
+    return (out * gamma.astype(jnp.float32).reshape(shape)).astype(data.dtype)
 
 
 @register("InstanceNorm")
@@ -663,11 +678,19 @@ def _seq_parallel_mesh(T, S, H):
 
 
 @register("cached_attention", num_outputs=3)
-def cached_attention(query, key, value, k_cache, v_cache, pos, num_heads=1,
-                     alibi=False):
+def cached_attention(query, key, value, k_cache, v_cache, pos, valid_len=None,
+                     q_gain=None, k_gain=None, num_heads=1, alibi=False,
+                     num_kv_heads=0, window=0, rope_theta=0.0, norm_eps=1e-5):
     """query/key/value ``[B, T, D]``; caches ``[B, S, D]``; ``pos [B]``
     (write offset per sample). Returns ``(out, k_cache_next,
-    v_cache_next)``. ``alibi=True`` adds the parameter-free linear
+    v_cache_next)``.
+
+    With every argument after ``alibi`` left at its default this is the
+    multi-head op as it always was. ``num_kv_heads`` (grouped queries),
+    ``window`` (a ring cache), ``rope_theta`` (rotary positions), the
+    per-head gains ``q_gain``/``k_gain`` and ``valid_len`` (a padded
+    chunk's true length) take :func:`_cached_attention_grouped`, which
+    says what each means. ``alibi=True`` adds the parameter-free linear
     distance bias (Press et al.) — per-head slope ``2^(-8(i+1)/H)``
     times the query-key distance ``(pos + t) - s``. Because the
     distance is computed from the ABSOLUTE cache positions, the bias is
@@ -685,6 +708,15 @@ def cached_attention(query, key, value, k_cache, v_cache, pos, num_heads=1,
     H = int(num_heads)
     hd = D // H
     use_alibi = bool(alibi) and str(alibi).lower() not in ("false", "0")
+    spec = _AttnSpec(H, int(num_kv_heads) or H, int(window),
+                     float(rope_theta), float(norm_eps))
+    if (spec.kv_heads != H or spec.window or spec.rope_theta
+            or valid_len is not None or q_gain is not None):
+        if use_alibi:
+            raise ValueError("cached_attention: alibi goes with the "
+                             "multi-head op only (no caller has both)")
+        return _cached_attention_grouped(query, key, value, k_cache, v_cache,
+                                         p, valid_len, q_gain, k_gain, spec)
     write = jax.vmap(
         lambda cache, rows, at: lax.dynamic_update_slice(cache, rows, (at, 0)))
     new_k = write(k_cache, key.astype(k_cache.dtype), p)
@@ -739,6 +771,159 @@ def _attend_dense(query, new_k, new_v, p, H, use_alibi):
     return out.astype(query.dtype)
 
 
+# ---------------------------------------------------------------------------
+# Grouped queries, ring caches, rotary positions, per-head norms: what a
+# decoder with 64 query heads over 8 key/value heads, window layers among
+# full ones and normalised queries and keys asks of the same op.
+# ---------------------------------------------------------------------------
+
+_AttnSpec = collections.namedtuple(
+    "_AttnSpec", "heads kv_heads window rope_theta norm_eps")
+
+_WINDOW_NODES = _obs.counter(
+    "ops.cached_attention.window_nodes",
+    "cached_attention nodes traced with a window (their cache is a ring)")
+
+
+def _rms_head(x, gain, eps):
+    """``x [..., hd]`` normalised over the head, times ``gain [hd]``."""
+    x32 = x.astype(jnp.float32)
+    y = x32 * lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps)
+    return y * gain.astype(jnp.float32)
+
+
+def _rope(x, positions, theta):
+    """Rotary positions over the whole head in half-split pairs:
+    ``x [B, T, H, hd]`` (float32), ``positions [B, T]`` absolute."""
+    half = x.shape[-1] // 2
+    freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    ang = positions.astype(jnp.float32)[:, :, None, None] * freq
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    x1, x2 = x[..., :half], x[..., half:]
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
+
+
+def _ring_write(cache, rows, p, n):
+    """A chunk into a ring: row ``r`` of the ring takes the chunk's newest
+    TRUE row whose position is ``r`` modulo the ring's length, and keeps
+    what it held where the chunk has none. ``rows [B, T, D]`` at positions
+    ``p .. p + n - 1``; rows past ``n`` are padding and are never written."""
+    B, T, _D = rows.shape
+    R = cache.shape[1]
+    if T == 1:                                 # a decode step: one true row
+        return jax.vmap(lambda c, r, a: lax.dynamic_update_slice(
+            c, r, (a, 0)))(cache, rows, p % R)
+    last = (p + n - 1)[:, None]                                  # [B, 1]
+    r_idx = jnp.arange(R, dtype=jnp.int32)[None, :]
+    at = last - (last - r_idx) % R             # newest position = r mod R
+    fresh = (at >= p[:, None]) & (n[:, None] > 0)
+    take = jnp.clip(at - p[:, None], 0, T - 1)
+    picked = jnp.take_along_axis(rows, take[:, :, None], axis=1)
+    return jnp.where(fresh[:, :, None], picked, cache)
+
+
+def _cached_attention_grouped(query, key, value, k_cache, v_cache, p,
+                              valid_len, q_gain, k_gain, spec):
+    """The op for grouped queries over caches of two kinds.
+
+    ``query [B, T, heads x hd]``, ``key``/``value [B, T, kv_heads x hd]``,
+    caches ``[B, S, kv_heads x hd]``; query head ``i`` attends key/value
+    head ``i // (heads / kv_heads)``. ``q_gain``/``k_gain [hd]``: queries
+    and keys are RMS-normalised over each head first. ``rope_theta``:
+    rotary positions at the absolute position ``pos + t``. Keys go into the
+    cache AFTER norm and rotation, so a decode step reads them as they lie.
+
+    ``window = 0``: the cache holds position ``s`` in row ``s`` and a query
+    attends every ``s <= pos + t``. ``window > 0``: the cache is a RING of
+    ``S >= window`` rows, position ``s`` in row ``s mod S``, and a query
+    attends ``0 <= pos + t - s < window``. A chunk (``T > 1``) attends the
+    ring as it was plus its own rows under that band, and leaves its last
+    ``S`` TRUE rows in the ring: ``valid_len [B]`` says how many of the
+    ``T`` rows are true (all, when absent), so padding never overwrites a
+    live row. Scores and softmax are float32. One row a sample on heads of
+    whole 128-lane slabs takes the kernel ``decode_attention``."""
+    B, T, _Dq = query.shape
+    S = k_cache.shape[1]
+    H, K = spec.heads, spec.kv_heads
+    hd = query.shape[2] // H
+    n = (jnp.full((B,), T, jnp.int32) if valid_len is None
+         else valid_len.astype(jnp.int32).reshape(-1))
+    qh = query.reshape(B, T, H, hd).astype(jnp.float32)
+    kh = key.reshape(B, T, K, hd).astype(jnp.float32)
+    if q_gain is not None:
+        qh = _rms_head(qh, q_gain, spec.norm_eps)
+        kh = _rms_head(kh, k_gain, spec.norm_eps)
+    t_idx = jnp.arange(T, dtype=jnp.int32)[None, :]
+    q_abs = p[:, None] + t_idx                                    # [B, T]
+    if spec.rope_theta:
+        qh = _rope(qh, q_abs, spec.rope_theta)
+        kh = _rope(kh, q_abs, spec.rope_theta)
+    q = qh.astype(query.dtype).reshape(B, T, H * hd)
+    k_rows = kh.astype(k_cache.dtype).reshape(B, T, K * hd)
+    v_rows = value.astype(v_cache.dtype)
+    on_kernel = _decode_path(q, k_cache, H, K)
+    if spec.window:
+        _WINDOW_NODES.inc()
+    if on_kernel and S % 16 == 0:
+        # a decode step on the kernels: the row goes in through one too
+        from .pallas_attention import cache_write_row
+        at = p % S if spec.window else jnp.clip(p, 0, S - 1)
+        new_k = cache_write_row(k_cache, k_rows, at)
+        new_v = cache_write_row(v_cache, v_rows, at)
+    elif spec.window:
+        new_k = _ring_write(k_cache, k_rows, p, n)
+        new_v = _ring_write(v_cache, v_rows, p, n)
+    else:
+        write = jax.vmap(lambda cache, rows, at:
+                         lax.dynamic_update_slice(cache, rows, (at, 0)))
+        new_k, new_v = write(k_cache, k_rows, p), write(v_cache, v_rows, p)
+    if on_kernel:
+        _DECODE_PATH_NODES.inc()
+        out = _attend_decode_grouped(q, new_k, new_v, p, spec)
+    elif spec.window:
+        # the ring as it was (row r holds the newest position below pos
+        # that is r modulo S), then the chunk's own rows
+        r_idx = jnp.arange(S, dtype=jnp.int32)[None, :]
+        prev = (p - 1)[:, None]
+        old_abs = prev - (prev - r_idx) % S
+        k_abs = jnp.concatenate([old_abs, q_abs], axis=1)         # [B, S+T]
+        live = jnp.concatenate(
+            [old_abs >= 0, t_idx < n[:, None]], axis=1)
+        out = _attend_grouped(
+            q, jnp.concatenate([k_cache, k_rows], axis=1),
+            jnp.concatenate([v_cache, v_rows], axis=1),
+            q_abs, k_abs, live, spec)
+    else:
+        s_idx = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None], (B, S))
+        out = _attend_grouped(q, new_k, new_v, q_abs, s_idx,
+                              jnp.ones((B, S), bool), spec)
+    return out, new_k, new_v
+
+
+def _attend_grouped(q, keys, values, q_abs, k_abs, live, spec):
+    """Every query row against ``keys [B, N, kv_heads x hd]`` that lie at
+    the absolute positions ``k_abs [B, N]`` (``live`` where a row holds
+    one): causal, banded by ``spec.window``, float32 scores and softmax."""
+    B, T, _ = q.shape
+    N = keys.shape[1]
+    H, K = spec.heads, spec.kv_heads
+    hd = q.shape[2] // H
+    qg = q.reshape(B, T, K, H // K, hd)
+    kg = keys.astype(q.dtype).reshape(B, N, K, hd)
+    vg = values.astype(q.dtype).reshape(B, N, K, hd)
+    scores = jnp.einsum("btkgd,bnkd->bkgtn", qg, kg,
+                        preferred_element_type=jnp.float32) * hd ** -0.5
+    dist = q_abs[:, :, None] - k_abs[:, None, :]                  # [B, T, N]
+    allowed = (dist >= 0) & live[:, None, :]
+    if spec.window:
+        allowed &= dist < spec.window
+    scores = jnp.where(allowed[:, None, None], scores, -1e30)
+    att = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+    out = jnp.einsum("bkgtn,bnkd->btkgd", att, vg,
+                     preferred_element_type=jnp.float32)
+    return out.reshape(B, T, H * hd).astype(q.dtype)
+
+
 # One query row a sample (the decode step) attends through the Pallas
 # kernel ``pallas_attention.decode_attention``, which reads K and V in the
 # [B, S, D] tiling they are stored in and only the blocks at or below
@@ -762,16 +947,19 @@ def decode_path_nodes():
     return _DECODE_PATH_NODES.default().value
 
 
-def _decode_path(query, cache, H):
+def _decode_path(query, cache, H, kv_heads=None):
     """Whether this call's shapes put it on the decode kernel."""
     B, T, D = query.shape
     if T != 1 or D % H or (D // H) % 128:
         return False
+    if kv_heads not in (None, H) and (H % kv_heads or H % 8):
+        return False        # a group's query heads are the kernel's rows
     from ..parallel.mesh import current_mesh
     if _SEQ_PARALLEL or current_mesh() is not None:
         return False
     from .pallas_attention import decode_block
-    return decode_block(cache.shape[1], D, cache.dtype) is not None
+    return decode_block(cache.shape[1], cache.shape[2],
+                        cache.dtype) is not None
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
@@ -795,6 +983,13 @@ def _attend_decode_bwd(H, use_alibi, res, g):
 
 
 _attend_decode.defvjp(_attend_decode_fwd, _attend_decode_bwd)
+
+
+def _attend_decode_grouped(q, new_k, new_v, p, spec):
+    """Forward only (serving): nothing differentiates a grouped step."""
+    from .pallas_attention import decode_attention
+    return decode_attention(q, new_k, new_v, p, spec.heads,
+                            num_kv_heads=spec.kv_heads, window=spec.window)
 
 
 # ---------------------------------------------------------------------------
@@ -822,6 +1017,96 @@ def moe_ffn(data, gate_weight, w1, b1, w2, b2, capacity_factor=1.25,
                       capacity_factor=float(capacity_factor),
                       num_selected=int(num_selected))
     return y.reshape(shape).astype(data.dtype), aux.reshape(1)
+
+
+_MOE_ASSIGNMENTS = _obs.counter(
+    "ops.moe_ffn.assignments",
+    "token-to-expert assignments an expert layer routed, held here or not",
+    ("layer",))
+_MOE_ASSIGNMENTS_HELD = _obs.counter(
+    "ops.moe_ffn.assignments_held",
+    "assignments that fell on an expert this device holds", ("layer",))
+_MOE_MAX_LOAD = _obs.gauge(
+    "ops.moe_ffn.max_load",
+    "the fullest held expert's assignments over the held experts' mean, "
+    "between the last two readings of the device sums (1 = even)",
+    ("layer",))
+_MOE_EXPERTS_HIT = _obs.gauge(
+    "ops.moe_ffn.experts_hit",
+    "held experts with at least one assignment, a one-row (decode) run of "
+    "the layer on average, between the last two readings", ("layer",))
+_MOE_EXPERTS_HIT_RUN = _obs.gauge(
+    "ops.moe_ffn.experts_hit_run",
+    "held experts with at least one assignment, a run of the layer (decode "
+    "step or chunk) on average, between the last two readings", ("layer",))
+
+# columns of ``moe_ffn_held``'s counts after the held experts' own
+MOE_LOAD_EXTRA = 5      # all assignments; experts hit, runs (one row a
+#                         sample); experts hit, runs (chunks)
+
+
+def publish_moe_load(layer, delta):
+    """Fold ``delta`` (``moe_ffn_held``'s ``load`` gained since the last
+    call: each held expert's assignments, then the ``MOE_LOAD_EXTRA``
+    columns) into the registry: the counters grow by it, the gauges say
+    how that interval went. The serving engine calls it when it reads its
+    device sums (``InferenceEngine.stats()`` has the whole per-expert
+    table); nothing reads the device in a step."""
+    held = [int(n) for n in delta[:-MOE_LOAD_EXTRA]]
+    total, hit_step, steps, hit_chunk, chunks = (
+        int(n) for n in delta[-MOE_LOAD_EXTRA:])
+    _MOE_ASSIGNMENTS.labels(layer).inc(total)
+    _MOE_ASSIGNMENTS_HELD.labels(layer).inc(sum(held))
+    if sum(held):
+        _MOE_MAX_LOAD.labels(layer).set(max(held) * len(held) / sum(held))
+    if steps:
+        _MOE_EXPERTS_HIT.labels(layer).set(hit_step / steps)
+    if steps + chunks:
+        _MOE_EXPERTS_HIT_RUN.labels(layer).set(
+            (hit_step + hit_chunk) / (steps + chunks))
+
+
+# what a generate state of kind ``sum:<name>`` feeds, by name
+SUM_PUBLISHERS = {"moe_load": publish_moe_load}
+
+
+@register("moe_ffn_held", num_outputs=2)
+def moe_ffn_held(data, router_weight, select_bias, gate_weight, up_weight,
+                 down_weight, load=None, valid_len=None, top_k=1,
+                 expert_first=0, scale=1.0):
+    """Top-``top_k`` expert layer over ALL the router's experts, computed
+    on the experts this device holds (``parallel/moe.py::moe_ffn_held``):
+    ``data [B, T, D]`` (or ``[N, D]``); ``router_weight [E, D]``;
+    ``select_bias [E]``; ``gate_weight, up_weight [held, D, F]``,
+    ``down_weight [held, F, D]``, the experts ``expert_first ..``. Returns
+    ``(y, load_next)``: ``y`` like ``data``, the held experts' weighted
+    SiLU-gated outputs; ``load_next = load + counts`` with ``counts [1,
+    held + MOE_LOAD_EXTRA]`` int32: the assignments of each held expert,
+    all assignments, then the held experts that got any and 1, in the
+    first pair of columns for a run of one row a sample (a decode step)
+    and in the second for a chunk (``load`` absent: the counts; a sum
+    wraps like any int32, its reader takes differences modulo 2^32). No
+    capacity, no dropped token.
+    ``valid_len [B]``: rows ``t >= valid_len[b]`` of a padded ``[B, T, D]``
+    chunk are padding: they are neither computed nor counted, and come
+    back zero."""
+    from ..parallel.moe import moe_ffn_held as _held
+    shape = data.shape
+    rows = None
+    if valid_len is not None and data.ndim == 3:
+        rows = (jnp.arange(shape[1], dtype=jnp.int32)[None, :]
+                < valid_len.astype(jnp.int32).reshape(-1, 1)).reshape(-1)
+    y, counts = _held(data.reshape(-1, shape[-1]), router_weight,
+                      select_bias, gate_weight, up_weight, down_weight,
+                      int(top_k), int(expert_first), float(scale), rows)
+    one_row = data.ndim == 3 and shape[1] == 1
+    run = jnp.stack([jnp.sum(counts[:-1] > 0, dtype=counts.dtype),
+                     jnp.ones((), counts.dtype)])
+    counts = jnp.concatenate([counts, run * int(one_row),
+                              run * int(not one_row)])[None]
+    if load is not None:
+        counts = load + counts.astype(load.dtype)
+    return y.reshape(shape), counts
 
 
 @register("SVMOutput")

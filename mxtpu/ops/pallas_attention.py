@@ -471,14 +471,21 @@ def _decode_call():
     from .pallas_util import per_platform
 
     def kernel(pos_ref, q_ref, slope_ref, k_ref, v_ref, o_ref,
-               qbd_ref, acc_ref, m_ref, l_ref, *, hd, block_s, scale):
+               qbd_ref, acc_ref, m_ref, l_ref, *, hd, block_s, scale, group,
+               window):
         b, j = pl.program_id(0), pl.program_id(1)
         p = pos_ref[b]
         rows, d = qbd_ref.shape
+        ring = k_ref.shape[1] * pl.num_programs(1) if window else 0
+        # the newest row a block may hold: pos itself, or the ring's end
+        newest = jnp.minimum(p, ring - 1) if window else p
 
         def own_lanes():
-            """[rows, D]: True where a lane belongs to its row's head."""
+            """[rows, D]: True where a lane belongs to its row's key/value
+            head (``group`` query heads share one)."""
             head = jax.lax.broadcasted_iota(jnp.int32, (rows, d), 0)
+            if group > 1:
+                head = head // group
             lane = jax.lax.broadcasted_iota(jnp.int32, (rows, d), 1)
             return (lane >= head * hd) & (lane < (head + 1) * hd)
 
@@ -490,13 +497,17 @@ def _decode_call():
             # gives every head's score row
             # (selected in float32: a mask over packed bfloat16 rows is a
             # relayout Mosaic refuses)
-            q = jnp.broadcast_to(q_ref[0].astype(jnp.float32), (rows, d))
+            if group == 1:
+                q = jnp.broadcast_to(q_ref[0].astype(jnp.float32), (rows, d))
+            else:       # [heads, hd] rows, each laid over every slab
+                q = jnp.concatenate(
+                    [q_ref[0].astype(jnp.float32)] * (d // hd), axis=1)
             qbd_ref[:] = jnp.where(own_lanes(), q, 0.0).astype(qbd_ref.dtype)
             acc_ref[:] = jnp.zeros_like(acc_ref)
             m_ref[:] = jnp.full_like(m_ref, _NEG)
             l_ref[:] = jnp.zeros_like(l_ref)
 
-        @pl.when(j * block_s <= p)      # a block past pos[b] costs nothing
+        @pl.when(j * block_s <= newest)  # a block past pos[b] costs nothing
         def _compute():
             cdt = qbd_ref.dtype
             s = jax.lax.dot_general(
@@ -504,13 +515,25 @@ def _decode_call():
                 preferred_element_type=jnp.float32) * scale
             at = j * block_s + jax.lax.broadcasted_iota(
                 jnp.int32, s.shape, 1)
-            s = s - slope_ref[:] * (p - at).astype(jnp.float32)
-            s = jnp.where(at <= p, s, _NEG)
+            if window:
+                # row r of a ring holds the newest position that is r
+                # modulo its length: how far back from pos that lies
+                at_p = jax.lax.rem(p, ring)
+                dist = jnp.where(at <= at_p, at_p - at, at_p - at + ring)
+                live = (dist < window) & (dist <= p)
+            else:
+                dist = p - at
+                live = at <= p
+            s = s - slope_ref[:] * dist.astype(jnp.float32)
+            s = jnp.where(live, s, _NEG)
             m_prev = m_ref[:]
             m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-            # row j*block_s is live here, so m_new is a real score and a
-            # masked column's exp underflows to exactly 0
-            pr = jnp.exp(s - m_new)
+            # a computed block holds a live row (row j*block_s without a
+            # window; in a ring, row pos mod ring or block 0's row 0), so
+            # m_new is a real score once the live block has been seen and
+            # a masked column's exp underflows to exactly 0
+            pr = jnp.where(live, jnp.exp(s - m_new), 0.0) if window \
+                else jnp.exp(s - m_new)
             corr = jnp.exp(m_prev - m_new)
             l_ref[:] = l_ref[:] * corr + jnp.sum(pr, axis=-1, keepdims=True)
             m_ref[:] = m_new
@@ -524,20 +547,30 @@ def _decode_call():
             # row h of acc is head h's weights times ALL of V's columns;
             # its own slab is the head's output
             out = jnp.where(own_lanes(), acc_ref[:] / l_ref[:], 0.0)
-            o_ref[0] = jnp.sum(out, axis=0, keepdims=True).astype(o_ref.dtype)
+            if group == 1:
+                o_ref[0] = jnp.sum(out, axis=0,
+                                   keepdims=True).astype(o_ref.dtype)
+            else:       # fold the slabs: all but a row's own are zero
+                o_ref[0] = sum(out[:, c * hd:(c + 1) * hd]
+                               for c in range(d // hd)).astype(o_ref.dtype)
 
-    @functools.partial(jax.jit, static_argnums=(5, 6))
-    def call(q, k_cache, v_cache, pos, slopes, hd, block_s):
+    @functools.partial(jax.jit, static_argnums=(5, 6, 7, 8))
+    def call(q, k_cache, v_cache, pos, slopes, hd, block_s, group=1,
+             window=0):
         B, S, D = k_cache.shape
         rows = slopes.shape[0]
         nblk = S // block_s
 
         def kv_map(b, j, pos_ref):
             # a dead block repeats the last live one: no new DMA
-            return (b, jnp.minimum(j, pos_ref[b] // block_s), 0)
+            newest = jnp.minimum(pos_ref[b], S - 1) if window else pos_ref[b]
+            return (b, jnp.minimum(j, newest // block_s), 0)
 
+        # grouped: the query and the output ride as [B, heads, hd], a
+        # head a row, which is how the kernel's matrices want them
+        q_block = (1, 1, D) if group == 1 else (1, rows, hd)
         kern = functools.partial(kernel, hd=hd, block_s=block_s,
-                                 scale=hd ** -0.5)
+                                 scale=hd ** -0.5, group=group, window=window)
         return per_platform(functools.partial(
             pl.pallas_call,
             kern,
@@ -545,12 +578,12 @@ def _decode_call():
                 num_scalar_prefetch=1,
                 grid=(B, nblk),
                 in_specs=[
-                    pl.BlockSpec((1, 1, D), lambda b, j, pos_ref: (b, 0, 0)),
+                    pl.BlockSpec(q_block, lambda b, j, pos_ref: (b, 0, 0)),
                     pl.BlockSpec((rows, 1), lambda b, j, pos_ref: (0, 0)),
                     pl.BlockSpec((1, block_s, D), kv_map),
                     pl.BlockSpec((1, block_s, D), kv_map),
                 ],
-                out_specs=pl.BlockSpec((1, 1, D),
+                out_specs=pl.BlockSpec(q_block,
                                        lambda b, j, pos_ref: (b, 0, 0)),
                 scratch_shapes=[
                     pltpu.VMEM((rows, D), q.dtype),
@@ -558,11 +591,68 @@ def _decode_call():
                     pltpu.VMEM((rows, 1), jnp.float32),
                     pltpu.VMEM((rows, 1), jnp.float32),
                 ]),
-            out_shape=jax.ShapeDtypeStruct((B, 1, D), q.dtype),
+            out_shape=jax.ShapeDtypeStruct((B,) + q_block[1:], q.dtype),
             name="decode_attention",
         ), pos, q, slopes, k_cache, v_cache)
 
     return call
+
+
+WRITE_ROWS = 16     # rows of the block a row write carries (a bf16 tile)
+
+
+@functools.cache
+def _write_call():
+    """Build the row-write kernel's pallas_call wrapper on first use."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    from .pallas_util import per_platform
+
+    def kernel(at_ref, row_ref, cache_ref, out_ref):
+        r = at_ref[pl.program_id(0)] % WRITE_ROWS
+        shape = out_ref.shape[1:]
+        # selected in float32, as the decode kernel's query matrix is
+        old = cache_ref[0].astype(jnp.float32)
+        new = jnp.broadcast_to(row_ref[0].astype(jnp.float32), shape)
+        here = jax.lax.broadcasted_iota(jnp.int32, shape, 0) == r
+        out_ref[0] = jnp.where(here, new, old).astype(out_ref.dtype)
+
+    @jax.jit
+    def call(cache, rows, at):
+        B, _S, D = cache.shape
+
+        def block(b, at_ref):
+            return (b, at_ref[b] // WRITE_ROWS, 0)
+
+        return per_platform(functools.partial(
+            pl.pallas_call,
+            kernel,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1,
+                grid=(B,),
+                in_specs=[pl.BlockSpec((1, 1, D), lambda b, at_ref: (b, 0, 0)),
+                          pl.BlockSpec((1, WRITE_ROWS, D), block)],
+                out_specs=pl.BlockSpec((1, WRITE_ROWS, D), block)),
+            out_shape=jax.ShapeDtypeStruct(cache.shape, cache.dtype),
+            input_output_aliases={2: 0},       # the cache, in place
+            name="cache_write_row",
+        ), at, rows.astype(cache.dtype), cache)
+
+    return call
+
+
+def cache_write_row(cache, rows, at):
+    """``cache [B, S, D]`` with row ``at[b]`` of slot ``b`` replaced by
+    ``rows[b, 0]``, in place when the cache is donated: one kernel over the
+    slots, each moving the block of ``WRITE_ROWS`` rows that holds its row.
+    XLA's own scatter of one row a slot is a ``while`` of a trip a slot (4.3
+    us a trip on the v5e, and several trace events each: 64 slots and 16
+    caches of them fill a profile before its window opens, PERF.md PR 30).
+    Needs ``S`` in whole blocks; ``at`` inside the cache."""
+    if cache.shape[1] % WRITE_ROWS:
+        raise MXNetError("cache_write_row: %d rows are not whole blocks of "
+                         "%d" % (cache.shape[1], WRITE_ROWS))
+    return _write_call()(cache, rows, at.astype(jnp.int32).reshape(-1))
 
 
 def decode_block(S, D, cache_dtype, block_s=None):
@@ -593,14 +683,21 @@ def decode_block(S, D, cache_dtype, block_s=None):
 
 
 def decode_attention(q, k_cache, v_cache, pos, num_heads, alibi=False,
-                     block_s=None):
+                     block_s=None, num_kv_heads=None, window=0):
     """One query row a sample against the packed cache where it lies.
 
-    ``q [B, 1, D]``, caches ``[B, S, D]`` (row ``pos[b]`` already
-    written), ``pos [B]`` int32. Slot ``b`` attends rows ``s <= pos[b]``
-    of its own cache; ``alibi`` subtracts ``2^(-8(h+1)/H) * (pos[b] -
-    s)`` from head ``h``'s scores. Returns ``[B, 1, D]`` in ``q``'s
-    dtype.
+    ``q [B, 1, heads x hd]``, caches ``[B, S, kv_heads x hd]`` (row
+    ``pos[b]`` already written), ``pos [B]`` int32. Slot ``b`` attends
+    rows ``s <= pos[b]`` of its own cache; ``alibi`` subtracts
+    ``2^(-8(h+1)/H) * (pos[b] - s)`` from head ``h``'s scores. Returns
+    ``[B, 1, heads x hd]`` in ``q``'s dtype.
+
+    ``num_kv_heads`` under ``num_heads``: query head ``h`` attends
+    key/value head ``h // (heads / kv_heads)``; the group's query heads
+    are rows of one matrix against the shared column block. ``window >
+    0``: the cache is a ring (position ``s`` in row ``s mod S``, the row
+    of ``pos[b]`` already written) and a slot attends the ring's live rows
+    within ``window`` positions of ``pos[b]``.
 
     The cache is read in the layout it is stored in: the grid is
     (slot, block of rows), a block holds ALL heads' columns, and the
@@ -609,21 +706,32 @@ def decode_attention(q, k_cache, v_cache, pos, num_heads, alibi=False,
     a block past a slot's live length is neither fetched nor computed.
     Scores, the running maximum and sum and the output accumulate in
     float32. Needs a head of whole 128-lane slabs and ``block_s``
-    dividing ``S``; forward only (``ops.nn.cached_attention`` gives it
-    the dense formula's gradient)."""
+    dividing ``S``; forward only (``ops.nn.cached_attention`` gives the
+    multi-head call the dense formula's gradient; nothing differentiates
+    a grouped one)."""
     B, S, D = k_cache.shape
     H = int(num_heads)
-    hd = D // H
+    K = int(num_kv_heads or H)
+    hd = D // K
     blk = decode_block(S, D, k_cache.dtype, block_s)
-    if hd % 128 or blk is None:
+    if hd % 128 or blk is None or H % K or (K != H and H % 8):
         raise MXNetError(
-            "decode_attention: head dim %d must be a multiple of 128 and "
-            "the block must divide the cache length %d" % (hd, S))
+            "decode_attention: head dim %d must be a multiple of 128, the "
+            "block must divide the cache length %d, and %d query heads "
+            "over %d key/value heads must group into whole rows of 8"
+            % (hd, S, H, K))
     rows = -(-H // 8) * 8
     slopes = [2.0 ** (-8.0 * (i + 1) / H) if alibi and i < H else 0.0
               for i in range(rows)]
-    # a slot the scheduler left idle may count past the cache: it holds
-    # nothing anyone reads, only keep its block index inside the array
-    p = jnp.clip(pos.astype(jnp.int32).reshape(-1), 0, S - 1)
-    return _decode_call()(q, k_cache, v_cache, p,
-                          jnp.asarray(slopes, jnp.float32)[:, None], hd, blk)
+    p = pos.astype(jnp.int32).reshape(-1)
+    if not window:
+        # a slot the scheduler left idle may count past the cache: it holds
+        # nothing anyone reads, only keep its block index inside the array
+        p = jnp.clip(p, 0, S - 1)
+    slopes = jnp.asarray(slopes, jnp.float32)[:, None]
+    if K == H:
+        return _decode_call()(q, k_cache, v_cache, p, slopes, hd, blk, 1,
+                              int(window))
+    out = _decode_call()(q.reshape(B, H, hd), k_cache, v_cache, p, slopes,
+                         hd, blk, H // K, int(window))
+    return out.reshape(B, 1, H * hd)

@@ -12,6 +12,7 @@ auxiliary loss, fully differentiable.
 """
 from __future__ import annotations
 
+import functools
 import math
 
 import jax
@@ -19,7 +20,9 @@ import jax.numpy as jnp
 
 from .mesh import MeshContext, ShardingRules, PartitionSpec, AXIS_EXPERT
 
-__all__ = ["moe_dispatch", "moe_ffn", "expert_sharding_rules"]
+__all__ = ["moe_dispatch", "moe_ffn", "expert_sharding_rules",
+           "route_topk", "held_assignments", "grouped_matmul",
+           "moe_ffn_held"]
 
 
 def moe_dispatch(gate_logits, capacity, num_selected=1):
@@ -91,3 +94,108 @@ def expert_sharding_rules(extra=None):
         (r".*expert.*weight", PartitionSpec(AXIS_EXPERT)),
     ]
     return ShardingRules(rules + list(extra or []))
+
+
+# ---------------------------------------------------------------------------
+# Top-k routing over ALL experts, computed on the experts held HERE.
+#
+# What a large sparse decoder's expert layer asks for, and what expert
+# parallelism asks of every device: a router as wide as the model has
+# experts, and a device that is told which of them it holds
+# (``expert_first .. expert_first + experts_held - 1``) and adds their part
+# of the result. No capacity, so no token is dropped and no [T, E, C]
+# tensor exists: the assignments that fall on held experts are sorted by
+# expert and go through one grouped matrix product per weight.
+# ---------------------------------------------------------------------------
+
+def route_topk(x, router_w, select_bias, top_k, scale=1.0):
+    """``x [N, D]``, ``router_w [E, D]``, ``select_bias [E]``. Scores are
+    the sigmoid of ``x router_w^T`` in float32; the
+    ``top_k`` experts with the largest ``score + select_bias`` are chosen
+    (the bias only selects); their scores, renormalised to sum to one and
+    times ``scale``, are the weights. Returns ``(experts [N, k] int32,
+    weights [N, k] float32)``."""
+    logits = jnp.einsum("nd,ed->ne", x, router_w,
+                        preferred_element_type=jnp.float32)
+    s = jax.nn.sigmoid(logits)
+    _top, experts = jax.lax.top_k(s + select_bias.astype(jnp.float32), top_k)
+    w = jnp.take_along_axis(s, experts, axis=1)
+    w = scale * w / jnp.sum(w, axis=1, keepdims=True)
+    return experts.astype(jnp.int32), w
+
+
+def held_assignments(experts, experts_held, expert_first, rows=None):
+    """Sort the assignments ``experts [N, k]`` by held expert. Returns
+    ``(order [N k], sizes [experts_held], n_held)``: ``order`` lists the
+    flat assignments (token ``i // k``) with those on held experts first,
+    grouped by expert; ``sizes`` counts each held expert's. ``rows [N]``
+    (bool): only these tokens' assignments count as held (padding is
+    nobody's)."""
+    local = experts.reshape(-1) - expert_first
+    held = (local >= 0) & (local < experts_held)
+    if rows is not None:
+        held &= jnp.repeat(rows, experts.shape[1])
+    key = jnp.where(held, local, experts_held)
+    order = jnp.argsort(key, stable=True)
+    sizes = jnp.sum(key[:, None] == jnp.arange(experts_held)[None, :],
+                    axis=0, dtype=jnp.int32)
+    return order, sizes, jnp.sum(sizes)
+
+
+# rows, contraction and columns of a tile of the grouped product: timed on
+# the v5e at the widths of a 6144 x 2048 expert (PERF.md, PR 30)
+GROUPED_TILING = (128, 512, 2048)
+
+
+def grouped_matmul(rows, w, sizes):
+    """``rows [M, K]``, sorted by group, times ``w [G, K, N]``: the rows of
+    group ``i`` (``sizes[i]`` of them, in order) against ``w[i]``, float32
+    out. Rows past ``sum(sizes)`` come back undefined. The Pallas kernel is
+    JAX's own ``megablox.gmm``: a tile of rows visits only the groups it
+    holds rows of, so each held expert's matrix is read about once a call
+    whatever the number of rows."""
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+    from ..ops.pallas_util import per_platform
+    tiling = tuple(min(t, n) for t, n in zip(
+        GROUPED_TILING, (rows.shape[0], rows.shape[1], w.shape[2])))
+    return per_platform(
+        lambda interpret: functools.partial(
+            gmm, preferred_element_type=jnp.float32, tiling=tiling,
+            interpret=interpret), rows, w, sizes)
+
+
+def moe_ffn_held(x, router_w, select_bias, w_gate, w_up, w_down, top_k,
+                 expert_first=0, scale=1.0, rows=None):
+    """The held experts' part of a SiLU-gated expert layer.
+
+    ``x [N, D]``; ``router_w [E, D]`` over ALL ``E`` experts;
+    ``w_gate, w_up [held, D, F]``, ``w_down [held, F, D]`` the experts
+    ``expert_first .. expert_first + held - 1``. Returns ``(y [N, D], load
+    [held + 1] int32)``: ``y = sum over a token's chosen experts that are
+    held of weight x expert(x)``, ``expert(x) = (silu(x Wg) * (x Wu)) Wd``;
+    ``load`` counts the assignments of each held expert and, last, all
+    of them. A token none of whose experts is held gets zeros: what the
+    other devices' experts add is theirs to add. ``rows [N]`` (bool) marks
+    the tokens that are real; the others (a padded chunk's tail) are
+    neither computed nor counted."""
+    n, d = x.shape
+    held = w_gate.shape[0]
+    experts, weights = route_topk(x, router_w, select_bias, top_k, scale)
+    order, sizes, n_held = held_assignments(experts, held, expert_first,
+                                            rows)
+    n_rows = n if rows is None else jnp.sum(rows, dtype=jnp.int32)
+    # whole tiles of rows: the padding lies past the held rows, in no group
+    tile = min(GROUPED_TILING[0], -(-order.shape[0] // 8) * 8)
+    order = jnp.pad(order, (0, -order.shape[0] % tile))
+    token = order // top_k
+    rows = jnp.take(x, token, axis=0)                        # [N k, D]
+    h = (jax.nn.silu(grouped_matmul(rows, w_gate, sizes))
+         * grouped_matmul(rows, w_up, sizes))
+    out = grouped_matmul(h.astype(x.dtype), w_down, sizes)   # [N k, D] f32
+    w_sorted = jnp.take(weights.reshape(-1), order)
+    live = jnp.arange(order.shape[0]) < n_held   # rows past the held ones
+    out = jnp.where(live[:, None], out * w_sorted[:, None], 0.0)
+    y = jnp.zeros((n, d), jnp.float32).at[token].add(out)
+    load = jnp.concatenate([sizes, jnp.reshape(n_rows * top_k, (1,))
+                            .astype(jnp.int32)])
+    return y.astype(x.dtype), load

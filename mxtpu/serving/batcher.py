@@ -848,6 +848,10 @@ class GenerateScheduler:
         self.release_metrics()
 
     def stats(self):
+        # what the engine summed on the device (per-expert load) is folded
+        # into the registry whenever someone asks how things stand: the
+        # registry's gauges then say how the time since the last asking went
+        self._engine.gen_publish_sums()
         out = {f: s.value for f, s in self._c.items()}
         out.update({f: s.value for f, s in self._g.items()})
         with self._cv:

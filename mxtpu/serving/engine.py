@@ -176,16 +176,17 @@ class InferenceEngine:
                                   and n not in arg_params)
         self._aux_names = tuple(aux_names)
         self._gen = self._detect_generate()
+        self._gen_sums = None          # device sums, made on first use
+        self._sums_published = {}      # state -> totals already folded in
+        self._sums_lock = threading.Lock()
         # one shared device-resident copy of params/aux for all buckets,
         # per weight VERSION: an immutable store tuple swap_weights
         # replaces wholesale (programs take params as runtime arguments,
         # so a same-shape swap is always a program-cache hit)
-        param_vals = tuple(
-            self._put_named(n, self._host_array(arg_params[n]))
-            for n in self._param_names)
-        aux_vals = tuple(
-            self._put_named(n, self._host_array(aux_params[n]))
-            for n in self._aux_names)
+        param_vals = tuple(self._put_param(n, arg_params[n])
+                           for n in self._param_names)
+        aux_vals = tuple(self._put_param(n, aux_params[n])
+                         for n in self._aux_names)
         self._param_shapes = tuple((v.shape, _np.dtype(v.dtype))
                                    for v in param_vals)
         self._aux_shapes = tuple((v.shape, _np.dtype(v.dtype))
@@ -250,6 +251,14 @@ class InferenceEngine:
         if self._mesh is None:
             return self._dev
         return self._rules.sharding_for(self._mesh, name, tuple(shape))
+
+    def _put_param(self, name, v):
+        """A store array from what the caller handed in. A ``jax.Array``
+        goes from device to device (12 GB of weights would otherwise cross
+        the host twice); everything else through ``numpy``."""
+        if isinstance(v, jax.Array):
+            return jax.device_put(v, self._placement(name, v.shape))
+        return self._put_named(name, self._host_array(v))
 
     def _put_named(self, name, host):
         host = _np.asarray(host)
@@ -326,8 +335,11 @@ class InferenceEngine:
         return sorted(devs, key=lambda d: d.id)
 
     def stats(self):
+        sums = self.gen_publish_sums()
         with self._stats_lock:
             out = dict(self._stats)
+        if sums:
+            out["gen_sums"] = sums
         out.update(self.cache.stats())
         out.update(self.version_state())
         return out
@@ -780,6 +792,22 @@ class InferenceEngine:
     # ``n`` (a KV/state cache, declared per-sample shape (0, S, ...))
     # an output named ``n + "_next"`` carrying its updated value.
     # ``example/char_lm`` builds it; any symbol shaped this way serves.
+    #
+    # Each state keeps its OWN length, and says what kind it is through
+    # its variable's ``__state_kind__`` attribute:
+    #   * ``full`` (the default): row ``s`` holds position ``s``; it grows
+    #     with the sequence, so the shortest of them is the sequence
+    #     ceiling and clamps the prefill menu;
+    #   * ``ring``: a window layer's cache, position ``s`` in row ``s mod
+    #     rows``; its length bounds nothing;
+    #   * ``sum:<publisher>``: no slot dimension at all. One ``(1, ...)``
+    #     array the ENGINE holds; every program adds into it on the device
+    #     (a prefill's part is added at adoption), it is never donated, and
+    #     ``stats()`` reads it (``ops.nn.SUM_PUBLISHERS`` names what it
+    #     feeds in the registry): counts per step without a read per step.
+    # An optional extra var ``len`` (declared shape (0,)) is fed the number
+    # of TRUE rows of the chunk: the prompt's length in a padded prefill,
+    # one in a decode step. A ring needs it to keep padding out.
     def _detect_generate(self):
         if len(self._data_names) != 1:
             return None
@@ -788,20 +816,32 @@ class InferenceEngine:
         out_idx = {n: i for i, n in
                    enumerate(self._symbol.list_outputs())}
         declared = self._declared_var_specs()
-        states = []
+        attrs = self._symbol.attr_dict()
+        states, kinds = [], []
         for n in self._extra_names:
-            if n == "pos":
+            if n in ("pos", "len"):
                 continue
             i = out_idx.get(n + "_next_output")
             spec = declared.get(n)
             if i is None or spec is None or len(spec[0]) < 2:
                 return None
             states.append((n, tuple(spec[0][1:]), spec[1], i))
+            kinds.append(attrs.get(n, {}).get("__state_kind__", "full"))
         if not states:
             return None
+        grows = [s[1][0] for s, k in zip(states, kinds) if k == "full"] \
+            or [s[1][0] for s in states]
+        # a lane packs every kind but sums by slot; the engine holds those
+        slot = tuple(i for i, k in enumerate(kinds)
+                     if not k.startswith("sum"))
+        sums = tuple(i for i in range(len(kinds)) if i not in slot)
         return {"tok": self._data_names[0], "pos": "pos",
-                "states": tuple(states),
-                "cache_len": int(min(s[1][0] for s in states))}
+                "len": "len" if "len" in self._extra_names else None,
+                "states": tuple(states), "kinds": tuple(kinds),
+                "slot_states": slot, "sum_states": sums,
+                "slot_specs": tuple(states[i] for i in slot),
+                "sum_specs": tuple(states[i] for i in sums),
+                "cache_len": int(min(grows))}
 
     @property
     def is_generative(self):
@@ -815,6 +855,11 @@ class InferenceEngine:
             return None
         return {"token_input": self._gen["tok"],
                 "states": [n for n, _s, _d, _i in self._gen["states"]],
+                "state_rows": {n: s[0] for n, s, _d, _i
+                               in self._gen["states"]},
+                "state_kinds": dict(zip(
+                    (n for n, _s, _d, _i in self._gen["states"]),
+                    self._gen["kinds"])),
                 "cache_len": self._gen["cache_len"],
                 "prefill_buckets": list(self.gen_prefill_menu()),
                 "slots": gen_slots(),
@@ -853,7 +898,11 @@ class InferenceEngine:
         win), replicated when unmatched, the lone device when no mesh
         is configured."""
         return tuple(self._placement(n, (K,) + s)
-                     for n, s, _dt, _i in self._gen["states"])
+                     for n, s, _dt, _i in self._gen["slot_specs"])
+
+    def _sum_abs(self):
+        return tuple(self._abs((1,) + s, dt, self._placement(n, (1,) + s))
+                     for n, s, dt, _i in self._gen["sum_specs"])
 
     def _build_gen_prefill(self, L):
         """Prompt in (padded to bucket ``L``, batch 1) -> (first greedy
@@ -871,6 +920,8 @@ class InferenceEngine:
             feed.update(zip(aux_names, aux_vals))
             feed[tok_name] = tokens
             feed[pos_name] = jnp.zeros((1,), jnp.int32)
+            if g["len"]:
+                feed[g["len"]] = length.astype(jnp.int32)
             for n, s, dt, _i in states:
                 feed[n] = jnp.zeros((1,) + s, dt)
             with rng_scope(jax.random.PRNGKey(0)):
@@ -917,17 +968,22 @@ class InferenceEngine:
         constant cost; adoption overwrites their rows."""
         g = self._gen
         tok_name, pos_name = g["tok"], g["pos"]
-        states = g["states"]
+        states, sums = g["slot_specs"], g["sum_specs"]
         state_names = tuple(n for n, _s, _d, _i in states)
+        sum_names = tuple(n for n, _s, _d, _i in sums)
         param_names, aux_names = self._param_names, self._aux_names
         outputs_ref = self._symbol._outputs
 
-        def decode_fn(tok_feed, pos, state_vals, param_vals, aux_vals):
+        def decode_fn(tok_feed, pos, state_vals, param_vals, aux_vals,
+                      sum_vals):
             feed = dict(zip(param_names, param_vals))
             feed.update(zip(aux_names, aux_vals))
             feed[tok_name] = tok_feed
             feed[pos_name] = pos
+            if g["len"]:
+                feed[g["len"]] = jnp.ones_like(pos)
             feed.update(zip(state_names, state_vals))
+            feed.update(zip(sum_names, sum_vals))
             with rng_scope(jax.random.PRNGKey(0)):
                 outs, _aux = eval_graph(outputs_ref, feed, False)
             logits = outs[0]
@@ -935,8 +991,9 @@ class InferenceEngine:
                 logits = logits[:, -1, :]
             nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             new_states = tuple(outs[i] for _n, _s, _dt, i in states)
+            new_sums = tuple(outs[i] for _n, _s, _dt, i in sums)
             return (nxt, nxt[:, None].astype(tok_feed.dtype),
-                    pos + 1, new_states)
+                    pos + 1, new_states, new_sums)
 
         state_sh = self._gen_state_placements(K)
         if self._mesh is None:
@@ -946,7 +1003,8 @@ class InferenceEngine:
             # carries the sharded KV caches across steps reshard-free
             repl = self._mesh.replicated()
             jitted = jax.jit(decode_fn, donate_argnums=(0, 1, 2),
-                             out_shardings=(repl, repl, repl, state_sh))
+                             out_shardings=(repl, repl, repl, state_sh,
+                                            tuple(repl for _ in sums)))
         param_abs, aux_abs = self._store_abs()
         state_abs = tuple(
             self._abs((K,) + s, dt, sh)
@@ -962,7 +1020,7 @@ class InferenceEngine:
                 lowered = jitted.lower(
                     self._abs((K, 1), self._dtype),
                     self._abs((K,), _np.int32),
-                    state_abs, param_abs, aux_abs)
+                    state_abs, param_abs, aux_abs, self._sum_abs())
             return lowered.compile()
 
     def _build_gen_adopt(self, K):
@@ -970,11 +1028,10 @@ class InferenceEngine:
         the packed batch (donated in place) — how a queued sequence
         joins the in-flight batch at a step boundary without draining
         it."""
-        g = self._gen
-        states = g["states"]
+        states = self._gen["slot_specs"]
 
         def adopt_fn(tok_feed, pos, state_vals, row_tok, row_pos,
-                     row_states, slot):
+                     row_states, slot, sum_vals, sum_rows):
             slot = slot.astype(jnp.int32)
             tok_feed = lax.dynamic_update_slice(
                 tok_feed, row_tok.reshape(1, 1).astype(tok_feed.dtype),
@@ -985,7 +1042,10 @@ class InferenceEngine:
                 lax.dynamic_update_slice(
                     s, r.astype(s.dtype), (slot,) + (0,) * (s.ndim - 1))
                 for s, r in zip(state_vals, row_states))
-            return tok_feed, pos, new_states
+            # what the prefill counted joins the engine's sums here
+            new_sums = tuple(s + r.astype(s.dtype)
+                             for s, r in zip(sum_vals, sum_rows))
+            return tok_feed, pos, new_states, new_sums
 
         state_sh = self._gen_state_placements(K)
         if self._mesh is None:
@@ -993,7 +1053,8 @@ class InferenceEngine:
         else:
             repl = self._mesh.replicated()
             jitted = jax.jit(adopt_fn, donate_argnums=(0, 1, 2),
-                             out_shardings=(repl, repl, state_sh))
+                             out_shardings=(repl, repl, state_sh, tuple(
+                                 repl for _ in self._gen["sum_specs"])))
         state_abs = tuple(
             self._abs((K,) + s, dt, sh)
             for (_n, s, dt, _i), sh in zip(states, state_sh))
@@ -1010,7 +1071,8 @@ class InferenceEngine:
                 self._abs((1,), _np.int32),
                 self._abs((1,), _np.int32),
                 row_abs,
-                self._abs((), _np.int32)).compile()
+                self._abs((), _np.int32),
+                self._sum_abs(), self._sum_abs()).compile()
 
     def _require_gen(self):
         if self._gen is None:
@@ -1047,8 +1109,54 @@ class InferenceEngine:
         states = tuple(
             jax.device_put(_np.zeros((K,) + s, dt), sh)
             for (_n, s, dt, _i), sh in zip(
-                self._gen["states"], self._gen_state_placements(K)))
+                self._gen["slot_specs"], self._gen_state_placements(K)))
+        by_kind = {}
+        for (_n, s, dt, _i), kind in zip(self._gen["states"],
+                                         self._gen["kinds"]):
+            kind = kind.partition(":")[0]
+            rows = 1 if kind == "sum" else K
+            by_kind[kind] = by_kind.get(kind, 0) + rows * int(
+                _np.prod(s)) * _np.dtype(dt).itemsize
+        with self._stats_lock:
+            self._stats["gen_state_bytes"] = by_kind
         return [tok_feed, pos, states]
+
+    def _sums(self):
+        """The engine's device sums (zeros until a program has run)."""
+        if self._gen_sums is None:
+            self._gen_sums = tuple(
+                jax.device_put(_np.zeros((1,) + s, dt),
+                               self._placement(n, (1,) + s))
+                for n, s, dt, _i in self._gen["sum_specs"])
+        return self._gen_sums
+
+    def gen_publish_sums(self):
+        """Read the device sums (one small transfer; never inside a step)
+        and fold what they gained since the last reading into the
+        registry. Returns ``{state: totals}``, the totals since the start
+        kept here in int64: the device's int32 sums wrap (after 2^31
+        assignments, 20 hours at 30,000 a second), so what a sum gained is
+        its difference modulo 2^32. The scheduler's thread replaces
+        ``_gen_sums`` with every program it runs and takes no lock for it:
+        a sum is never donated, so whichever tuple is read here is whole."""
+        if self._gen is None or not self._gen["sum_states"]:
+            return {}
+        from ..ops.nn import SUM_PUBLISHERS
+        with self._sums_lock:
+            now = [_np.asarray(v).reshape(-1).astype(_np.int64)
+                   for v in jax.device_get(self._sums())]
+            out = {}
+            for i, value in zip(self._gen["sum_states"], now):
+                name = self._gen["states"][i][0]
+                seen, total = self._sums_published.get(name, (0, 0))
+                delta = (value - seen) & 0xFFFFFFFF
+                publish = SUM_PUBLISHERS.get(
+                    self._gen["kinds"][i].partition(":")[2])
+                if publish is not None:
+                    publish(name, delta)
+                self._sums_published[name] = (value, total + delta)
+                out[name] = (total + delta).tolist()
+            return out
 
     def gen_prefill(self, tokens, param_vals, aux_vals):
         """Prefill one prompt against an explicit store. Returns
@@ -1078,8 +1186,9 @@ class InferenceEngine:
         self._require_gen()
         K = int(state[0].shape[0])
         program = self.gen_decode_program(K)
-        nxt, tok_feed, pos, new_states = program(
-            state[0], state[1], state[2], param_vals, aux_vals)
+        nxt, tok_feed, pos, new_states, self._gen_sums = program(
+            state[0], state[1], state[2], param_vals, aux_vals,
+            self._sums())
         self._note("gen_steps")
         return nxt, [tok_feed, pos, new_states]
 
@@ -1090,9 +1199,12 @@ class InferenceEngine:
         self._require_gen()
         K = int(state[0].shape[0])
         program = self.gen_adopt_program(K)
-        tok_feed, pos, new_states = program(
+        g = self._gen
+        tok_feed, pos, new_states, self._gen_sums = program(
             state[0], state[1], state[2], first_tok,
-            _np.asarray([plen], _np.int32), rows, _np.int32(slot))
+            _np.asarray([plen], _np.int32),
+            tuple(rows[i] for i in g["slot_states"]), _np.int32(slot),
+            self._sums(), tuple(rows[i] for i in g["sum_states"]))
         return [tok_feed, pos, new_states]
 
     # -- prewarm: export/import the AOT program menu (ISSUE 16) --------

@@ -339,13 +339,15 @@ class _ScalarConst:
 _OPTIONAL_ARRAY_PARAMS = {"bias", "gamma", "state", "state_cell", "weight32",
                           "parameters", "crop_like", "trans",
                           "sequence_length", "data_lengths",
-                          "label_lengths"}
+                          "label_lengths", "valid_len", "q_gain", "k_gain",
+                          "load"}
 
 # optional array inputs that are genuinely absent when not supplied — no
 # implicit variable is auto-created for them (unlike bias/state, which are
 # real parameters the frontend materializes)
 _OPTIONAL_NO_AUTO = {"crop_like", "trans", "sequence_length",
-                     "data_lengths", "label_lengths"}
+                     "data_lengths", "label_lengths", "valid_len", "q_gain",
+                     "k_gain", "load"}
 
 
 def _array_input_names(op, params):
@@ -665,7 +667,13 @@ def eval_graph(sym_outputs, feed, training=False):
             # operator and node it came from in its op_name, forward and
             # transposed: what a device trace's `fusion` is made of
             with jax.named_scope("%s/%s" % (node.op.name, node.name)):
-                out = node.op.fn(*in_vals, **params)
+                if _OPTIONAL_NO_AUTO.intersection(node.input_names):
+                    # an optional input left out ahead of one that is
+                    # given would shift the positions: bind by name
+                    out = node.op.fn(**dict(zip(node.input_names, in_vals)),
+                                     **params)
+                else:
+                    out = node.op.fn(*in_vals, **params)
             vals = out if isinstance(out, tuple) else (out,)
             for in_pos, out_idx in node.op.aux_update.items():
                 if in_pos < len(node.inputs):
@@ -757,6 +765,14 @@ def _ln_hint(params, in_shapes, input_names):
     axis = int(params.get("axis", -1)) % len(data)
     c = (data[axis],)
     return {"gamma": c, "beta": c}
+
+
+@shape_hint("RMSNorm")
+def _rms_hint(params, in_shapes, input_names):
+    data = in_shapes.get("data")
+    if data is None:
+        return {}
+    return {"gamma": (data[int(params.get("axis", -1)) % len(data)],)}
 
 
 @shape_hint("InstanceNorm")

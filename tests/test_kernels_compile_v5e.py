@@ -211,3 +211,34 @@ def test_decode_over_limit_block_raises_the_frameworks_error(v5e):
     size = _refused(lambda q, kc, vc, pos, sl: _decode_call()(
         q, kc, vc, pos, sl, D // H, 1024), q, kc, vc, pos, slopes)
     assert size > 16.0
+
+
+# -- grouped queries and ring caches (kexaone-reasoning-saturated's decode step) --
+
+KEXAONE = dict(B=64, H=64, K=8, hd=128, window=128)
+
+
+@pytest.mark.parametrize("rows,window", [(2048, 0), (128, 128)])
+def test_grouped_decode_step_at_the_cells_shapes(v5e, rows, window):
+    """64 query heads over 8 key/value heads, 64 slots: a full layer's cache
+    of 2048 rows and a window layer's ring of 128. The op is the Mosaic
+    kernel, both caches are written in place, nothing cache-sized is kept."""
+    from mxtpu.ops.nn import cached_attention, decode_path_nodes
+    B, H, K, hd = (KEXAONE[k] for k in ("B", "H", "K", "hd"))
+    S_ = functools.partial(jax.ShapeDtypeStruct, sharding=v5e)
+    q, kv = S_((B, 1, H * hd), jnp.bfloat16), S_((B, 1, K * hd), jnp.bfloat16)
+    cache = S_((B, rows, K * hd), jnp.bfloat16)
+    gain = S_((hd,), jnp.bfloat16)
+    before = decode_path_nodes()
+    compiled = jax.jit(
+        lambda q, k, v, kc, vc, pos, n, qg, kg: cached_attention(
+            q, k, v, kc, vc, pos, n, qg, kg, num_heads=H, num_kv_heads=K,
+            window=window, rope_theta=1e6 if window else 0.0),
+        donate_argnums=(3, 4)).trace(
+            q, kv, kv, cache, cache, S_((B,), jnp.int32), S_((B,), jnp.int32),
+            gain, gain).lower(lowering_platforms=("tpu",)).compile()
+    assert decode_path_nodes() == before + 1
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == 2 * B * rows * K * hd * 2
+    assert mem.temp_size_in_bytes < 8 * 2 ** 20
