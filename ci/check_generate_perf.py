@@ -9,8 +9,11 @@ replica in this process and pinned so a regression fails CI:
   2. **Zero hidden host syncs**: the whole sustained load runs with
      JAX's device-to-host transfer guard set to ``disallow`` — the
      decode loop's ONE explicit per-step ``device_get`` (the token
-     read) is allowed, any implicit ``np.asarray`` on device state
-     would raise and fail the run.
+     read; since ISSUE 31 it reads the step dispatched the turn
+     BEFORE, whose copy the scheduler started explicitly with
+     ``copy_to_host_async`` when it went out) is allowed, any
+     implicit ``np.asarray`` on device state would raise and fail
+     the run.
   3. **Batching wins**: tokens/s at 64 concurrent sequences must be at
      least ``SPEEDUP_PIN``x tokens/s at 8 — the fixed-capacity packed
      decode step amortises dispatch across active slots, so throughput

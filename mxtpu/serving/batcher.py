@@ -386,6 +386,23 @@ class DynamicBatcher:
 # throughput story tools/bench_serving.py measures and
 # ci/check_generate_perf.py pins.
 #
+# One step stays in flight per lane: the decode program keeps the next
+# step's feed on the device, so a turn of a lane dispatches step n+1
+# FIRST and only then reads step n's tokens (the one host read a step),
+# emits them and admits. The host's bookkeeping (eos, max_new, deadline,
+# freeing a slot) therefore runs one dispatch late, and the device never
+# waits for it. At dispatch the lane notes who sat in each slot; a row
+# is emitted only to the sequence that sat there then and still does.
+# The row a step computed for a sequence that had already left (it
+# finished, expired or was dropped in the step before) is a DISCARDED
+# row: never emitted, never counted in ``tokens``; it lands at most at
+# plen + max_new - 1 <= cache_len - 1, so no cache grows for it. A
+# sequence adopted into a freed slot is adopted AFTER the step in flight
+# (gen_adopt consumes the state that step returned and overwrites the
+# slot's token, position and state rows), so its first decode token
+# comes from the step after, and the in-flight step's stale row for
+# that slot never reaches it.
+#
 # Versions: a sequence's weight version resolves ONCE at admission and
 # the store tuple rides the sequence's decode LANE — a packed batch of
 # slots all on one version. A hot-swap never tears an in-flight
@@ -416,6 +433,12 @@ _GEN_COUNTERS = {
     "step_faults": _obs.counter(
         "serve.gen.step_faults", "decode steps lost to injected faults",
         ("inst",)),
+    "steps_ahead": _obs.counter(
+        "serve.gen.steps_ahead", "decode steps dispatched while the step "
+        "before was still unread", ("inst",)),
+    "rows_discarded": _obs.counter(
+        "serve.gen.rows_discarded", "slot-steps computed for a sequence "
+        "that had left its slot the step before", ("inst",)),
 }
 _GEN_GAUGES = {
     "slots_active": _obs.gauge(
@@ -524,7 +547,8 @@ class _GenLane:
     store tuple is held by reference — a swap or store GC can never
     tear the lane's in-flight sequences."""
 
-    __slots__ = ("version", "store", "state", "slot_req", "active")
+    __slots__ = ("version", "store", "state", "slot_req", "active",
+                 "flight")
 
     def __init__(self, version, store, state, capacity):
         self.version = version
@@ -532,6 +556,9 @@ class _GenLane:
         self.state = state             # [tok_feed, pos, states]
         self.slot_req = [None] * capacity
         self.active = 0
+        # the step dispatched and not yet read: (its tokens on the
+        # device, who sat in each slot when it went out)
+        self.flight = None
 
 
 class GenerateScheduler:
@@ -748,34 +775,64 @@ class GenerateScheduler:
             del self._lanes[v]
 
     def _step(self, lane):
-        """One decode step of one lane: dispatch, the one host read,
-        then every live slot's token out (emit, free, resolve)."""
+        """One turn of one lane: dispatch the next decode step from the
+        state the device holds, THEN the one host read, of the step
+        before, and every token of it out (emit, free, resolve)."""
         t0 = time.perf_counter()
-        with _obs.span("serve.gen.step.dispatch"):
-            nxt, lane.state = self._engine.gen_step(
-                lane.state, lane.store[0], lane.store[1])
+        ahead, lane.flight = lane.flight, None
+        if self._owes_a_token(lane, ahead):
+            with _obs.span("serve.gen.step.dispatch"):
+                nxt, lane.state = self._engine.gen_step(
+                    lane.state, lane.store[0], lane.store[1])
+                # the copy starts as soon as THIS step has run, whatever
+                # is queued behind it by then
+                nxt.copy_to_host_async()
+            lane.flight = (nxt, tuple(lane.slot_req))
+            self._c["steps"].inc()
+            if ahead is not None:
+                self._c["steps_ahead"].inc()
+        if ahead is None:
+            return
+        # a row belongs to the sequence that sat in the slot at dispatch
+        # and still does; any other sequence's row is a discarded one
+        nxt, sat = ahead
+        live = [slot for slot, req in enumerate(sat)
+                if req is not None and lane.slot_req[slot] is req]
+        self._c["rows_discarded"].inc(
+            len(sat) - sat.count(None) - len(live))
+        if not live:
+            return                        # nobody's step: never read
         with _obs.span("serve.gen.step.read"):
             toks = _jax.device_get(nxt)   # the ONE per-step host read
         _GEN_STEP_MS.observe((time.perf_counter() - t0) * 1e3)
-        self._c["steps"].inc()
-        self._c["tokens"].inc(lane.active)
+        self._c["tokens"].inc(len(live))
         now = time.monotonic()
         with _obs.span("serve.gen.step.emit"):
-            self._emit_step(lane, toks, now)
+            self._emit_step(lane, live, toks, now)
 
-    def _emit_step(self, lane, toks, now):
-        for slot, req in enumerate(lane.slot_req):
-            if req is None:
-                continue
-            req.emit(int(toks[slot]))
-            if ((req.eos_id is not None
-                 and int(toks[slot]) == req.eos_id)
+    @staticmethod
+    def _owes_a_token(lane, ahead):
+        """Whether a sequence of the lane still lacks a token once the
+        step in flight is in. ``max_new`` the host can count ahead; an
+        ``eos`` or a deadline it sees only in the tokens, a step late."""
+        sat = ahead[1] if ahead is not None else [None] * len(lane.slot_req)
+        return any(
+            req is not None
+            and len(req.tokens_out) + (sat[slot] is req) < req.max_new
+            for slot, req in enumerate(lane.slot_req))
+
+    def _emit_step(self, lane, live, toks, now):
+        for slot in live:
+            req = lane.slot_req[slot]
+            tok = int(toks[slot])
+            req.emit(tok)
+            if ((req.eos_id is not None and tok == req.eos_id)
                     or len(req.tokens_out) >= req.max_new):
                 self._free(lane, slot)
                 self._c["finished"].inc()
                 req.resolve(req._finish(
                     "eos" if req.eos_id is not None
-                    and int(toks[slot]) == req.eos_id else "len"))
+                    and tok == req.eos_id else "len"))
             elif req.deadline is not None and now >= req.deadline:
                 # the mid-generation expiry fix (ISSUE 17 satellite):
                 # a budget exhausted BETWEEN decode steps frees the
@@ -791,6 +848,12 @@ class GenerateScheduler:
     def _free(self, lane, slot):
         lane.slot_req[slot] = None
         lane.active -= 1
+        if lane.active == 0 and lane.flight is not None:
+            # every row of the step in flight is a discarded one: it is
+            # never read and nobody waits for it
+            sat = lane.flight[1]
+            self._c["rows_discarded"].inc(len(sat) - sat.count(None))
+            lane.flight = None
         with self._cv:
             self._active -= 1
             self._cv.notify_all()

@@ -256,6 +256,270 @@ def test_continuous_batching_joins_and_leaves():
 
 
 # ---------------------------------------------------------------------------
+# one step in flight: the host reads a step's tokens one dispatch late
+# ---------------------------------------------------------------------------
+
+def _compile_menu(eng, slots):
+    """Every generate program of ``slots`` slots built; returns the
+    compile count from which retraces are counted."""
+    for length in eng.gen_prefill_menu():
+        eng.gen_prefill_program(length)
+    eng.gen_decode_program(slots)
+    eng.gen_adopt_program(slots)
+    return eng.cache.stats()["compiles"]
+
+
+def _first_new_token(eng, prompt, n):
+    """A token of the oracle's continuation that has not come before it:
+    as ``eos_id`` it stops the sequence there and not earlier."""
+    ref = _oracle(eng, prompt, n)
+    return next(t for i, t in enumerate(ref) if i and t not in ref[:i])
+
+
+class _Held:
+    """A GenerateScheduler whose thread is parked inside the first
+    sequence's first-token callback while the test queues the rest, so
+    who is admitted when does not hang on the host's pace: ``first``
+    gets one decode step to itself, then every other sequence is
+    admitted in one pass, in the order submitted."""
+
+    def __init__(self, eng, slots=4, depth=32):
+        from mxtpu.serving.batcher import GenerateScheduler
+        self.eng = eng
+        self.compiles = _compile_menu(eng, slots)
+        self.sched = GenerateScheduler(eng, depth, slots=slots)
+        self.seen = {}                 # rid -> [(idx, tok)] as streamed
+        self.turns = 0
+        step_lanes = self.sched._step_lanes
+
+        def counted():
+            self.turns += 1
+            step_lanes()
+        self.sched._step_lanes = counted
+
+    def submit_all(self, seqs):
+        """``seqs``: (rid, prompt, max_new[, eos_id[, deadline]])."""
+        gate = threading.Event()
+        reqs = []
+        for i, (rid, prompt, max_new, *rest) in enumerate(seqs):
+            eos_id = rest[0] if rest else None
+            deadline = (time.monotonic() + rest[1]
+                        if len(rest) > 1 else None)
+            got = self.seen.setdefault(rid, [])
+
+            def on_token(idx, tok, _v, got=got, hold=(i == 0)):
+                got.append((idx, tok))
+                if hold and idx == 0:
+                    assert gate.wait(30)
+            reqs.append(self.sched.submit(rid, prompt, max_new, deadline,
+                                          eos_id=eos_id, on_token=on_token))
+            if i == 0:
+                until = time.monotonic() + 30
+                while not got and time.monotonic() < until:
+                    time.sleep(0.001)
+        gate.set()
+        return reqs
+
+    def retraces(self):
+        return self.eng.cache.stats()["compiles"] - self.compiles
+
+    def gone(self):
+        self.sched._thread.join(timeout=10)
+        return not self.sched._thread.is_alive()
+
+
+@pytest.fixture
+def held():
+    made = []
+
+    def make(eng=None, **kw):
+        made.append(_Held(eng or _engine(), **kw))
+        return made[-1]
+    yield make
+    for h in made:
+        h.sched.stop()
+
+
+def test_streams_match_recompute_as_sequences_join_and_leave(held):
+    """Eleven sequences of unequal prompt and output lengths on 4 slots:
+    a slot is freed while the next step is already in flight and is
+    adopted again at once. Every stream, as streamed and as replied, is
+    the full-recompute oracle's: the in-flight step's stale row for a
+    freed slot reaches nobody, and nothing retraces."""
+    h = held()
+    rng = np.random.RandomState(5)
+    seqs = [("r%d" % j, rng.randint(1, V, 1 + (3 * j) % 7).tolist(),
+             2 + (5 * j) % 8) for j in range(11)]
+    refs = {rid: _oracle(h.eng, prompt, n) for rid, prompt, n in seqs}
+    replies = [r.wait(120) for r in h.submit_all(seqs)]
+    assert all(r[0] == "ok" for r in replies), replies
+    for (rid, _p, n), rep in zip(seqs, replies):
+        assert list(rep[1]["tokens"]) == refs[rid], rid
+        assert rep[1]["n"] == n and rep[1]["reason"] == "len"
+        assert h.seen[rid] == list(enumerate(refs[rid])), rid
+    st = h.sched.stats()
+    assert st["tokens"] == sum(n for _r, _p, n in seqs)
+    assert st["rows_discarded"] >= 1        # somebody left a live lane
+    assert h.retraces() == 0
+
+
+# who leaves, how, and beside whom -> discarded rows. ``r0`` is admitted
+# one step before the rest and, where it is there, outlives them.
+_LEAVING = {
+    # a companion still owes tokens: the step after the leaver's last
+    # was dispatched before the host saw it leave
+    "len": ([("r1", [3, 1, 4], 5)], 1),
+    "eos": ([("r1", [3, 1, 4], 10, "eos")], 1),
+    "deadline": ([("r1", [3, 1, 4], 10, None, 0.2)], 1),
+    "three_leave": ([("r1", [3, 1, 4], 3), ("r2", [2, 7], 5),
+                     ("r3", [5], 7)], 3),
+    # alone: ``max_new`` the host counts ahead and dispatches nothing;
+    # an ``eos`` it cannot, and drops the one step unread
+    "len_alone": ([], 0),
+    "eos_alone": ([], 1),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_LEAVING))
+def test_leaving_is_seen_one_step_late_on_the_device_only(held, case):
+    """``eos``, ``max_new`` and a deadline take effect on the host one
+    dispatch after the device produced the token: ``n`` is exact,
+    nothing follows an ``eos``, and each sequence that leaves a lane
+    with work left costs exactly one discarded row."""
+    h = held()
+    others, discarded = _LEAVING[case]
+    seqs = [("r0", [3, 1, 4], 12, "eos") if case == "eos_alone"
+            else ("r0", [1, 2, 3], 12)] + others
+    seqs = [(rid, prompt, n, *(_first_new_token(h.eng, prompt, n)
+                               if r == "eos" else r for r in rest))
+            for rid, prompt, n, *rest in seqs]
+    if case == "deadline":
+        fault.install("kind=delay,point=serve.step,delay=0.06,nth=1,"
+                      "count=1000")
+    replies = [r.wait(120) for r in h.submit_all(seqs)]
+    fault.uninstall()
+    emitted = 0
+    for (rid, prompt, n, *rest), rep in zip(seqs, replies):
+        eos_id = rest[0] if rest else None
+        want = _oracle(h.eng, prompt, n)
+        if len(rest) > 1:
+            assert rep[0] == "expired", rep
+            got = [t for _i, t in h.seen[rid]]
+            assert 1 <= rep[1]["generated"] == len(got) < n
+            assert got == want[:len(got)]
+            emitted += len(got)
+            continue
+        assert rep[0] == "ok", rep
+        if eos_id is not None:
+            want = want[:want.index(eos_id) + 1]
+        assert list(rep[1]["tokens"]) == want, rid
+        assert rep[1]["n"] == len(want) == len(h.seen[rid])
+        assert rep[1]["reason"] == ("eos" if eos_id is not None else "len")
+        emitted += len(want)
+    st = h.sched.stats()
+    assert st["rows_discarded"] == discarded
+    assert st["tokens"] == emitted
+    if case == "len_alone":
+        assert st["steps"] == 11 and st["steps_ahead"] == 10
+    # the lane emptied: nothing in flight, and the thread sleeps
+    assert all(ln.flight is None for ln in h.sched._lanes.values())
+    turns = h.turns
+    time.sleep(0.2)
+    assert h.turns == turns, "the scheduler spins on an empty lane"
+    assert turns <= st["steps"] + len(seqs) + 1
+
+
+def test_the_row_after_the_last_fits_the_cache(held):
+    """``plen + max_new == cache_len``: the one step a finished sequence
+    runs beyond its last token writes row ``cache_len - 1``, the last
+    there is. Its neighbour's tokens and the next occupant's are the
+    oracle's."""
+    h = held()
+    seqs = [("r0", [1, 2, 3], S - 3),
+            ("edge", list(range(1, 11)), S - 10),
+            ("next", [4, 4, 2, 1, 3, 6, 5, 7], S - 8)]
+    # three slots taken for good, so ``next`` waits for ``edge``'s slot
+    seqs[1:1] = [("w1", [2, 2], S - 2), ("w2", [6], S - 1)]
+    replies = [r.wait(120) for r in h.submit_all(seqs)]
+    assert h.sched.stats()["queue_hwm"] >= 1
+    for (rid, prompt, n), rep in zip(seqs, replies):
+        assert rep[0] == "ok", rep
+        assert rep[1]["n"] == n == S - len(prompt)
+        assert list(rep[1]["tokens"]) == _oracle(h.eng, prompt, n), rid
+    assert h.sched.stats()["rows_discarded"] >= 1
+
+
+@pytest.mark.parametrize("how", ["drop", "kill", "drain", "stop"])
+def test_a_step_in_flight_when_the_lane_goes_down(held, how):
+    """``serve.step`` faults, ``drain()`` and ``stop()`` each land while
+    a decode step is in flight. drop: the lane's sequences fail, the
+    in-flight tokens go with them, and the next occupants get the
+    oracle's tokens. kill: everything fails fast, nothing is left
+    pending. drain: every sequence finishes with its exact tokens. stop:
+    the rest fail. In each the thread is gone (or asleep) afterwards."""
+    h = held()
+    seqs = [("r0", [1, 2, 3], 12), ("r1", [3, 1, 4], 12), ("r2", [5], 9)]
+    refs = {rid: _oracle(h.eng, p, n) for rid, p, n in seqs}
+    if how in ("drop", "kill"):
+        fault.install("kind=%s,point=serve.step,nth=4,count=1" % how)
+    elif how == "stop":
+        fault.install("kind=delay,point=serve.step,delay=0.05,nth=1,"
+                      "count=1000")
+    reqs = h.submit_all(seqs)
+    if how == "drain":
+        assert h.sched.drain(60)
+    elif how == "stop":
+        while len(h.seen["r1"]) < 2:
+            time.sleep(0.001)
+        assert any(ln.flight is not None
+                   for ln in h.sched._lanes.values())
+        h.sched.stop()
+    replies = [r.wait(60) for r in reqs]
+    fault.uninstall()
+    st = h.sched.stats()
+    if how == "drain":
+        for (rid, _p, n), rep in zip(seqs, replies):
+            assert rep[0] == "ok" and list(rep[1]["tokens"]) == refs[rid]
+        assert h.gone()
+        assert st["rows_discarded"] == 2     # the last to finish costs none
+    elif how == "drop":
+        assert all(r == ("err", "decode step dropped (injected)")
+                   for r in replies), replies
+        assert st["step_faults"] == 1 and st["active"] == 0
+        assert all(ln.flight is None for ln in h.sched._lanes.values())
+        # every streamed token was the oracle's, up to the drop
+        for rid, _p, _n in seqs:
+            got = [t for _i, t in h.seen[rid]]
+            assert 1 <= len(got) < 9 and got == refs[rid][:len(got)]
+        again = h.sched.submit("again", [3, 1, 4], 12, None).wait(60)
+        assert list(again[1]["tokens"]) == refs["r1"]
+    else:
+        assert all(r[0] == "err" for r in replies), replies
+        assert h.gone()
+        assert h.sched.pending() == 0 and not h.sched._lanes
+        if how == "kill":
+            assert st["step_faults"] == 1
+
+
+def test_every_step_but_a_lanes_first_goes_out_ahead(held):
+    """Steady load on a lane that never empties (``r0`` holds its slot
+    from the first step to the last): each decode step but the lane's
+    first is dispatched while the step before is unread, and nothing
+    retraces."""
+    h = held()
+    seqs = [("r0", [1, 2, 3], 13)] + [
+        ("r%d" % j, [1 + j % 5, 2], 3 + j % 2) for j in range(1, 7)]
+    replies = [r.wait(120) for r in h.submit_all(seqs)]
+    assert all(r[0] == "ok" for r in replies), replies
+    st = h.sched.stats()
+    assert st["steps"] == 12                  # r0's, prefill token aside
+    assert st["steps_ahead"] == st["steps"] - 1
+    assert st["rows_discarded"] == 6          # each short one left r0 behind
+    assert st["tokens"] == sum(n for _r, _p, n in seqs)
+    assert h.retraces() == 0
+
+
+# ---------------------------------------------------------------------------
 # the wire: streamed partials, concurrency, plain-request fallback
 # ---------------------------------------------------------------------------
 
@@ -484,11 +748,7 @@ def _generate_all(eng, prompts, max_new):
     than slots); returns the tokens per prompt and the compiles the run
     added after its programs were built."""
     from mxtpu.serving.batcher import GenerateScheduler
-    for length in eng.gen_prefill_menu():
-        eng.gen_prefill_program(length)
-    eng.gen_decode_program(4)
-    eng.gen_adopt_program(4)
-    compiles = eng.cache.stats()["compiles"]
+    compiles = _compile_menu(eng, 4)
     sched = GenerateScheduler(eng, 16, slots=4)
     try:
         reqs = [sched.submit("r%d" % j, prompt, max_new, None)

@@ -231,20 +231,32 @@ def test_generate_scheduler_spans(tmp_path, monkeypatch):
         at1 = sched.stats()
     finally:
         sched.stop()
+    # one ``serve.gen.step`` a turn of the lane: the next step goes out
+    # first, then the step BEFORE it is read and emitted. So a lane's first
+    # turn has nothing to read and its last nothing to dispatch.
     steps = sorted(named("serve.gen.step"), key=lambda e: e["ts"])
-    assert len(steps) == at1["steps"] - at0["steps"] > 0
     kids = ("serve.gen.step.dispatch", "serve.gen.step.read",
             "serve.gen.step.emit")
     by_parent = {}
     for e in spans():
         by_parent.setdefault(e["args"]["parent"], []).append(e)
+    turns = []
     for step in steps:
         mine = sorted(by_parent[step["args"]["span"]], key=lambda e: e["ts"])
-        assert tuple(e["name"] for e in mine) == kids
+        turns.append(tuple(e["name"] for e in mine))
+        assert turns[-1] in (kids, kids[:1], kids[1:])
         assert all(inside(e, step) for e in mine)
         assert all(a["ts"] + a["dur"] <= b["ts"]
                    for a, b in zip(mine, mine[1:]))
         assert int(step["args"]["active"]) >= 1
+    assert turns[0] == kids[:1] and turns[-1] == kids[1:]
+    assert kids in turns
+    dispatched = at1["steps"] - at0["steps"]
+    assert sum(kids[0] in t for t in turns) == dispatched > 0
+    assert at1["steps_ahead"] - at0["steps_ahead"] == turns.count(kids)
+    # every step was read, once (all six finish by length, which the host
+    # counts ahead: no step goes out that nobody waits for)
+    assert sum(kids[1] in t for t in turns) == dispatched
     # one set per request, each with the request's id
     admits = {e["args"]["span"]: e for e in named("serve.gen.admit")}
     for name in ("prefill", "first_read", "adopt"):
