@@ -1,9 +1,8 @@
 #!/usr/bin/env python
 """Counter-based comms-perf smoke for the dist_async fast path.
 
-The loopback MB/s numbers (tools/bench_kvstore.py) are load-bearing but
-wall-clock — useless as a CI gate on a noisy shared host. This check
-pins the fast path's *structural* properties instead, straight from the
+Loopback MB/s is wall-clock — useless as a CI gate on a noisy shared
+host. This check pins the fast path's *structural* properties, straight from the
 ``kv.stats()`` counters, so a regression that quietly reintroduces a
 copy, a per-key frame, or an unbounded window fails deterministically:
 

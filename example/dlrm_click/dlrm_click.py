@@ -16,7 +16,7 @@ as
   (``Optimizer.update_host_rows``), the reply scattering straight back
   into the device store;
 * wire bytes and server optimizer cost that scale with rows touched,
-  never with table size (``tools/bench_embedding.py`` sweeps it).
+  never with table size (``ci/check_embedding_perf.py`` pins it).
 
 Synthetic click data with planted preferences keeps it CPU-runnable;
 the click signal depends on (user-bucket, item-bucket) affinity so the

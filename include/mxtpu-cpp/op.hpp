@@ -404,6 +404,19 @@ inline Symbol Pooling(const std::string &name,
   return op.CreateSymbol(name);
 }
 
+inline Symbol RMSNorm(const std::string &name,
+    Symbol data,
+    Symbol gamma,
+    int axis = -1,
+    double eps = 1e-05) {
+  Operator op("RMSNorm");
+  op.SetParam("axis", std::to_string(axis));
+  op.SetParam("eps", NumStr(eps));
+  op.SetInput("data", data);
+  op.SetInput("gamma", gamma);
+  return op.CreateSymbol(name);
+}
+
 inline Symbol RNN(const std::string &name,
     Symbol data,
     Symbol parameters,
@@ -844,15 +857,15 @@ inline Symbol contrib_flash_attention(const std::string &name,
     const std::string & scale = "None",
     int q_offset = 0,
     int k_offset = 0,
-    int block_q = 512,
-    int block_k = 1024) {
+    const std::string & block_q = "None",
+    const std::string & block_k = "None") {
   Operator op("_contrib_flash_attention");
   op.SetParam("causal", (causal ? "true" : "false"));
   if (scale != "None") op.SetParam("scale", scale);
   op.SetParam("q_offset", std::to_string(q_offset));
   op.SetParam("k_offset", std::to_string(k_offset));
-  op.SetParam("block_q", std::to_string(block_q));
-  op.SetParam("block_k", std::to_string(block_k));
+  if (block_q != "None") op.SetParam("block_q", block_q);
+  if (block_k != "None") op.SetParam("block_k", block_k);
   op.SetInput("q", q);
   op.SetInput("k", k);
   op.SetInput("v", v);
@@ -1529,6 +1542,41 @@ inline Symbol broadcast_to(const std::string &name,
   return op.CreateSymbol(name);
 }
 
+inline Symbol cached_attention(const std::string &name,
+    Symbol query,
+    Symbol key,
+    Symbol value,
+    Symbol k_cache,
+    Symbol v_cache,
+    Symbol pos,
+    int num_heads = 1,
+    bool alibi = false,
+    int num_kv_heads = 0,
+    int window = 0,
+    double rope_theta = 0.0,
+    double norm_eps = 1e-05,
+    Symbol valid_len = Symbol(),
+    Symbol q_gain = Symbol(),
+    Symbol k_gain = Symbol()) {
+  Operator op("cached_attention");
+  op.SetParam("num_heads", std::to_string(num_heads));
+  op.SetParam("alibi", (alibi ? "true" : "false"));
+  op.SetParam("num_kv_heads", std::to_string(num_kv_heads));
+  op.SetParam("window", std::to_string(window));
+  op.SetParam("rope_theta", NumStr(rope_theta));
+  op.SetParam("norm_eps", NumStr(norm_eps));
+  op.SetInput("query", query);
+  op.SetInput("key", key);
+  op.SetInput("value", value);
+  op.SetInput("k_cache", k_cache);
+  op.SetInput("v_cache", v_cache);
+  op.SetInput("pos", pos);
+  if (!valid_len.IsNull()) op.SetInput("valid_len", valid_len);
+  if (!q_gain.IsNull()) op.SetInput("q_gain", q_gain);
+  if (!k_gain.IsNull()) op.SetInput("k_gain", k_gain);
+  return op.CreateSymbol(name);
+}
+
 inline Symbol cast(const std::string &name,
     Symbol data,
     const std::string & dtype = "float32") {
@@ -1987,6 +2035,54 @@ inline Symbol min(const std::string &name,
   op.SetParam("keepdims", (keepdims ? "true" : "false"));
   op.SetParam("exclude", (exclude ? "true" : "false"));
   op.SetInput("data", data);
+  return op.CreateSymbol(name);
+}
+
+inline Symbol moe_ffn(const std::string &name,
+    Symbol data,
+    Symbol gate_weight,
+    Symbol w1,
+    Symbol b1,
+    Symbol w2,
+    Symbol b2,
+    double capacity_factor = 1.25,
+    int num_selected = 1) {
+  Operator op("moe_ffn");
+  op.SetParam("capacity_factor", NumStr(capacity_factor));
+  op.SetParam("num_selected", std::to_string(num_selected));
+  op.SetInput("data", data);
+  op.SetInput("gate_weight", gate_weight);
+  op.SetInput("w1", w1);
+  op.SetInput("b1", b1);
+  op.SetInput("w2", w2);
+  op.SetInput("b2", b2);
+  return op.CreateSymbol(name);
+}
+
+inline Symbol moe_ffn_held(const std::string &name,
+    Symbol data,
+    Symbol router_weight,
+    Symbol select_bias,
+    Symbol gate_weight,
+    Symbol up_weight,
+    Symbol down_weight,
+    int top_k = 1,
+    int expert_first = 0,
+    double scale = 1.0,
+    Symbol load = Symbol(),
+    Symbol valid_len = Symbol()) {
+  Operator op("moe_ffn_held");
+  op.SetParam("top_k", std::to_string(top_k));
+  op.SetParam("expert_first", std::to_string(expert_first));
+  op.SetParam("scale", NumStr(scale));
+  op.SetInput("data", data);
+  op.SetInput("router_weight", router_weight);
+  op.SetInput("select_bias", select_bias);
+  op.SetInput("gate_weight", gate_weight);
+  op.SetInput("up_weight", up_weight);
+  op.SetInput("down_weight", down_weight);
+  if (!load.IsNull()) op.SetInput("load", load);
+  if (!valid_len.IsNull()) op.SetInput("valid_len", valid_len);
   return op.CreateSymbol(name);
 }
 

@@ -733,7 +733,7 @@ class ImageRecordIterImpl:
     Two paths: the standard fixed-function pipeline (resize / crop /
     mirror / mean-std) runs on a spawned process pool
     (``preprocess_threads`` workers, see _FastRecordIter — the OMP-loop
-    analogue, measured in tools/bench_io.py); configurations outside that
+    analogue); configurations outside that
     surface (custom augmenters, mean_img, multi-label) fall back to the
     in-process ImageIter wrapped in a background-thread prefetcher.
 
@@ -773,9 +773,9 @@ class ImageRecordIterImpl:
                                       min_random_scale, max_random_scale,
                                       max_aspect_ratio, random_h,
                                       random_s, random_l)
-        # measured in tools/bench_io.py: the pool path wins even on a
-        # single-core host (the fixed-function numpy/PIL workers beat the
-        # per-image nd-op augmenters 3x, and decode overlaps the consumer)
+        # the pool path is taken even on a single-core host: the
+        # fixed-function numpy/PIL workers do less per image than the
+        # nd-op augmenters, and decode overlaps the consumer
         fast_ok = (not kwargs and not mean_img and label_width == 1
                    and len(data_shape) == 3 and data_shape[0] == 3
                    and int(preprocess_threads) >= 1 and _spawn_safe())

@@ -70,8 +70,8 @@ device_put. Seq-deduped replays answer with current row values; sparse
 records forward on the replication stream and move through
 ``("split", dst)`` handoffs exactly-once like any other update. bf16
 rows (``MXTPU_AMP``) upcast into the fp32 master table and replies
-ride bf16 in kind. ``tools/bench_embedding.py`` measures the
-bytes/step scaling; ``ci/check_embedding_perf.py`` pins it.
+ride bf16 in kind. ``ci/check_embedding_perf.py`` pins the bytes/step
+scaling.
 
 Wire compression: ``set_gradient_compression({'type': '2bit'})`` makes
 ``push`` ship the 2-bit packed form (16x smaller) with a per-part
@@ -220,8 +220,8 @@ Fast path
 ---------
 The data path is built for throughput on top of those fault semantics
 (ps-lite's levers — zero-copy scatter-gather, many requests per
-connection, message coalescing — rendered here; measured in
-``tools/bench_kvstore.py`` / docs/perf_analysis.md "Comms fast path"):
+connection, message coalescing — rendered here; its counts are pinned
+by ``ci/check_comms_perf.py``, docs/perf_analysis.md "Comms fast path"):
 
 * **Zero-copy wire.** Sends are scatter-gather (``socket.sendmsg`` over
   the frame head + each pickle-5 out-of-band buffer), so an N-byte
@@ -3207,10 +3207,10 @@ def serve_forever():
 
 # sockets per server per worker: the server handles each connection on
 # its own thread, so k sockets let k in-flight parts unpickle/apply in
-# parallel inside ONE server. Default 1 — on the 1-core measurement
-# host extra sockets bought nothing (docs/ps_throughput.json; the
-# server CPU, not the socket serialization, is the limit there); raise
-# on multi-core servers where handler threads can actually overlap.
+# parallel inside ONE server. Default 1 — on a 1-core host extra
+# sockets buy nothing (the server CPU, not the socket serialization,
+# is the limit there); raise on multi-core servers where handler
+# threads can actually overlap.
 _CONNS_PER_SERVER = int(os.environ.get("MXTPU_PS_CONNS", "1"))
 
 

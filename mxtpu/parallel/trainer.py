@@ -141,9 +141,7 @@ class ShardedTrainer:
         # XLA-chosen persistent-state layouts (experimental): compile the
         # train step with AUTO input/output layouts for params/optimizer
         # state/aux so conv weights live in the layout the convolutions
-        # want instead of being relaid out every step — the round-5 TPU
-        # trace attributes ~22% of ResNet-50 step time to layout copies
-        # (docs/perf_analysis.md, round-5 scoreboard). Opt-in while the
+        # want instead of being relaid out every step. Opt-in while the
         # win is unmeasured; numerics are layout-invariant either way.
         self._auto_layout = auto_layout_enabled(auto_layout)
         self._step_fns = {}
@@ -406,8 +404,8 @@ class ShardedTrainer:
                 # buffers in place (zero extra HBM for the update).
                 # inputs(3)/label(4) are deliberately NOT donated: callers
                 # legitimately reuse pre-staged batches across steps
-                # (bench.py's steady-state loop; a donated batch buffer
-                # would be invalidated after the first step). lr(7) is a
+                # (a donated batch buffer would be invalidated after the
+                # first step). lr(7) is a
                 # carried constant, never replaced, so it must stay live.
                 donate = (0, 1, 2, 5, 6) if self._donate else ()
                 if self._auto_layout:

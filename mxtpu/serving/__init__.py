@@ -59,8 +59,7 @@ kill/delay/sever serving scenarios replay deterministically
 (``tests/test_fault_tolerance.py``, ``tests/test_serving.py``,
 ``tests/test_rollout.py``). Full architecture and semantics:
 ``docs/serving.md``; knobs: ``docs/env_vars.md`` (``MXTPU_SERVE_*``);
-measured behavior: ``tools/bench_serving.py`` →
-``docs/perf_analysis.md`` "Serving".
+pinned counts: ``docs/perf_analysis.md`` "Serving".
 """
 from __future__ import annotations
 

@@ -383,8 +383,7 @@ class DynamicBatcher:
 # Per-step cost is constant (the decode program is compiled for a fixed
 # slot capacity; inactive slots compute garbage), so aggregate tokens/s
 # scales with the number of ACTIVE sequences — the continuous-batching
-# throughput story tools/bench_serving.py measures and
-# ci/check_generate_perf.py pins.
+# throughput story ci/check_generate_perf.py pins.
 #
 # One step stays in flight per lane: the decode program keeps the next
 # step's feed on the device, so a turn of a lane dispatches step n+1

@@ -563,7 +563,7 @@ class ModelServer:
                 else (time.monotonic() - arrival) * 1e3
             if lat is not None:
                 # the serve.request latency histogram: p50/p99 per
-                # model for mxtop / bench_serving / the controller
+                # model for mxtop / the controller
                 _SRV_REQUEST_MS.labels(entry.name).observe(lat)
             entry.note(v, "responses", lat_ms=lat)
         elif reply[0] == "expired":

@@ -49,12 +49,17 @@ def test_agrees_with_a_wall_clock_on_real_work():
     def step(s):
         return (a if s is None else s) @ a / 256.0
 
-    sec, _ = timed_steps(step, warmup=2, iters=20)
-    s = step(None)
-    s.block_until_ready()
-    t0 = time.perf_counter()
-    for _ in range(20):
-        s = step(s)
-    s.block_until_ready()
-    naive = (time.perf_counter() - t0) / 20
-    assert 0.2 < sec / naive < 5.0, (sec, naive)
+    def naive():
+        s = step(None)
+        s.block_until_ready()
+        t0 = time.perf_counter()
+        for _ in range(20):
+            s = step(s)
+        s.block_until_ready()
+        return (time.perf_counter() - t0) / 20
+
+    # the best of three of each: one loop of 20 steps of 0.3 ms that loses
+    # its core to another test worker reads several times too long
+    sec = min(timed_steps(step, warmup=2, iters=20)[0] for _ in range(3))
+    wall = min(naive() for _ in range(3))
+    assert 0.2 < sec / wall < 5.0, (sec, wall)

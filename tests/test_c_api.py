@@ -13,6 +13,9 @@ _ROOT = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
 _NATIVE = os.path.join(_ROOT, "mxtpu", "_native")
 _SO = os.path.join(_NATIVE, "libmxtpu_c.so")
 
+# slow: toolchain (make + g++/gcc build libmxtpu_c.so and the C drivers)
+pytestmark = pytest.mark.slow
+
 
 def _build_so():
     if shutil.which("g++") is None:

@@ -13,6 +13,10 @@ import pytest
 import mxtpu as mx
 from mxtpu import nd
 
+# slow: toolchain (make + gcc/g++ build libmxtpu_predict.so, a C and a C++
+# program and the amalgamation)
+pytestmark = pytest.mark.slow
+
 _NATIVE = os.path.join(os.path.dirname(__file__), "..", "mxtpu", "_native")
 _SO = os.path.join(_NATIVE, "libmxtpu_predict.so")
 

@@ -11,6 +11,10 @@ import pytest
 _ROOT = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
 _NATIVE = os.path.join(_ROOT, "mxtpu", "_native")
 
+# slow: toolchain (g++ builds the C++ LeNet against libmxtpu_c.so; the
+# wrapper test rewrites include/mxtpu-cpp/op.hpp in place)
+pytestmark = pytest.mark.slow
+
 
 def test_op_wrappers_up_to_date(tmp_path):
     """Regenerating op.hpp must reproduce the checked-in file, so a newly

@@ -64,8 +64,7 @@ def test_entry_points_pass_no_cpu_context():
     the process-wide default backend."""
     pinned = re.compile(r"context=mx\.cpu\(\)|ctx=mx\.cpu\(\)|else cpu\(\)")
     roots = ["example/image-classification", "example/char_lm",
-             "example/moe_transformer", "mxtpu/serving",
-             "tools/bench_module.py"]
+             "example/moe_transformer", "mxtpu/serving"]
     hits = []
     for root in roots:
         path = os.path.join(_ROOT, root)
@@ -155,7 +154,7 @@ def test_compile_cache_rule():
         == "/x/elsewhere"
     src = open(os.path.join(_ROOT, "mxtpu", "__init__.py")).read()
     assert src.count("jax_compilation_cache_dir") == 1
-    for other in ("bench.py", "chip_smoke.py", "tools/launch.py"):
+    for other in ("chip_smoke.py", "tools/launch.py"):
         assert "compilation_cache_dir\"" not in \
             open(os.path.join(_ROOT, other)).read(), other
 
@@ -165,6 +164,22 @@ def test_compile_cache_rule():
 @pytest.fixture(scope="module")
 def smoke():
     return _load("chip_smoke.py", "chip_smoke_mod")
+
+
+def test_no_tpu_means_no_metric():
+    """As a process: with no TPU the script exits non-zero, says so once,
+    and prints nothing that parses as a result (a CPU number is never
+    written under a TPU metric's name)."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=_ROOT)
+    res = subprocess.run([sys.executable, os.path.join(_ROOT, "chip_smoke.py")],
+                         capture_output=True, text=True, timeout=300,
+                         env=env)
+    assert res.returncode != 0
+    said = [l for l in res.stderr.splitlines() if "no TPU" in l]
+    assert len(said) == 1, res.stderr[-500:]
+    for line in res.stdout.splitlines():
+        assert not line.lstrip().startswith("{"), line
+        assert "img" not in line and "images/sec" not in line, line
 
 
 def test_chip_smoke_main_refuses_the_cpu(smoke, capsys):

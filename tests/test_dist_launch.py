@@ -9,6 +9,11 @@ import sys
 import pytest
 
 
+# slow: several processes (every test forks workers, servers or replicas
+# through tools/launch.py)
+pytestmark = pytest.mark.slow
+
+
 def _free_port():
     import socket
     with socket.socket() as s:

@@ -1047,7 +1047,6 @@ def test_backup_refuses_client_ops_until_promoted(monkeypatch):
         bak.stop()
 
 
-@pytest.mark.slow
 def test_kill_worker_mid_push_window(monkeypatch, tmp_path):
     """kill_worker row: a child worker is SIGKILLed by the fault
     harness between the pipelined part-pushes of one big array. The

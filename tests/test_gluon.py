@@ -281,6 +281,9 @@ def test_model_zoo_smoke():
         models.get_model(name)
 
 
+# slow: over 30 s alone (124.1 and 129.1 s in PR 32's two runs: every family
+# of the zoo is built, initialised and forwarded)
+@pytest.mark.slow
 def test_model_zoo_every_family_forwards():
     """One variant per family runs a real forward at its native input
     size (reference model zoo gluon/model_zoo/vision: resnet, vgg,

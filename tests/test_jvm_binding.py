@@ -24,6 +24,9 @@ CAPI_JAVA = os.path.join(JVM, "src", "main", "java", "ml", "mxtpu",
                          "CApi.java")
 NATIVE = os.path.join(ROOT, "mxtpu", "_native")
 
+# slow: toolchain (make builds the native libraries; javac/gcc hosts)
+pytestmark = pytest.mark.slow
+
 
 def _declared_functions():
     """Names of the C functions CApi.java binds (JNA interface methods:

@@ -22,9 +22,6 @@ from mxtpu.base import MXNetError
 from mxtpu.ops import pallas_attention, pallas_rnn, rnn as rnn_ops
 from mxtpu.ops.pallas_attention import flash_attention
 
-pytestmark = pytest.mark.slow
-
-
 @pytest.fixture(scope="module")
 def v5e():
     from jax.experimental import topologies

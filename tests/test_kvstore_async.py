@@ -360,7 +360,6 @@ def test_scalar_and_edge_row_ids():
         kv.close()
 
 
-@pytest.mark.slow
 def test_realistic_volume_straggler():
     """The async property at real parameter scale (round-4 verdict: the
     service's throughput at ~100 MB/step was unmeasured): one worker

@@ -9,6 +9,9 @@ import pytest
 _ROOT = os.path.join(os.path.dirname(__file__), "..")
 _NATIVE = os.path.join(_ROOT, "mxtpu", "_native")
 
+# slow: toolchain (g++ builds the recordio test against libmxtpu_io.so)
+pytestmark = pytest.mark.slow
+
 
 def test_recordio_cpp(tmp_path):
     if shutil.which("g++") is None:

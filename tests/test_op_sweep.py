@@ -208,6 +208,17 @@ def np_lrn(x, alpha, beta, knorm, nsize):
     return out.astype(np.float32)
 
 
+def np_moe_ffn(x, gw, w1, b1, w2, b2, capacity_factor):
+    """Top-1 experts with room for every token: each token's own expert's
+    relu feed-forward times its gate probability, and the Switch loss."""
+    p = np_softmax(x @ gw, -1)
+    e = p.argmax(-1)
+    h = np.maximum(np.einsum("td,tdh->th", x, w1[e]) + b1[e], 0)
+    y = p.max(-1, keepdims=True) * (np.einsum("th,thd->td", h, w2[e]) + b2[e])
+    frac = np.bincount(e, minlength=gw.shape[1]) / len(e)
+    return y, np.array([gw.shape[1] * (frac * p.mean(0)).sum()])
+
+
 def _vec(f):
     return np.vectorize(f, otypes=[np.float32])
 
@@ -226,6 +237,16 @@ SKIP = {
     "_contrib_gc_quantize_2bit": "2-bit gradient compression round-trip + "
                                  "error-feedback in tests/test_gradcomp.py",
     "_contrib_gc_dequantize_2bit": "see _contrib_gc_quantize_2bit",
+    "cached_attention": "three outputs over a carried KV cache (write "
+                        "offsets, ring window, grouped heads); prefill and "
+                        "decode vs a dense numpy/jnp attention in "
+                        "tests/test_serving_generate.py, "
+                        "tests/test_decode_attention.py and "
+                        "tests/test_grouped_attention.py",
+    "moe_ffn_held": "integer load counters out and an expert_first offset "
+                    "in; the held shares vs the reference's uncut layer in "
+                    "tests/test_exaone_moe_serving.py"
+                    "::test_eight_shares_add_up_to_the_uncut_layer",
 }
 
 # --------------------------------------------------------------------------
@@ -590,6 +611,13 @@ S("LayerNorm", lambda r: [u(r, 3, 4), pos(r, 4), u(r, 4)],
   ref=lambda x, g, b, eps: (x - x.mean(-1, keepdims=True)) /
       np.sqrt(x.var(-1, keepdims=True) + eps) * g + b,
   rtol=1e-3, atol=1e-4)
+S("RMSNorm", lambda r: [u(r, 3, 4), pos(r, 4)], params={"eps": 1e-5},
+  ref=lambda x, g, eps: x / np.sqrt((x * x).mean(-1, keepdims=True) + eps) * g,
+  rtol=1e-3, atol=1e-4)
+# capacity_factor = the number of experts: no token is dropped
+S("moe_ffn", lambda r: [u(r, 6, 4), u(r, 4, 3), u(r, 3, 4, 5), u(r, 3, 5),
+                        u(r, 3, 5, 4), u(r, 3, 4)],
+  params={"capacity_factor": 3.0}, ref=np_moe_ffn, rtol=1e-3, atol=1e-4)
 S("InstanceNorm", lambda r: [u(r, 2, 3, 5), pos(r, 3), u(r, 3)],
   params={"eps": 1e-3},
   ref=lambda x, g, b, eps: (x - x.mean(-1, keepdims=True)) /

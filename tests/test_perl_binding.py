@@ -11,6 +11,9 @@ _ROOT = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
 _PKG = os.path.join(_ROOT, "perl-package", "AI-MXTpu")
 _NATIVE = os.path.join(_ROOT, "mxtpu", "_native")
 
+# slow: toolchain (perl Makefile.PL + make build the XS module)
+pytestmark = pytest.mark.slow
+
 
 def test_perl_binding(tmp_path):
     if shutil.which("perl") is None:

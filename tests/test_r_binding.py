@@ -16,6 +16,9 @@ import pytest
 
 _ROOT = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
 
+# slow: toolchain (gcc type-checks the R glue and builds a C host)
+pytestmark = pytest.mark.slow
+
 
 def test_r_glue_typechecks_against_c_abi():
     if shutil.which("gcc") is None:

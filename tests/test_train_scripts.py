@@ -23,6 +23,8 @@ def _run(args, cwd):
                           timeout=560)
 
 
+# slow: several processes (the script runs twice, each with its pool of
+# decode workers)
 @pytest.mark.slow
 def test_cifar_script_trains_checkpoints_and_resumes(tmp_path):
     base = ["--synthetic", "48", "--num-layers", "8", "--batch-size", "8",

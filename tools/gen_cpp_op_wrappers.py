@@ -21,7 +21,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 OPT_INPUTS = {
     "bias", "gamma", "beta", "moving_mean", "moving_var", "sequence_length",
     "state_cell", "crop_like", "trans", "grid", "label", "weight32",
-    "data_lengths", "label_lengths",
+    "data_lengths", "label_lengths", "valid_len", "q_gain", "k_gain", "load",
 }
 
 # C++ reserved words that appear as op names or arg names
