@@ -717,10 +717,12 @@ def cached_attention(query, key, value, k_cache, v_cache, pos, valid_len=None,
                              "multi-head op only (no caller has both)")
         return _cached_attention_grouped(query, key, value, k_cache, v_cache,
                                          p, valid_len, q_gain, k_gain, spec)
-    write = jax.vmap(
-        lambda cache, rows, at: lax.dynamic_update_slice(cache, rows, (at, 0)))
-    new_k = write(k_cache, key.astype(k_cache.dtype), p)
-    new_v = write(v_cache, value.astype(v_cache.dtype), p)
+    k_rows, v_rows = key.astype(k_cache.dtype), value.astype(v_cache.dtype)
+    if _decode_path(query, k_cache, H):
+        return _decode_step(query, k_rows, v_rows, k_cache, v_cache, p, spec,
+                            use_alibi, with_grad=True)
+    new_k = _scatter_rows(k_cache, k_rows, p)
+    new_v = _scatter_rows(v_cache, v_rows, p)
     mesh = _seq_parallel_mesh(T, S, H)
     if mesh is not None:
         from ..parallel.ring_attention import ring_attention_sharded
@@ -734,12 +736,16 @@ def cached_attention(query, key, value, k_cache, v_cache, pos, valid_len=None,
             mesh, causal=True, data_axis=None, alibi=use_alibi)
         out = o.transpose(0, 2, 1, 3).reshape(B, T, D)
         return out.astype(query.dtype), new_k, new_v
-    if _decode_path(query, new_k, H):
-        _DECODE_PATH_NODES.inc()
-        out = _attend_decode(query, new_k, new_v, p, H, use_alibi)
-    else:
-        out = _attend_dense(query, new_k, new_v, p, H, use_alibi)
-    return out, new_k, new_v
+    return (_attend_dense(query, new_k, new_v, p, H, use_alibi),
+            new_k, new_v)
+
+
+def _scatter_rows(cache, rows, at):
+    """``cache [B, S, D]`` with ``rows [B, T, D]`` written from row
+    ``at[b]`` of slot ``b`` on (the start clamped so the chunk fits): XLA's
+    scatter, a ``while`` of a trip a slot on the TPU."""
+    return jax.vmap(lambda c, r, a: lax.dynamic_update_slice(c, r, (a, 0)))(
+        cache, rows, at)
 
 
 def _attend_dense(query, new_k, new_v, p, H, use_alibi):
@@ -811,8 +817,7 @@ def _ring_write(cache, rows, p, n):
     B, T, _D = rows.shape
     R = cache.shape[1]
     if T == 1:                                 # a decode step: one true row
-        return jax.vmap(lambda c, r, a: lax.dynamic_update_slice(
-            c, r, (a, 0)))(cache, rows, p % R)
+        return _scatter_rows(cache, rows, p % R)
     last = (p + n - 1)[:, None]                                  # [B, 1]
     r_idx = jnp.arange(R, dtype=jnp.int32)[None, :]
     at = last - (last - r_idx) % R             # newest position = r mod R
@@ -861,26 +866,13 @@ def _cached_attention_grouped(query, key, value, k_cache, v_cache, p,
     q = qh.astype(query.dtype).reshape(B, T, H * hd)
     k_rows = kh.astype(k_cache.dtype).reshape(B, T, K * hd)
     v_rows = value.astype(v_cache.dtype)
-    on_kernel = _decode_path(q, k_cache, H, K)
     if spec.window:
         _WINDOW_NODES.inc()
-    if on_kernel and S % 16 == 0:
-        # a decode step on the kernels: the row goes in through one too
-        from .pallas_attention import cache_write_row
-        at = p % S if spec.window else jnp.clip(p, 0, S - 1)
-        new_k = cache_write_row(k_cache, k_rows, at)
-        new_v = cache_write_row(v_cache, v_rows, at)
-    elif spec.window:
+    if _decode_path(q, k_cache, H, K):
+        return _decode_step(q, k_rows, v_rows, k_cache, v_cache, p, spec)
+    if spec.window:
         new_k = _ring_write(k_cache, k_rows, p, n)
         new_v = _ring_write(v_cache, v_rows, p, n)
-    else:
-        write = jax.vmap(lambda cache, rows, at:
-                         lax.dynamic_update_slice(cache, rows, (at, 0)))
-        new_k, new_v = write(k_cache, k_rows, p), write(v_cache, v_rows, p)
-    if on_kernel:
-        _DECODE_PATH_NODES.inc()
-        out = _attend_decode_grouped(q, new_k, new_v, p, spec)
-    elif spec.window:
         # the ring as it was (row r holds the newest position below pos
         # that is r modulo S), then the chunk's own rows
         r_idx = jnp.arange(S, dtype=jnp.int32)[None, :]
@@ -894,6 +886,8 @@ def _cached_attention_grouped(query, key, value, k_cache, v_cache, p,
             jnp.concatenate([v_cache, v_rows], axis=1),
             q_abs, k_abs, live, spec)
     else:
+        new_k = _scatter_rows(k_cache, k_rows, p)
+        new_v = _scatter_rows(v_cache, v_rows, p)
         s_idx = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None], (B, S))
         out = _attend_grouped(q, new_k, new_v, q_abs, s_idx,
                               jnp.ones((B, S), bool), spec)
@@ -924,20 +918,29 @@ def _attend_grouped(q, keys, values, q_abs, k_abs, live, spec):
     return out.reshape(B, T, H * hd).astype(q.dtype)
 
 
-# One query row a sample (the decode step) attends through the Pallas
-# kernel ``pallas_attention.decode_attention``, which reads K and V in the
-# [B, S, D] tiling they are stored in and only the blocks at or below
-# pos[b]. The dense formula re-tiles both whole caches to heads-minor
-# every step (19.6 ms of BLOOM-1b7's 36.9 ms decode program on the v5e,
-# PERF.md PR 28) and attends all S rows. Which of the two a call takes is
-# read off its shapes at trace time and nothing else: T == 1, a head of
-# whole 128-lane slabs (so a head is a column block of the stored tile),
-# a block that divides S, and no ambient mesh (a kernel is one device's
-# program; under a mesh GSPMD partitions the dense formula). Prefill,
-# training and every small-head model take the dense formula as before.
+# One query row a sample (the decode step) runs on two Pallas kernels, in
+# both halves of the op (:func:`_decode_step`). ``cache_write_row`` puts
+# the step's key and value row into the caches in place, moving the one
+# 16-row block that holds each slot's row; XLA's scatter of a row a slot
+# is a ``while`` of a trip a slot (3.3 ms of BLOOM-1b7's 9.3 ms decode
+# program on the v5e, PERF.md PR 33). ``decode_attention`` then reads K and
+# V in the [B, S, D] tiling they are stored in and only the blocks at or
+# below pos[b]; the dense formula re-tiles both whole caches to heads-minor
+# every step (19.6 ms of 36.9 ms, PERF.md PR 28) and attends all S rows.
+# Which path a call takes is read off its shapes at trace time and nothing
+# else: T == 1, a head of whole 128-lane slabs (so a head is a column
+# block of the stored tile), a block that divides S, and no ambient mesh
+# (a kernel is one device's program; under a mesh GSPMD partitions the
+# dense formula). A cache that is not whole 16-row blocks keeps the
+# scatter in front of the attention kernel. Prefill, training and every
+# small-head model take the scatter and the dense formula as before.
 _DECODE_PATH_NODES = _obs.counter(
     "ops.cached_attention.decode_path",
     "cached_attention nodes traced onto the one-token decode kernel")
+_ROW_WRITE_NODES = _obs.counter(
+    "ops.cached_attention.row_write",
+    "cached_attention nodes traced with their cache rows written by the "
+    "kernel cache_write_row")
 
 
 def decode_path_nodes():
@@ -945,6 +948,11 @@ def decode_path_nodes():
     the decode kernel so far (``InferenceEngine.stats()`` reports the
     count per generate program)."""
     return _DECODE_PATH_NODES.default().value
+
+
+def row_write_nodes():
+    """How many of them wrote their rows through ``cache_write_row``."""
+    return _ROW_WRITE_NODES.default().value
 
 
 def _decode_path(query, cache, H, kv_heads=None):
@@ -962,34 +970,61 @@ def _decode_path(query, cache, H, kv_heads=None):
                         cache.dtype) is not None
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
-def _attend_decode(query, new_k, new_v, p, H, use_alibi):
-    from .pallas_attention import decode_attention
-    return decode_attention(query, new_k, new_v, p, H, alibi=use_alibi)
+def _decode_step(q, k_rows, v_rows, k_cache, v_cache, p, spec,
+                 use_alibi=False, with_grad=False):
+    """A decode step whose shapes :func:`_decode_path` admits, for both
+    halves of the op: the step's rows go into both caches, through the
+    row-write kernel where they are whole blocks, and ``decode_attention``
+    attends them. ``with_grad``: under the ``custom_vjp`` that gives the
+    multi-head formula's gradient; nothing differentiates a grouped
+    step."""
+    from .pallas_attention import WRITE_ROWS
+    by_kernel = k_cache.shape[1] % WRITE_ROWS == 0
+    _DECODE_PATH_NODES.inc()
+    if by_kernel:
+        _ROW_WRITE_NODES.inc()
+    step = _decode_kernels_with_grad if with_grad else _decode_kernels
+    return step(q, k_rows, v_rows, k_cache, v_cache, p, spec, use_alibi,
+                by_kernel)
 
 
-def _attend_decode_fwd(query, new_k, new_v, p, H, use_alibi):
-    return (_attend_decode(query, new_k, new_v, p, H, use_alibi),
-            (query, new_k, new_v, p))
+def _decode_kernels(q, k_rows, v_rows, k_cache, v_cache, p, spec, use_alibi,
+                    by_kernel):
+    from .pallas_attention import cache_write_row, decode_attention
+    S = k_cache.shape[1]
+    # row pos[b], modulo a ring's length; an idle slot may count past a
+    # cache: held inside it, as dynamic_update_slice holds its start
+    at = p % S if spec.window else jnp.clip(p, 0, S - 1)
+    write = cache_write_row if by_kernel else _scatter_rows
+    new_k, new_v = write(k_cache, k_rows, at), write(v_cache, v_rows, at)
+    out = decode_attention(q, new_k, new_v, p, spec.heads, alibi=use_alibi,
+                           num_kv_heads=spec.kv_heads, window=spec.window)
+    return out, new_k, new_v
 
 
-def _attend_decode_bwd(H, use_alibi, res, g):
-    # the kernel is forward only: differentiate the formula it computes
-    query, new_k, new_v, p = res
-    _, vjp = jax.vjp(
-        lambda q, k, v: _attend_dense(q, k, v, p, H, use_alibi),
-        query, new_k, new_v)
+_decode_kernels_with_grad = jax.custom_vjp(_decode_kernels,
+                                           nondiff_argnums=(6, 7, 8))
+
+
+def _decode_kernels_fwd(*args):
+    return _decode_kernels(*args), args[:6]
+
+
+def _decode_kernels_bwd(spec, use_alibi, by_kernel, res, g):
+    # the kernels are forward only: differentiate the formula they compute
+    *diff, p = res
+
+    def formula(q, k_rows, v_rows, k_cache, v_cache):
+        new_k = _scatter_rows(k_cache, k_rows, p)
+        new_v = _scatter_rows(v_cache, v_rows, p)
+        return (_attend_dense(q, new_k, new_v, p, spec.heads, use_alibi),
+                new_k, new_v)
+
+    _, vjp = jax.vjp(formula, *diff)
     return vjp(g) + (None,)
 
 
-_attend_decode.defvjp(_attend_decode_fwd, _attend_decode_bwd)
-
-
-def _attend_decode_grouped(q, new_k, new_v, p, spec):
-    """Forward only (serving): nothing differentiates a grouped step."""
-    from .pallas_attention import decode_attention
-    return decode_attention(q, new_k, new_v, p, spec.heads,
-                            num_kv_heads=spec.kv_heads, window=spec.window)
+_decode_kernels_with_grad.defvjp(_decode_kernels_fwd, _decode_kernels_bwd)
 
 
 # ---------------------------------------------------------------------------

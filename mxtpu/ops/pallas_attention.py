@@ -645,9 +645,13 @@ def cache_write_row(cache, rows, at):
     """``cache [B, S, D]`` with row ``at[b]`` of slot ``b`` replaced by
     ``rows[b, 0]``, in place when the cache is donated: one kernel over the
     slots, each moving the block of ``WRITE_ROWS`` rows that holds its row.
-    XLA's own scatter of one row a slot is a ``while`` of a trip a slot (4.3
-    us a trip on the v5e, and several trace events each: 64 slots and 16
-    caches of them fill a profile before its window opens, PERF.md PR 30).
+    The row is selected in float32 and cast back, which is exact for
+    bfloat16 and float32 caches. XLA's own scatter of one row a slot is a
+    ``while`` of a trip a slot (4.3 us a trip on the v5e, and several trace
+    events each: 64 slots and 16 caches of them fill a profile before its
+    window opens, PERF.md PR 30; 16 slots and 48 caches are 3.3 ms of a 9.3
+    ms decode program, PR 33). Both halves of ``ops.nn.cached_attention``
+    write a decode step's rows through it (``ops.nn._decode_step``).
     Needs ``S`` in whole blocks; ``at`` inside the cache."""
     if cache.shape[1] % WRITE_ROWS:
         raise MXNetError("cache_write_row: %d rows are not whole blocks of "
@@ -707,8 +711,8 @@ def decode_attention(q, k_cache, v_cache, pos, num_heads, alibi=False,
     Scores, the running maximum and sum and the output accumulate in
     float32. Needs a head of whole 128-lane slabs and ``block_s``
     dividing ``S``; forward only (``ops.nn.cached_attention`` gives the
-    multi-head call the dense formula's gradient; nothing differentiates
-    a grouped one)."""
+    multi-head step, row write and all, the dense formula's gradient;
+    nothing differentiates a grouped one)."""
     B, S, D = k_cache.shape
     H = int(num_heads)
     K = int(num_kv_heads or H)

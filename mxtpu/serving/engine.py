@@ -211,7 +211,9 @@ class InferenceEngine:
                        "version_rebinds": 0,
                        "gen_prefills": 0, "gen_steps": 0,
                        "gen_decode_attn_path": 0,
-                       "gen_prefill_attn_path": 0}
+                       "gen_prefill_attn_path": 0,
+                       "gen_decode_row_write": 0,
+                       "gen_prefill_row_write": 0}
         if warm:
             self.warm()
 
@@ -452,16 +454,22 @@ class InferenceEngine:
         return v
 
     @contextlib.contextmanager
-    def _noting_attn_path(self, field):
-        """Around the trace of a generate program: add to ``field`` how
-        many of its ``cached_attention`` nodes the trace put on the
-        one-token decode kernel (``ops/nn.py``): ``n_layer`` for a decode
-        program whose shapes engage it, 0 for a prefill."""
-        from ..ops.nn import decode_path_nodes
-        before = decode_path_nodes()
+    def _noting_attn_path(self, program):
+        """Around the trace of a generate program (``"decode"`` or
+        ``"prefill"``): add to ``gen_<program>_attn_path`` how many of its
+        ``cached_attention`` nodes the trace put on the one-token decode
+        kernel (``ops/nn.py``), and to ``gen_<program>_row_write`` how many
+        of those write their cache rows through the row-write kernel:
+        ``n_layer`` each for a decode program whose shapes engage them, 0
+        for a prefill."""
+        from ..ops.nn import decode_path_nodes, row_write_nodes
+        counts = {"gen_%s_attn_path" % program: decode_path_nodes,
+                  "gen_%s_row_write" % program: row_write_nodes}
+        before = {field: read() for field, read in counts.items()}
         yield
         with self._stats_lock:
-            self._stats[field] += decode_path_nodes() - before
+            for field, read in counts.items():
+                self._stats[field] += read() - before[field]
 
     def _note(self, field):
         with self._stats_lock:
@@ -951,7 +959,7 @@ class InferenceEngine:
         with warnings.catch_warnings():
             warnings.filterwarnings(
                 "ignore", message="Some donated buffers were not usable")
-            with self._noting_attn_path("gen_prefill_attn_path"):
+            with self._noting_attn_path("prefill"):
                 lowered = jitted.lower(
                     self._abs((1, L), self._dtype),
                     self._abs((1,), _np.int32),
@@ -1016,7 +1024,7 @@ class InferenceEngine:
             # is traced, so cached_attention sees it and keeps the dense
             # formula, which GSPMD partitions (the kernel is one device's)
             with self._mesh or contextlib.nullcontext(), \
-                    self._noting_attn_path("gen_decode_attn_path"):
+                    self._noting_attn_path("decode"):
                 lowered = jitted.lower(
                     self._abs((K, 1), self._dtype),
                     self._abs((K,), _np.int32),

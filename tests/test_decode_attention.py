@@ -1,8 +1,10 @@
 """The one-token decode path of ``cached_attention`` (PR 28): the Pallas
 kernel ``pallas_attention.decode_attention`` against the definition and the
-dense formula, and which shapes the op puts on it. Runs through the Pallas
-interpreter on the CPU; ``tests/test_kernels_compile_v5e.py`` compiles the
-same kernel through Mosaic at the benchmark's shapes."""
+dense formula, which shapes the op puts on it, and (PR 33) the step's rows
+going into the caches through ``pallas_attention.cache_write_row``. Runs
+through the Pallas interpreter on the CPU;
+``tests/test_kernels_compile_v5e.py`` compiles the same kernels through
+Mosaic at the benchmark's shapes."""
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -158,6 +160,51 @@ def test_cached_attention_takes_the_decode_path_at_one_token(alibi):
                                    atol=1e-6, rtol=1e-6)
 
 
+@pytest.mark.parametrize("cache_dtype", [jnp.bfloat16, jnp.float32])
+@pytest.mark.parametrize("pos", [
+    (0, 15, 16),                 # first row, a 16-row block's two edges
+    (DS - 1, DS, DS + 7)])       # last row; past the cache: an idle slot
+def test_decode_step_writes_its_rows_through_the_kernel(pos, cache_dtype):
+    """On the kernel path a cache of whole 16-row blocks takes the step's
+    rows through ``cache_write_row``: the node counts itself, and both
+    caches are the old op's bit for bit, an idle slot's included
+    (``dynamic_update_slice`` holds its start inside the cache too)."""
+    from mxtpu.ops.nn import cached_attention, row_write_nodes
+    q, k, v, kc, vc = _op_inputs(1, DH, DHD, dtype=cache_dtype)
+    p = jnp.asarray(pos, jnp.int32)
+    before = row_write_nodes()
+    out, nk, nv = jax.jit(lambda *a: cached_attention(
+        *a, num_heads=DH, alibi=True))(q, k, v, kc, vc, p)
+    assert row_write_nodes() == before + 1
+    want, wk, wv = _frozen_dense_op(q, k, v, kc, vc, p, DH, True)
+    np.testing.assert_array_equal(np.asarray(nk), np.asarray(wk))
+    np.testing.assert_array_equal(np.asarray(nv), np.asarray(wv))
+    assert not np.array_equal(np.asarray(nk), np.asarray(kc))
+    live = np.asarray(pos) < DS          # an idle slot's output is nobody's
+    np.testing.assert_allclose(np.asarray(out)[live], np.asarray(want)[live],
+                               atol=2e-5, rtol=2e-5)
+
+
+def test_decode_step_keeps_the_scatter_where_the_cache_is_no_whole_blocks():
+    """24 cache rows are a block for the attention kernel and no whole
+    16-row blocks: the step attends through the kernel behind XLA's
+    scatter, counts no row write and returns the old op's caches."""
+    from mxtpu.ops.nn import (cached_attention, decode_path_nodes,
+                              row_write_nodes)
+    q, k, v, kc, vc = _op_inputs(1, DH, DHD, S=24, dtype=jnp.float32)
+    pos = jnp.asarray([0, 17, 23], jnp.int32)
+    attended, written = decode_path_nodes(), row_write_nodes()
+    out, nk, nv = cached_attention(q, k, v, kc, vc, pos, num_heads=DH,
+                                   alibi=True)
+    assert decode_path_nodes() == attended + 1
+    assert row_write_nodes() == written
+    want, wk, wv = _frozen_dense_op(q, k, v, kc, vc, pos, DH, True)
+    np.testing.assert_array_equal(np.asarray(nk), np.asarray(wk))
+    np.testing.assert_array_equal(np.asarray(nv), np.asarray(wv))
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                               atol=2e-5, rtol=2e-5)
+
+
 @pytest.mark.parametrize("T,heads,hd,why", [
     (4, 2, 8, "a prefill chunk of small heads"),
     (1, 2, 8, "one token, but a head is no whole 128-lane slab"),
@@ -165,18 +212,20 @@ def test_cached_attention_takes_the_decode_path_at_one_token(alibi):
     (1, 2, 128, "one token, heads of 128, under an ambient mesh")])
 def test_cached_attention_keeps_the_dense_path(T, heads, hd, why):
     """Everything but the decode shape returns what it returned before,
-    bit for bit, and is not counted."""
+    bit for bit, and is not counted, on the attention kernel or on the
+    row-write kernel."""
     import contextlib
-    from mxtpu.ops.nn import cached_attention, decode_path_nodes
+    from mxtpu.ops.nn import (cached_attention, decode_path_nodes,
+                              row_write_nodes)
     from mxtpu.parallel import MeshContext
     q, k, v, kc, vc = _op_inputs(T, heads, hd, S=32, dtype=jnp.float32)
     pos = jnp.asarray([0, 5, 32 - T], jnp.int32)
     mesh = MeshContext(jax.devices()[:1], data=1) if "mesh" in why \
         else contextlib.nullcontext()
-    before = decode_path_nodes()
+    before = decode_path_nodes(), row_write_nodes()
     with mesh:
         got = cached_attention(q, k, v, kc, vc, pos, num_heads=heads,
                                alibi=True)
-    assert decode_path_nodes() == before, why
+    assert (decode_path_nodes(), row_write_nodes()) == before, why
     for g, w in zip(got, _frozen_dense_op(q, k, v, kc, vc, pos, heads, True)):
         np.testing.assert_array_equal(np.asarray(g), np.asarray(w))

@@ -161,22 +161,28 @@ def _decode_step_avals(v5e, B, S, D, cache_dtype=jnp.bfloat16):
 
 
 def test_decode_path_at_the_cells_shapes_copies_no_cache(v5e):
-    """What ISSUE 28 is about, pinned without a chip: at the saturated
-    cell's decode shapes the op is the Mosaic kernel, both caches are still
-    written in place, and nothing cache-sized is copied or kept as a
-    temporary (the dense formula re-tiled both whole caches to heads-minor
-    every step: ``temp_size_in_bytes`` 134,411,264)."""
-    from mxtpu.ops.nn import cached_attention, decode_path_nodes
+    """What ISSUEs 28 and 33 are about, pinned without a chip: at the
+    saturated cell's decode shapes the op is the Mosaic kernel, both caches
+    are still written in place, each by one row-write kernel and no scatter
+    loop (a ``while`` of 16 trips a cache), and nothing cache-sized is
+    copied or kept as a temporary (the dense formula re-tiled both whole
+    caches to heads-minor every step: ``temp_size_in_bytes``
+    134,411,264)."""
+    from mxtpu.ops.nn import (cached_attention, decode_path_nodes,
+                              row_write_nodes)
     B, S, D, H = (CELL[k] for k in "BSDH")
-    before = decode_path_nodes()
+    before, written = decode_path_nodes(), row_write_nodes()
     compiled = jax.jit(
         lambda *a: cached_attention(*a, num_heads=H, alibi=True),
         donate_argnums=(3, 4)).trace(
             *_decode_step_avals(v5e, B, S, D)).lower(
                 lowering_platforms=("tpu",)).compile()
     assert decode_path_nodes() == before + 1
+    assert row_write_nodes() == written + 1
     text = compiled.as_text()
     assert "tpu_custom_call" in text
+    assert not re.search(r"\bwhile\(", text)
+    assert len(re.findall(r"cache_write_row\S* = \S+ custom-call\(", text)) == 2
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes == 2 * B * S * D * 2 == 268435456
     assert mem.temp_size_in_bytes < 8 * 2 ** 20

@@ -763,8 +763,9 @@ def _generate_all(eng, prompts, max_new):
 
 def test_wide_heads_generate_through_the_decode_kernel(monkeypatch):
     """Prompts of unequal length on 4 slots: the tokens are those of the
-    same model traced onto the dense formula; the decode program counts
-    n_layer nodes on the kernel and the prefill programs none; nothing
+    same model traced onto the scatter and the dense formula; the decode
+    program counts n_layer nodes on the attention kernel and as many whose
+    rows the row-write kernel puts in, the prefill programs none; nothing
     retraces during the run."""
     from mxtpu.ops import nn
     monkeypatch.setenv("MXTPU_SERVE_GENERATE_PREFILL_BUCKETS", "8,32")
@@ -775,13 +776,14 @@ def test_wide_heads_generate_through_the_decode_kernel(monkeypatch):
     got, retraces = _generate_all(eng, prompts, 12)
     assert retraces == 0
     st = eng.stats()
-    assert st["gen_decode_attn_path"] == 2
-    assert st["gen_prefill_attn_path"] == 0
+    assert st["gen_decode_attn_path"] == st["gen_decode_row_write"] == 2
+    assert st["gen_prefill_attn_path"] == st["gen_prefill_row_write"] == 0
     with monkeypatch.context() as m:
         m.setattr(nn, "_decode_path", lambda *a: False)
         dense_eng = _wide_lm()
         want, _ = _generate_all(dense_eng, prompts, 12)
-    assert dense_eng.stats()["gen_decode_attn_path"] == 0
+    st = dense_eng.stats()
+    assert st["gen_decode_attn_path"] == st["gen_decode_row_write"] == 0
     assert got == want
     assert len({tuple(t) for t in got}) > 1, "degenerate model"
 
@@ -802,8 +804,10 @@ def test_sharded_engine_keeps_the_dense_formula():
     want, _ = _generate_all(one, prompts, 6)
     got, retraces = _generate_all(meshed, prompts, 6)
     assert got == want and retraces == 0
-    assert one.stats()["gen_decode_attn_path"] == 2
-    assert meshed.stats()["gen_decode_attn_path"] == 0
+    for eng, nodes in ((one, 2), (meshed, 0)):
+        st = eng.stats()
+        assert st["gen_decode_attn_path"] == nodes
+        assert st["gen_decode_row_write"] == nodes
 
 
 # ---------------------------------------------------------------------------
