@@ -1821,6 +1821,38 @@ inline Symbol gather_nd(const std::string &name,
   return op.CreateSymbol(name);
 }
 
+inline Symbol hyper_merge(const std::string &name,
+    Symbol carried,
+    Symbol write,
+    Symbol data) {
+  Operator op("hyper_merge");
+  op.SetInput("carried", carried);
+  op.SetInput("write", write);
+  op.SetInput("data", data);
+  return op.CreateSymbol(name);
+}
+
+inline Symbol hyper_mix(const std::string &name,
+    Symbol streams,
+    Symbol phi,
+    Symbol alpha,
+    Symbol base,
+    int sinkhorn_iters = 20,
+    double eps = 1e-06,
+    double clamp_min = -30.0,
+    double clamp_max = 30.0) {
+  Operator op("hyper_mix");
+  op.SetParam("sinkhorn_iters", std::to_string(sinkhorn_iters));
+  op.SetParam("eps", NumStr(eps));
+  op.SetParam("clamp_min", NumStr(clamp_min));
+  op.SetParam("clamp_max", NumStr(clamp_max));
+  op.SetInput("streams", streams);
+  op.SetInput("phi", phi);
+  op.SetInput("alpha", alpha);
+  op.SetInput("base", base);
+  return op.CreateSymbol(name);
+}
+
 inline Symbol identity(const std::string &name,
     Symbol data) {
   Operator op("identity");
@@ -1832,6 +1864,45 @@ inline Symbol khatri_rao(const std::string &name,
     const std::vector<Symbol> &args) {
   Operator op("khatri_rao");
   for (const auto &s : args) op.PushInput(s);
+  return op.CreateSymbol(name);
+}
+
+inline Symbol latent_attention(const std::string &name,
+    Symbol query,
+    Symbol kv_row,
+    Symbol c_gain,
+    Symbol kv_up_weight,
+    Symbol cache,
+    Symbol pos,
+    int num_heads = 1,
+    int nope_dim = 128,
+    int rope_dim = 64,
+    int v_dim = 128,
+    double scale = 0.0,
+    double rope_theta = 10000.0,
+    double rope_factor = 1.0,
+    double rope_beta_fast = 32.0,
+    double rope_beta_slow = 1.0,
+    int rope_orig_len = 4096,
+    double norm_eps = 1e-06) {
+  Operator op("latent_attention");
+  op.SetParam("num_heads", std::to_string(num_heads));
+  op.SetParam("nope_dim", std::to_string(nope_dim));
+  op.SetParam("rope_dim", std::to_string(rope_dim));
+  op.SetParam("v_dim", std::to_string(v_dim));
+  op.SetParam("scale", NumStr(scale));
+  op.SetParam("rope_theta", NumStr(rope_theta));
+  op.SetParam("rope_factor", NumStr(rope_factor));
+  op.SetParam("rope_beta_fast", NumStr(rope_beta_fast));
+  op.SetParam("rope_beta_slow", NumStr(rope_beta_slow));
+  op.SetParam("rope_orig_len", std::to_string(rope_orig_len));
+  op.SetParam("norm_eps", NumStr(norm_eps));
+  op.SetInput("query", query);
+  op.SetInput("kv_row", kv_row);
+  op.SetInput("c_gain", c_gain);
+  op.SetInput("kv_up_weight", kv_up_weight);
+  op.SetInput("cache", cache);
+  op.SetInput("pos", pos);
   return op.CreateSymbol(name);
 }
 

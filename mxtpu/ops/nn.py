@@ -1028,6 +1028,256 @@ _decode_kernels_with_grad.defvjp(_decode_kernels_fwd, _decode_kernels_bwd)
 
 
 # ---------------------------------------------------------------------------
+# Latent attention (DeepSeek-V2/V3's multi-head latent attention): a
+# position is ONE cached row ``[c ; k_rope]`` shared by all heads. Keys and
+# values are expanded from it a head (a chunk), or the expansion is absorbed
+# into the query and the output (one row a sample: the decode step, on the
+# kernel ``latent_decode_attention``). Neither half of ``cached_attention``
+# can express it: both take a key cache and a value cache of ``kv_heads x
+# head_dim`` columns, and the value here is the first ``rank`` columns of the
+# key's row.
+# ---------------------------------------------------------------------------
+
+_LatentSpec = collections.namedtuple(
+    "_LatentSpec", "heads nope rope v_dim rank scale freqs norm_eps")
+
+_LATENT_DECODE_NODES = _obs.counter(
+    "ops.latent_attention.decode_path",
+    "latent_attention nodes traced onto the kernel latent_decode_attention")
+
+
+def latent_decode_nodes():
+    """How many ``latent_attention`` nodes this process has traced onto the
+    latent decode kernel so far."""
+    return _LATENT_DECODE_NODES.default().value
+
+
+def yarn_frequencies(rope_dim, theta, factor=1.0, beta_fast=32.0,
+                     beta_slow=1.0, orig_len=4096):
+    """The rotary pairs' angular frequencies under YaRN, ``[rope_dim / 2]``
+    floats: ``f_j = theta^(-2j / rope_dim)`` blended toward ``f_j / factor``
+    by a ramp over the pairs that turn between ``beta_fast`` and
+    ``beta_slow`` times in ``orig_len`` positions (``factor`` 1: plain
+    rotary positions)."""
+    import math
+    half = int(rope_dim) // 2
+
+    def pair_turning(turns):
+        return (rope_dim * math.log(orig_len / (turns * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(pair_turning(beta_fast)), 0)
+    high = min(math.ceil(pair_turning(beta_slow)), half - 1)
+    out = []
+    for j in range(half):
+        f = theta ** (-2.0 * j / rope_dim)
+        g = min(max((j - low) / max(high - low, 1e-3), 0.0), 1.0)
+        out.append(f * ((1.0 - g) + g / factor))
+    return tuple(out)
+
+
+def _rotate(x, positions, freqs):
+    """Half-split rotary pairs at the angular frequencies ``freqs``: ``x
+    [B, T, ..., rope]`` (float32), ``positions [B, T]`` absolute."""
+    half = x.shape[-1] // 2
+    ang = (positions.astype(jnp.float32).reshape(
+        positions.shape + (1,) * (x.ndim - 2))
+        * jnp.asarray(freqs, jnp.float32))
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    x1, x2 = x[..., :half], x[..., half:]
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
+
+
+def _pad_columns(x, width):
+    """``x [..., w]`` with zeros up to ``width`` columns."""
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, width - x.shape[-1])])
+
+
+@register("latent_attention", num_outputs=2)
+def latent_attention(query, kv_row, c_gain, kv_up_weight, cache, pos,
+                     num_heads=1, nope_dim=128, rope_dim=64, v_dim=128,
+                     scale=0.0, rope_theta=10000.0, rope_factor=1.0,
+                     rope_beta_fast=32.0, rope_beta_slow=1.0,
+                     rope_orig_len=4096, norm_eps=1e-6):
+    """Causal attention over a latent cache. Returns ``(out, cache_next)``.
+
+    ``query [B, T, heads x (nope_dim + rope_dim)]``: head ``i``'s ``[q_nope
+    ; q_rope]``. ``kv_row [B, T, rank + rope_dim]``: ``[c ; k_rope]`` as the
+    down projection gives them; ``c`` is RMS-normalised here (gain ``c_gain
+    [rank]``) and ``q_rope``, ``k_rope`` are rotated at the absolute
+    position ``pos + t`` (YaRN frequencies from the ``rope_*`` attributes,
+    half-split pairs). ``kv_up_weight [heads x (nope_dim + v_dim), rank]``:
+    head ``i``'s ``[k_nope ; v] = W_i c``. ``cache [B, S, W]``, ``W >= rank
+    + rope_dim``: row ``s`` holds position ``s`` as ``[c ; k_rope]`` after
+    norm and rotation, in the cache's dtype, zeros in any columns past them
+    (a row padded to whole 128-lane slabs keeps XLA from giving the cache a
+    positions-minor layout on the TPU, which would cost a cache-sized copy
+    in front of each kernel); ``pos [B]`` the write offset. ``score_i(t, s)
+    = scale (q_nope,i . k_nope,i(s) + q_rope,i . k_rope(s))`` for ``s <= pos
+    + t`` (``scale`` 0: ``(nope_dim + rope_dim)^-1/2``), float32 softmax,
+    ``out [B, T, heads x v_dim]``.
+
+    A chunk expands keys and values from the rows of the whole cache. One
+    row a sample absorbs the expansion instead, ``q_lat,i = W_uk,i^T
+    q_nope,i`` and ``o_i = W_uv,i sum_s att c_s``, writes its row through
+    ``cache_write_row`` and attends on ``latent_decode_attention``: the same
+    mathematics in another order (``_latent_decode_path`` says when)."""
+    spec = _LatentSpec(
+        int(num_heads), int(nope_dim), int(rope_dim), int(v_dim),
+        kv_row.shape[2] - int(rope_dim),
+        float(scale) or (int(nope_dim) + int(rope_dim)) ** -0.5,
+        yarn_frequencies(int(rope_dim), float(rope_theta), float(rope_factor),
+                         float(rope_beta_fast), float(rope_beta_slow),
+                         float(rope_orig_len)),
+        float(norm_eps))
+    B, T, _ = query.shape
+    H, rank = spec.heads, spec.rank
+    p = pos.astype(jnp.int32).reshape(-1)
+    q_abs = p[:, None] + jnp.arange(T, dtype=jnp.int32)[None, :]   # [B, T]
+    q = query.reshape(B, T, H, spec.nope + spec.rope)
+    q_nope = q[..., :spec.nope]
+    q_rope = _rotate(q[..., spec.nope:].astype(jnp.float32), q_abs,
+                     spec.freqs).astype(query.dtype)
+    row32 = kv_row.astype(jnp.float32)
+    c = _rms_head(row32[..., :rank], c_gain, spec.norm_eps)
+    k_rope = _rotate(row32[..., rank:], q_abs, spec.freqs)
+    rows = _pad_columns(jnp.concatenate([c, k_rope], axis=-1),
+                        cache.shape[2]).astype(cache.dtype)
+    w_up = kv_up_weight.reshape(H, spec.nope + spec.v_dim, rank)
+    if _latent_decode_path(query, cache, rank):
+        return _latent_decode_step(q_nope, q_rope, rows, w_up, cache, p, spec)
+    new_cache = _scatter_rows(cache, rows, p)
+    seen = new_cache.astype(query.dtype)
+    kv = jnp.einsum("bsr,hor->bsho", seen[..., :rank], w_up.astype(query.dtype),
+                    preferred_element_type=jnp.float32).astype(query.dtype)
+    scores = spec.scale * (
+        jnp.einsum("bthd,bshd->bhts", q_nope, kv[..., :spec.nope],
+                   preferred_element_type=jnp.float32)
+        + jnp.einsum("bthd,bsd->bhts", q_rope,
+                     seen[..., rank:rank + spec.rope],
+                     preferred_element_type=jnp.float32))
+    s_idx = jnp.arange(cache.shape[1], dtype=jnp.int32)[None, None, :]
+    allowed = s_idx <= q_abs[:, :, None]                          # [B, T, S]
+    scores = jnp.where(allowed[:, None], scores, -1e30)
+    att = jax.nn.softmax(scores, axis=-1).astype(query.dtype)
+    out = jnp.einsum("bhts,bshd->bthd", att, kv[..., spec.nope:],
+                     preferred_element_type=jnp.float32)
+    return out.reshape(B, T, H * spec.v_dim).astype(query.dtype), new_cache
+
+
+def _latent_decode_path(query, cache, rank):
+    """Whether this call's shapes put it on the latent decode kernel: one
+    row a sample, a rank of whole 128-lane slabs, a block that divides the
+    cache, and no ambient mesh (a kernel is one device's program)."""
+    if query.shape[1] != 1 or rank % 128:
+        return False
+    from ..parallel.mesh import current_mesh
+    if _SEQ_PARALLEL or current_mesh() is not None:
+        return False
+    from .pallas_attention import latent_block
+    return latent_block(cache.shape[1]) is not None
+
+
+def _latent_decode_step(q_nope, q_rope, rows, w_up, cache, p, spec):
+    """The absorbed form on two kernels: the step's row goes into the cache
+    in place (``cache_write_row``: the attention kernel's blocks of whole
+    16-row tiles are its blocks too), then ``latent_decode_attention`` reads
+    each live row once for all heads."""
+    from .pallas_attention import cache_write_row, latent_decode_attention
+    B, S, W = cache.shape
+    dtype = q_nope.dtype
+    _LATENT_DECODE_NODES.inc()
+    # an idle slot may count past the cache: held inside it
+    new_cache = cache_write_row(cache, rows, jnp.clip(p, 0, S - 1))
+    w_uk, w_uv = w_up[:, :spec.nope], w_up[:, spec.nope:]
+    # heads lead as the batch dimension of both sides: the CPU backend has
+    # no bfloat16 product with float32 out for "bhd,hdr->bhr"
+    q_lat = jnp.einsum("hbd,hdr->hbr", q_nope[:, 0].swapaxes(0, 1),
+                       w_uk.astype(dtype), preferred_element_type=jnp.float32
+                       ).swapaxes(0, 1).astype(dtype)
+    q_cat = _pad_columns(jnp.concatenate([q_lat, q_rope[:, 0]], -1), W)
+    o_lat = latent_decode_attention(q_cat, new_cache, p, spec.rank,
+                                    spec.scale)
+    out = jnp.einsum("bhr,hvr->bhv", o_lat, w_uv.astype(dtype),
+                     preferred_element_type=jnp.float32)
+    return (out.reshape(B, 1, spec.heads * spec.v_dim).astype(dtype),
+            new_cache)
+
+
+# ---------------------------------------------------------------------------
+# Hyper-connections (Zhu et al., arXiv 2409.19606) in their manifold-
+# constrained form (mHC, arXiv 2512.24880): ``n`` residual streams, and
+# around every sub-layer a read weight a stream, a write weight a stream and
+# an ``n x n`` stream matrix that Sinkhorn iterations make doubly
+# stochastic, all three functions of the token's streams. ``hyper_mix`` is
+# what runs before the sub-layer, ``hyper_merge`` what runs after it.
+# ---------------------------------------------------------------------------
+
+_HYPER_MIX_NODES = _obs.counter(
+    "ops.hyper_mix.nodes", "hyper_mix nodes traced (two a decoder layer)")
+
+
+def hyper_mix_nodes():
+    return _HYPER_MIX_NODES.default().value
+
+
+def sinkhorn(logits, iters, eps):
+    """``logits [..., n, n]`` (float32) to a matrix whose rows and columns
+    sum to one: ``exp``, then ``iters`` times ``M / (rowsum + eps)`` and ``M
+    / (colsum + eps)``, every one of them whatever a tolerance would
+    forgive."""
+    m = jnp.exp(logits)
+    for _ in range(int(iters)):
+        m = m / (jnp.sum(m, axis=-1, keepdims=True) + eps)
+        m = m / (jnp.sum(m, axis=-2, keepdims=True) + eps)
+    return m
+
+
+@register("hyper_mix", num_outputs=3)
+def hyper_mix(streams, phi, alpha, base, sinkhorn_iters=20, eps=1e-6,
+              clamp_min=-30.0, clamp_max=30.0):
+    """``streams [B, T, n, d]``; ``phi [n d, n (n + 2)]``, ``alpha [3]``,
+    ``base [n (n + 2)]`` in float32. Returns ``(read [B, T, d], carried [B,
+    T, n, d], write [B, T, n])``:
+
+    ``x~ = vec(X) rsqrt(mean(vec(X)^2) + eps)`` (no gain), ``m = x~ phi``;
+    ``a = sigmoid(alpha_0 m[:n] + base[:n])``; ``write = 2 sigmoid(alpha_1
+    m[n:2n] + base[n:2n])``; ``R = sinkhorn(clamp(alpha_2 mat(m[2n:]) +
+    mat(base[2n:]), clamp_min, clamp_max))``; ``read = sum_j a_j X[j]`` (the
+    sub-layer's input before its norm, in ``streams``' dtype); ``carried[i]
+    = sum_j R[i, j] X[j]`` (float32). All of it in float32, the product with
+    ``phi`` at the highest precision: the TPU's default would round both
+    sides to bfloat16. The sums over streams are multiply-adds, not matrix
+    products, for the same reason."""
+    _HYPER_MIX_NODES.inc()
+    B, T, n, d = streams.shape
+    eps = float(eps)
+    x = streams.astype(jnp.float32)
+    flat = x.reshape(B, T, n * d)
+    flat = flat * lax.rsqrt(jnp.mean(flat * flat, -1, keepdims=True) + eps)
+    m = jnp.einsum("btk,kc->btc", flat, phi.astype(jnp.float32),
+                   precision=lax.Precision.HIGHEST)
+    alpha, base = alpha.astype(jnp.float32), base.astype(jnp.float32)
+    a = jax.nn.sigmoid(alpha[0] * m[..., :n] + base[:n])
+    write = 2.0 * jax.nn.sigmoid(alpha[1] * m[..., n:2 * n] + base[n:2 * n])
+    logits = jnp.clip(alpha[2] * m[..., 2 * n:] + base[2 * n:],
+                      float(clamp_min), float(clamp_max))
+    R = sinkhorn(logits.reshape(B, T, n, n), sinkhorn_iters, eps)
+    read = jnp.sum(a[..., None] * x, axis=2)
+    carried = jnp.sum(R[..., None] * x[:, :, None], axis=3)
+    return read.astype(streams.dtype), carried, write
+
+
+@register("hyper_merge")
+def hyper_merge(carried, write, data):
+    """``carried [B, T, n, d]`` and ``write [B, T, n]`` (``hyper_mix``'s, in
+    float32) with the sub-layer's output ``data [B, T, d]``: ``X'[i] =
+    carried[i] + write_i y``, in ``data``'s dtype."""
+    y = data.astype(jnp.float32)[:, :, None, :]
+    return (carried + write[..., None] * y).astype(data.dtype)
+
+
+# ---------------------------------------------------------------------------
 # Mixture-of-Experts FFN (ISSUE 20): the symbol-level wrapper over
 # parallel/moe.py's einsum dispatch/combine, so Module-built transformers
 # can carry an expert layer. With the expert weights rule-sharded over the

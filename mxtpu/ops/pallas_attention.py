@@ -30,6 +30,12 @@ it to hand-written Pallas TPU kernels:
   block that divides ``S`` and no ambient mesh; every other shape keeps
   the dense formula.
 
+* one-token decode over a LATENT cache
+  (:func:`latent_decode_attention`, PR 34): one ``[c ; k_rope]`` row a
+  position shared by all heads; a block of rows is fetched once and serves
+  as every head's key (all its columns) and value (the first ``rank``).
+  ``ops.nn.latent_attention`` takes it for one row a sample.
+
 Lowered for TPU the kernels compile through Mosaic; lowered for any
 other platform the same kernels run through the Pallas interpreter
 (tests), so numerics are identical everywhere (``pallas_util``). Timed
@@ -55,7 +61,8 @@ from ..base import MXNetError
 from .pallas_util import SCOPED_VMEM_LIMIT
 
 __all__ = ["flash_attention", "flash_attention_with_lse",
-           "flash_attention_reference", "decode_attention"]
+           "flash_attention_reference", "decode_attention",
+           "latent_decode_attention"]
 
 _NEG = -1e30  # large-negative instead of finfo.min: exp() underflows to 0
               # without inf - inf = nan hazards in the running-max rescale
@@ -739,3 +746,139 @@ def decode_attention(q, k_cache, v_cache, pos, num_heads, alibi=False,
     out = _decode_call()(q.reshape(B, H, hd), k_cache, v_cache, p, slopes,
                          hd, blk, H // K, int(window))
     return out.reshape(B, 1, H * hd)
+
+
+# ---------------------------------------------------------------------------
+# one-token decode attention over a LATENT cache: one [c_kv ; k_rope] row a
+# position, shared by all heads, key (all columns) and value (the first
+# ``rank``) at once
+# ---------------------------------------------------------------------------
+
+LATENT_BLOCK_S = 512    # cache rows a grid step reads
+
+
+@functools.cache
+def _latent_call():
+    """Build the latent decode kernel's pallas_call wrapper on first use."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    from .pallas_util import per_platform
+
+    def kernel(pos_ref, q_ref, c_ref, o_ref, acc_ref, m_ref, l_ref, *,
+               block_s, scale, rank):
+        b, j = pl.program_id(0), pl.program_id(1)
+        p = pos_ref[b]
+
+        @pl.when(j == 0)
+        def _init():
+            acc_ref[:] = jnp.zeros_like(acc_ref)
+            m_ref[:] = jnp.full_like(m_ref, _NEG)
+            l_ref[:] = jnp.zeros_like(l_ref)
+
+        @pl.when(j * block_s <= p)      # a block past pos[b] costs nothing
+        def _compute():
+            # the block serves twice from one fetch: every column is the
+            # key of all heads, the first ``rank`` are their value
+            rows = c_ref[0]
+            s = jax.lax.dot_general(
+                q_ref[0], rows, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale
+            at = j * block_s + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            s = jnp.where(at <= p, s, _NEG)
+            m_prev = m_ref[:]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            # row j * block_s of a computed block is live, so m_new is a
+            # real score and a masked column's exp underflows to exactly 0
+            pr = jnp.exp(s - m_new)
+            corr = jnp.exp(m_prev - m_new)
+            l_ref[:] = l_ref[:] * corr + jnp.sum(pr, axis=-1, keepdims=True)
+            m_ref[:] = m_new
+            acc_ref[:] = acc_ref[:] * corr + jax.lax.dot_general(
+                pr.astype(rows.dtype), rows[:, :rank],
+                (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+
+        @pl.when(j == pl.num_programs(1) - 1)
+        def _fin():
+            o_ref[0] = (acc_ref[:] / l_ref[:]).astype(o_ref.dtype)
+
+    @functools.partial(jax.jit, static_argnums=(3, 4, 5))
+    def call(q, cache, pos, rank, block_s, scale):
+        B, S, W = cache.shape
+        H = q.shape[1]
+
+        def rows_map(b, j, pos_ref):
+            # a dead block repeats the last live one: no new DMA
+            return (b, jnp.minimum(j, pos_ref[b] // block_s), 0)
+
+        kern = functools.partial(kernel, block_s=block_s, scale=scale,
+                                 rank=rank)
+        return per_platform(functools.partial(
+            pl.pallas_call,
+            kern,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1,
+                grid=(B, S // block_s),
+                in_specs=[
+                    pl.BlockSpec((1, H, W), lambda b, j, pos_ref: (b, 0, 0)),
+                    pl.BlockSpec((1, block_s, W), rows_map),
+                ],
+                out_specs=pl.BlockSpec((1, H, rank),
+                                       lambda b, j, pos_ref: (b, 0, 0)),
+                scratch_shapes=[
+                    pltpu.VMEM((H, rank), jnp.float32),
+                    pltpu.VMEM((H, 1), jnp.float32),
+                    pltpu.VMEM((H, 1), jnp.float32),
+                ]),
+            out_shape=jax.ShapeDtypeStruct((B, H, rank), q.dtype),
+            name="latent_decode_attention",
+        ), pos, q, cache)
+
+    return call
+
+
+def latent_block(S, block_s=None):
+    """The rows of cache one grid step of :func:`latent_decode_attention`
+    reads, or None when no block of whole 16-row tiles divides ``S``:
+    ``LATENT_BLOCK_S``, halved until it does."""
+    if block_s is None:
+        block_s = min(LATENT_BLOCK_S, S)
+        while block_s > 16 and S % block_s:
+            block_s //= 2
+    if S % block_s or block_s % 16:
+        return None
+    return block_s
+
+
+def latent_decode_attention(q, cache, pos, rank, scale, block_s=None):
+    """One query row a head and sample against a latent cache where it lies.
+
+    ``q [B, heads, rank + rope]``: head ``i``'s query with the key's
+    expansion absorbed, ``[W_uk,i^T q_nope,i ; q_rope,i]``; ``cache [B, S,
+    rank + rope]`` holds ``[c_s ; k_rope(s)]`` in row ``s`` (row ``pos[b]``
+    already written); ``pos [B]`` int32. Slot ``b`` attends rows ``s <=
+    pos[b]``: ``score_i(s) = scale * q_i . cache[b, s]``, float32 softmax,
+    and returns ``o_lat [B, heads, rank]``, ``sum_s att_i(s) c_s``, in
+    ``q``'s dtype; the caller applies ``W_uv,i``.
+
+    The grid is (slot, block of rows). A block is fetched ONCE and serves
+    as the key of every head (all its columns) and as their value (the
+    first ``rank``), so a position costs ``rank + rope`` values of traffic
+    whatever the number of heads. ``pos`` is scalar-prefetched: a block past
+    a slot's live length is neither fetched nor computed. Scores, the
+    running maximum and sum and the output accumulate in float32. Needs
+    ``rank`` in whole 128-lane slabs and a block of whole 16-row tiles that
+    divides ``S``; forward only."""
+    B, S, W = cache.shape
+    blk = latent_block(S, block_s)
+    if int(rank) % 128 or W <= int(rank) or blk is None or q.shape[2] != W:
+        raise MXNetError(
+            "latent_decode_attention: the rank %d must be whole 128-lane "
+            "slabs and under the row's %d columns (the query's: %d), and a "
+            "block of whole 16-row tiles must divide the cache length %d"
+            % (rank, W, q.shape[2], S))
+    # a slot the scheduler left idle may count past the cache: it holds
+    # nothing anyone reads, only keep its block index inside the array
+    p = jnp.clip(pos.astype(jnp.int32).reshape(-1), 0, S - 1)
+    return _latent_call()(q.astype(cache.dtype), cache, p, int(rank), blk,
+                          float(scale))

@@ -213,7 +213,11 @@ class InferenceEngine:
                        "gen_decode_attn_path": 0,
                        "gen_prefill_attn_path": 0,
                        "gen_decode_row_write": 0,
-                       "gen_prefill_row_write": 0}
+                       "gen_prefill_row_write": 0,
+                       "gen_decode_latent_path": 0,
+                       "gen_prefill_latent_path": 0,
+                       "gen_decode_hyper_mix": 0,
+                       "gen_prefill_hyper_mix": 0}
         if warm:
             self.warm()
 
@@ -461,10 +465,16 @@ class InferenceEngine:
         kernel (``ops/nn.py``), and to ``gen_<program>_row_write`` how many
         of those write their cache rows through the row-write kernel:
         ``n_layer`` each for a decode program whose shapes engage them, 0
-        for a prefill."""
-        from ..ops.nn import decode_path_nodes, row_write_nodes
-        counts = {"gen_%s_attn_path" % program: decode_path_nodes,
-                  "gen_%s_row_write" % program: row_write_nodes}
+        for a prefill. The ``latent_attention`` nodes traced onto their
+        kernel, each of which writes its row through the row-write kernel
+        too (``gen_<program>_latent_path``), and the ``hyper_mix`` nodes
+        traced at all (``gen_<program>_hyper_mix``: two a layer in every
+        program of a model with hyper-connections)."""
+        from ..ops import nn
+        counts = {"gen_%s_attn_path" % program: nn.decode_path_nodes,
+                  "gen_%s_row_write" % program: nn.row_write_nodes,
+                  "gen_%s_latent_path" % program: nn.latent_decode_nodes,
+                  "gen_%s_hyper_mix" % program: nn.hyper_mix_nodes}
         before = {field: read() for field, read in counts.items()}
         yield
         with self._stats_lock:

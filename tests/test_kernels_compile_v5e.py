@@ -245,3 +245,71 @@ def test_grouped_decode_step_at_the_cells_shapes(v5e, rows, window):
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes == 2 * B * rows * K * hd * 2
     assert mem.temp_size_in_bytes < 8 * 2 ** 20
+
+
+# -- latent attention (xing4.0-29b-a4b's decode step at its serving sizes) ---------
+
+XING4 = dict(B=128, S=3072, H=32, nope=128, rope=64, vd=128, rank=512)
+
+
+def _latent_step(v5e, width):
+    """``latent_attention``'s one-row step at the cell's shapes over a cache
+    whose rows are ``width`` columns, compiled for the v5e."""
+    from mxtpu.ops.nn import latent_attention
+    c = XING4
+    S_ = functools.partial(jax.ShapeDtypeStruct, sharding=v5e)
+    bf = jnp.bfloat16
+    return jax.jit(
+        lambda q, row, g, w, cache, pos: latent_attention(
+            q, row, g, w, cache, pos, num_heads=c["H"], nope_dim=c["nope"],
+            rope_dim=c["rope"], v_dim=c["vd"], scale=0.14468, rope_factor=64.0),
+        donate_argnums=(4,)).trace(
+            S_((c["B"], 1, c["H"] * (c["nope"] + c["rope"])), bf),
+            S_((c["B"], 1, c["rank"] + c["rope"]), bf), S_((c["rank"],), bf),
+            S_((c["H"] * (c["nope"] + c["vd"]), c["rank"]), bf),
+            S_((c["B"], c["S"], width), bf), S_((c["B"],), jnp.int32)).lower(
+                lowering_platforms=("tpu",)).compile()
+
+
+def test_latent_decode_step_at_the_cells_shapes(v5e):
+    """128 slots, 32 heads, a cache of 3072 rows of 576 values padded to
+    640 columns: the op is the Mosaic kernel behind the row-write kernel, the
+    cache is written in place, no loop, nothing cache-sized copied or kept."""
+    from mxtpu.ops.nn import latent_decode_nodes
+    c = XING4
+    before = latent_decode_nodes()
+    compiled = _latent_step(v5e, 640)
+    assert latent_decode_nodes() == before + 1
+    text = compiled.as_text()
+    assert not re.search(r"\bwhile\(", text)
+    assert len(re.findall(
+        r"latent_decode_attention\S* = \S+ custom-call\(", text)) == 1
+    assert len(re.findall(r"cache_write_row\S* = \S+ custom-call\(", text)) == 1
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == c["B"] * c["S"] * 640 * 2 == 503316480
+    assert mem.temp_size_in_bytes < 16 * 2 ** 20
+    for m in re.finditer(r"= \w+\[([\d,]+)\]\S* copy\(", text):
+        dims = [int(d) for d in m.group(1).split(",")]
+        assert functools.reduce(int.__mul__, dims) < 2 ** 24, m.group(0)
+
+
+def test_a_576_column_cache_is_copied_whole_every_step(v5e):
+    """Why the rows are padded: XLA lays an array of 576 columns out
+    positions-minor on the TPU (no padding to 640 lanes that way), the
+    kernels want rows-minor, and a copy of the whole cache, padded, stands
+    in front of them every step."""
+    c = XING4
+    mem = _latent_step(v5e, 576).memory_analysis()
+    assert mem.temp_size_in_bytes >= c["B"] * c["S"] * 640 * 2
+
+
+@pytest.mark.parametrize("block_s", [256, 1024])
+def test_latent_kernel_compiles_at_other_blocks(v5e, block_s):
+    from mxtpu.ops.pallas_attention import latent_decode_attention
+    c = XING4
+    S_ = functools.partial(jax.ShapeDtypeStruct, sharding=v5e)
+    assert "tpu_custom_call" in _compile(
+        lambda q, cache, pos: latent_decode_attention(
+            q, cache, pos, c["rank"], 0.14468, block_s=block_s),
+        S_((c["B"], c["H"], 640), jnp.bfloat16),
+        S_((c["B"], c["S"], 640), jnp.bfloat16), S_((c["B"],), jnp.int32))

@@ -247,6 +247,16 @@ SKIP = {
                     "in; the held shares vs the reference's uncut layer in "
                     "tests/test_exaone_moe_serving.py"
                     "::test_eight_shares_add_up_to_the_uncut_layer",
+    "latent_attention": "two outputs over a carried latent cache (write "
+                        "offset in, next cache out), a kernel on one row a "
+                        "sample; the per-head definition, chunked and "
+                        "absorbed, in tests/test_latent_attention.py",
+    "hyper_mix": "three outputs of two dtypes (the read in the streams' "
+                 "dtype, the carried streams and write weights in float32); "
+                 "the definition in float64 in tests/test_hyper_mix.py",
+    "hyper_merge": "float32 operands from hyper_mix, the result in the "
+                   "sub-layer's dtype; with hyper_mix in "
+                   "tests/test_hyper_mix.py",
 }
 
 # --------------------------------------------------------------------------
