@@ -23,9 +23,10 @@ it to hand-written Pallas TPU kernels:
 * one-token decode over the serving engine's packed ``[B, S, D]`` cache
   (:func:`decode_attention`): the cache is read in the tiling it is
   stored in, a block of rows with all heads' columns at a time, the
-  heads kept apart by a block-diagonal query matrix; ``pos`` is
-  scalar-prefetched so blocks past a slot's live length are neither
-  fetched nor computed. ``ops.nn.cached_attention`` takes it whenever a
+  heads kept apart by a block-diagonal query matrix; the grid is the
+  list of live blocks (:func:`live_blocks`, from ``pos``: whole blocks,
+  then tail blocks of a quarter), so a block past a slot's live length
+  is no grid step. ``ops.nn.cached_attention`` takes it whenever a
   call has one query row a sample, heads of whole 128-lane slabs, a
   block that divides ``S`` and no ambient mesh; every other shape keeps
   the dense formula.
@@ -467,7 +468,86 @@ def flash_attention_reference(q, k, v, causal=False, scale=None,
 # one-token decode attention over the packed [B, S, D] cache
 # ---------------------------------------------------------------------------
 
-DECODE_BLOCK_S = 256    # cache rows a grid step reads (timed on the v5e)
+# Bytes of K (and as many of V) a whole block holds: 128 rows of BLOOM-1b7's
+# 4 KiB, 256 of K-EXAONE's 2 KiB. Every grid step is a live block
+# (:func:`live_blocks`), so the block only has to be long enough for a step's
+# DMA to cover the step's own cost (timed on the v5e, PERF.md PR 35).
+DECODE_BLOCK_BYTES = 512 * 1024
+# A slot's rows past its last whole block are read in tail blocks, this many
+# to a block: a quarter of a block is the most a slot fetches past ``pos``.
+TAIL_BLOCKS = 4
+_FIRST, _LAST, _TAIL = 1, 2, 4      # what a grid step is, in ``flags``
+
+
+def tail_rows(block_s):
+    """The rows of a tail block: ``block_s / TAIL_BLOCKS`` where that is whole
+    16-row tiles, else the block itself (no finer tail)."""
+    rows = block_s // TAIL_BLOCKS
+    return rows if rows and rows % 16 == 0 else block_s
+
+
+def live_blocks(pos, S, block_s, tail_s):
+    """The grid of both decode kernels: the blocks that hold a live row,
+    slot after slot. ``pos [B]`` int32; slot ``b`` has ``n = clip(pos[b], 0,
+    S - 1) + 1`` live rows (a full cache's position, already inside it, or
+    all of a ring once it has turned), read as its ``n // block_s`` whole
+    blocks and then, in blocks of ``tail_s`` rows (which divides ``block_s``),
+    what is left: at least one step, and at most ``tail_s - 1`` rows past
+    ``pos``.
+
+    Returns ``(slot, block, tail, flags, total)``: four int32 tables of ``B x
+    (S // block_s - 1 + block_s // tail_s) + 1`` entries and the number of
+    grid steps. Step ``i`` belongs to slot ``slot[i]``; ``flags[i]`` says
+    whether it is the slot's first (1), its last (2) and a tail block (4).
+    ``block[i]`` and ``tail[i]`` name the whole block and the tail block the
+    step's two windows on the cache show, as ``slot x blocks a slot + block``:
+    the one the step works on, and for the other window the last one a step
+    before it worked on, so a window moves (and fetches) only when it is
+    used. Entries from ``total`` on repeat step ``total - 1``, so the entry a
+    pipeline reads one step ahead of the last lies inside the arrays and
+    names blocks that are already there.
+
+    Comparisons against the running sum only: no gather, no sort, no
+    ``while`` in the compiled step (``jnp.searchsorted``'s default lowers
+    to one), and one expression for every layer of a program that shares
+    ``pos``, ``S`` and the blocks, which XLA merges."""
+    p = pos.astype(jnp.int32).reshape(-1)
+    B = p.shape[0]
+    nblk, ntail, ratio = S // block_s, S // tail_s, block_s // tail_s
+    rows = jnp.clip(p, 0, S - 1) + 1
+    whole = rows // block_s
+    tails = (rows - whole * block_s + tail_s - 1) // tail_s
+    steps = whole + tails
+    before = jnp.arange(B)[:, None] <= jnp.arange(B)[None, :]
+    ends = jnp.sum(jnp.where(before, steps[:, None], 0), axis=0)  # cumsum
+    starts = ends - steps
+    total = jnp.sum(steps)
+    i = jnp.minimum(jnp.arange(B * (nblk - 1 + ratio) + 1, dtype=jnp.int32),
+                    total - 1)[:, None]
+    # [steps, B]: the slots that have begun by step i, those wholly behind it
+    begun, done = starts[None, :] <= i, ends[None, :] <= i
+
+    def of_slot(x):     # x[slot[i]], without a gather
+        return jnp.sum(jnp.where(begun & ~done, x[None, :], 0), axis=1)
+
+    def newest(x, seen):    # the largest x of the slots seen (x grows with b)
+        return jnp.max(jnp.where(seen, x[None, :], 0), axis=1)
+
+    slot = jnp.sum(done, axis=1, dtype=jnp.int32)
+    k = i[:, 0] - of_slot(starts)           # the step's place in its slot
+    w = of_slot(whole)
+    in_tail = k >= w
+    ids = jnp.arange(B, dtype=jnp.int32)
+    last_block = jnp.where(whole > 0, ids * nblk + whole - 1, 0)
+    last_tail = jnp.where(tails > 0,
+                          ids * ntail + whole * ratio + tails - 1, 0)
+    block = jnp.where(in_tail, newest(last_block, begun), slot * nblk + k)
+    tail = jnp.where(in_tail, slot * ntail + w * (ratio - 1) + k,
+                     newest(last_tail, done))
+    flags = (_FIRST * (k == 0) + _LAST * (i[:, 0] == of_slot(ends) - 1)
+             + _TAIL * in_tail)
+    return (slot, block.astype(jnp.int32), tail.astype(jnp.int32),
+            flags.astype(jnp.int32), total)
 
 
 @functools.cache
@@ -477,15 +557,13 @@ def _decode_call():
     from jax.experimental.pallas import tpu as pltpu
     from .pallas_util import per_platform
 
-    def kernel(pos_ref, q_ref, slope_ref, k_ref, v_ref, o_ref,
-               qbd_ref, acc_ref, m_ref, l_ref, *, hd, block_s, scale, group,
-               window):
-        b, j = pl.program_id(0), pl.program_id(1)
-        p = pos_ref[b]
+    def kernel(slot_ref, block_ref, tail_ref, flag_ref, pos_ref, q_ref,
+               slope_ref, k_ref, v_ref, kt_ref, vt_ref, o_ref, qbd_ref,
+               acc_ref, m_ref, l_ref, *, hd, S, scale, group, window):
+        i = pl.program_id(0)
+        flags = flag_ref[i]
+        p = pos_ref[slot_ref[i]]
         rows, d = qbd_ref.shape
-        ring = k_ref.shape[1] * pl.num_programs(1) if window else 0
-        # the newest row a block may hold: pos itself, or the ring's end
-        newest = jnp.minimum(p, ring - 1) if window else p
 
         def own_lanes():
             """[rows, D]: True where a lane belongs to its row's key/value
@@ -496,7 +574,7 @@ def _decode_call():
             lane = jax.lax.broadcasted_iota(jnp.int32, (rows, d), 1)
             return (lane >= head * hd) & (lane < (head + 1) * hd)
 
-        @pl.when(j == 0)
+        @pl.when(flags & _FIRST != 0)
         def _init():
             # the query as a block-diagonal [heads, D] matrix: row h
             # holds head h's 128-lane slab and zeros elsewhere, so ONE
@@ -514,19 +592,20 @@ def _decode_call():
             m_ref[:] = jnp.full_like(m_ref, _NEG)
             l_ref[:] = jnp.zeros_like(l_ref)
 
-        @pl.when(j * block_s <= newest)  # a block past pos[b] costs nothing
-        def _compute():
+        def attend(k_ref, v_ref, index):
+            """One live block of the slot's rows, whole or tail: block
+            ``index`` of the slot's, as long as its window on the cache."""
             cdt = qbd_ref.dtype
             s = jax.lax.dot_general(
                 qbd_ref[:], k_ref[0].astype(cdt), (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32) * scale
-            at = j * block_s + jax.lax.broadcasted_iota(
+            at = index * k_ref.shape[1] + jax.lax.broadcasted_iota(
                 jnp.int32, s.shape, 1)
             if window:
                 # row r of a ring holds the newest position that is r
                 # modulo its length: how far back from pos that lies
-                at_p = jax.lax.rem(p, ring)
-                dist = jnp.where(at <= at_p, at_p - at, at_p - at + ring)
+                at_p = jax.lax.rem(p, S)
+                dist = jnp.where(at <= at_p, at_p - at, at_p - at + S)
                 live = (dist < window) & (dist <= p)
             else:
                 dist = p - at
@@ -535,10 +614,10 @@ def _decode_call():
             s = jnp.where(live, s, _NEG)
             m_prev = m_ref[:]
             m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-            # a computed block holds a live row (row j*block_s without a
-            # window; in a ring, row pos mod ring or block 0's row 0), so
-            # m_new is a real score once the live block has been seen and
-            # a masked column's exp underflows to exactly 0
+            # a live block holds a live row (its first without a window;
+            # in a ring, row pos mod ring or block 0's row 0), so m_new is
+            # a real score once the live block has been seen and a masked
+            # column's exp underflows to exactly 0
             pr = jnp.where(live, jnp.exp(s - m_new), 0.0) if window \
                 else jnp.exp(s - m_new)
             corr = jnp.exp(m_prev - m_new)
@@ -549,7 +628,16 @@ def _decode_call():
                 (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
 
-        @pl.when(j == pl.num_programs(1) - 1)
+        # every grid step is a live block (:func:`live_blocks`)
+        @pl.when(flags & _TAIL == 0)
+        def _whole():
+            attend(k_ref, v_ref, block_ref[i] % (S // k_ref.shape[1]))
+
+        @pl.when(flags & _TAIL != 0)
+        def _tail():
+            attend(kt_ref, vt_ref, tail_ref[i] % (S // kt_ref.shape[1]))
+
+        @pl.when(flags & _LAST != 0)
         def _fin():
             # row h of acc is head h's weights times ALL of V's columns;
             # its own slab is the head's output
@@ -566,32 +654,40 @@ def _decode_call():
              window=0):
         B, S, D = k_cache.shape
         rows = slopes.shape[0]
-        nblk = S // block_s
-
-        def kv_map(b, j, pos_ref):
-            # a dead block repeats the last live one: no new DMA
-            newest = jnp.minimum(pos_ref[b], S - 1) if window else pos_ref[b]
-            return (b, jnp.minimum(j, newest // block_s), 0)
-
+        tail_s = tail_rows(block_s)
+        *tables, total = live_blocks(pos, S, block_s, tail_s)
         # grouped: the query and the output ride as [B, heads, hd], a
         # head a row, which is how the kernel's matrices want them
         q_block = (1, 1, D) if group == 1 else (1, rows, hd)
-        kern = functools.partial(kernel, hd=hd, block_s=block_s,
-                                 scale=hd ** -0.5, group=group, window=window)
+        nblk, ntail = S // block_s, S // tail_s
+
+        def slot_map(i, slot_ref, *_):
+            return (slot_ref[i], 0, 0)
+
+        # the cache's two windows, of whole blocks and of tail blocks
+        def block_map(i, _slot_ref, block_ref, *_):
+            return (block_ref[i] // nblk, block_ref[i] % nblk, 0)
+
+        def tail_map(i, _slot_ref, _block_ref, tail_ref, *_):
+            return (tail_ref[i] // ntail, tail_ref[i] % ntail, 0)
+
+        kern = functools.partial(kernel, hd=hd, S=S, scale=hd ** -0.5,
+                                 group=group, window=window)
         return per_platform(functools.partial(
             pl.pallas_call,
             kern,
             grid_spec=pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=1,
-                grid=(B, nblk),
+                num_scalar_prefetch=5,
+                grid=(total,),
                 in_specs=[
-                    pl.BlockSpec(q_block, lambda b, j, pos_ref: (b, 0, 0)),
-                    pl.BlockSpec((rows, 1), lambda b, j, pos_ref: (0, 0)),
-                    pl.BlockSpec((1, block_s, D), kv_map),
-                    pl.BlockSpec((1, block_s, D), kv_map),
+                    pl.BlockSpec(q_block, slot_map),
+                    pl.BlockSpec((rows, 1), lambda i, *_: (0, 0)),
+                    pl.BlockSpec((1, block_s, D), block_map),
+                    pl.BlockSpec((1, block_s, D), block_map),
+                    pl.BlockSpec((1, tail_s, D), tail_map),
+                    pl.BlockSpec((1, tail_s, D), tail_map),
                 ],
-                out_specs=pl.BlockSpec(q_block,
-                                       lambda b, j, pos_ref: (b, 0, 0)),
+                out_specs=pl.BlockSpec(q_block, slot_map),
                 scratch_shapes=[
                     pltpu.VMEM((rows, D), q.dtype),
                     pltpu.VMEM((rows, D), jnp.float32),
@@ -600,7 +696,7 @@ def _decode_call():
                 ]),
             out_shape=jax.ShapeDtypeStruct((B,) + q_block[1:], q.dtype),
             name="decode_attention",
-        ), pos, q, slopes, k_cache, v_cache)
+        ), *tables, pos, q, slopes, k_cache, v_cache, k_cache, v_cache)
 
     return call
 
@@ -668,20 +764,16 @@ def cache_write_row(cache, rows, at):
 
 def decode_block(S, D, cache_dtype, block_s=None):
     """The rows of cache one grid step of :func:`decode_attention`
-    reads, or None when no block divides ``S``: ``DECODE_BLOCK_S``,
-    halved until K's and V's double-buffered blocks fit half of the
-    scoped-VMEM limit (512 rows of 4 KiB compile, 1024 Mosaic refuses). An
-    explicit ``block_s`` over that raises."""
+    reads, or None when no block divides ``S``: the power of two whose rows
+    fill ``DECODE_BLOCK_BYTES`` (the whole cache where it is shorter). An
+    explicit ``block_s`` whose K and V blocks, double-buffered, pass half of
+    the scoped-VMEM limit raises (512 rows of 4 KiB compile, 1024 Mosaic
+    refuses)."""
     row_bytes = D * jnp.dtype(cache_dtype).itemsize
-
-    def fits(rows):
-        return 4 * rows * row_bytes <= SCOPED_VMEM_LIMIT // 2
-
     if block_s is None:
-        block_s = min(DECODE_BLOCK_S, S)
-        while block_s > 8 and not fits(block_s):
-            block_s //= 2
-    elif not fits(block_s):
+        rows = max(DECODE_BLOCK_BYTES // row_bytes, 8)
+        block_s = min(1 << (rows.bit_length() - 1), S)
+    elif 4 * block_s * row_bytes > SCOPED_VMEM_LIMIT // 2:
         raise MXNetError(
             "decode_attention: a block of %d rows of %d bytes, K and V "
             "double-buffered (%d MiB), does not fit the %d MiB "
@@ -710,11 +802,16 @@ def decode_attention(q, k_cache, v_cache, pos, num_heads, alibi=False,
     of ``pos[b]`` already written) and a slot attends the ring's live rows
     within ``window`` positions of ``pos[b]``.
 
-    The cache is read in the layout it is stored in: the grid is
-    (slot, block of rows), a block holds ALL heads' columns, and the
+    The cache is read in the layout it is stored in: a grid step is one
+    block of one slot's rows, a block holds ALL heads' columns, and the
     heads are kept apart by a block-diagonal query matrix, not by
-    re-tiling K and V to heads-minor. ``pos`` is scalar-prefetched, so
-    a block past a slot's live length is neither fetched nor computed.
+    re-tiling K and V to heads-minor. The grid is ONE dimension over the
+    live blocks of all slots (:func:`live_blocks`: its length and the slot
+    and block of each step are reckoned from ``pos`` and scalar-prefetched),
+    so a block past a slot's live length is no grid step; the rows past a
+    slot's last whole block are read in tail blocks of a quarter of a block
+    (:func:`tail_rows`), through a second, shorter window on the same cache,
+    so under a quarter of a block is fetched past ``pos``.
     Scores, the running maximum and sum and the output accumulate in
     float32. Needs a head of whole 128-lane slabs and ``block_s``
     dividing ``S``; forward only (``ops.nn.cached_attention`` gives the
@@ -754,7 +851,11 @@ def decode_attention(q, k_cache, v_cache, pos, num_heads, alibi=False,
 # ``rank``) at once
 # ---------------------------------------------------------------------------
 
-LATENT_BLOCK_S = 512    # cache rows a grid step reads
+# Cache rows a grid step reads. 32 query rows a slot keep a step bound by the
+# MXU's tile loads, not by its 640 KiB of rows: 256 rows take as long a row and
+# twice the steps, and tail blocks of a quarter, which :func:`decode_attention`
+# gains by, cost Xing4.0's cell 2.4% (timed on the v5e, PERF.md PR 35)
+LATENT_BLOCK_S = 512
 
 
 @functools.cache
@@ -764,41 +865,42 @@ def _latent_call():
     from jax.experimental.pallas import tpu as pltpu
     from .pallas_util import per_platform
 
-    def kernel(pos_ref, q_ref, c_ref, o_ref, acc_ref, m_ref, l_ref, *,
-               block_s, scale, rank):
-        b, j = pl.program_id(0), pl.program_id(1)
-        p = pos_ref[b]
+    def kernel(slot_ref, block_ref, flag_ref, pos_ref, q_ref, c_ref, o_ref,
+               acc_ref, m_ref, l_ref, *, scale, rank):
+        i = pl.program_id(0)
+        flags = flag_ref[i]
+        p = pos_ref[slot_ref[i]]
 
-        @pl.when(j == 0)
+        @pl.when(flags & _FIRST != 0)
         def _init():
             acc_ref[:] = jnp.zeros_like(acc_ref)
             m_ref[:] = jnp.full_like(m_ref, _NEG)
             l_ref[:] = jnp.zeros_like(l_ref)
 
-        @pl.when(j * block_s <= p)      # a block past pos[b] costs nothing
-        def _compute():
-            # the block serves twice from one fetch: every column is the
-            # key of all heads, the first ``rank`` are their value
-            rows = c_ref[0]
-            s = jax.lax.dot_general(
-                q_ref[0], rows, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale
-            at = j * block_s + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            s = jnp.where(at <= p, s, _NEG)
-            m_prev = m_ref[:]
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-            # row j * block_s of a computed block is live, so m_new is a
-            # real score and a masked column's exp underflows to exactly 0
-            pr = jnp.exp(s - m_new)
-            corr = jnp.exp(m_prev - m_new)
-            l_ref[:] = l_ref[:] * corr + jnp.sum(pr, axis=-1, keepdims=True)
-            m_ref[:] = m_new
-            acc_ref[:] = acc_ref[:] * corr + jax.lax.dot_general(
-                pr.astype(rows.dtype), rows[:, :rank],
-                (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
+        # every grid step is a live block (:func:`live_blocks`), and it
+        # serves twice from one fetch: every column is the key of all
+        # heads, the first ``rank`` are their value
+        rows = c_ref[0]
+        s = jax.lax.dot_general(
+            q_ref[0], rows, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale
+        at = block_ref[i] * rows.shape[0] + jax.lax.broadcasted_iota(
+            jnp.int32, s.shape, 1)
+        s = jnp.where(at <= p, s, _NEG)
+        m_prev = m_ref[:]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        # the first row of a live block is live, so m_new is a real
+        # score and a masked column's exp underflows to exactly 0
+        pr = jnp.exp(s - m_new)
+        corr = jnp.exp(m_prev - m_new)
+        l_ref[:] = l_ref[:] * corr + jnp.sum(pr, axis=-1, keepdims=True)
+        m_ref[:] = m_new
+        acc_ref[:] = acc_ref[:] * corr + jax.lax.dot_general(
+            pr.astype(rows.dtype), rows[:, :rank],
+            (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
 
-        @pl.when(j == pl.num_programs(1) - 1)
+        @pl.when(flags & _LAST != 0)
         def _fin():
             o_ref[0] = (acc_ref[:] / l_ref[:]).astype(o_ref.dtype)
 
@@ -806,25 +908,30 @@ def _latent_call():
     def call(q, cache, pos, rank, block_s, scale):
         B, S, W = cache.shape
         H = q.shape[1]
+        # no finer tail (LATENT_BLOCK_S says why): a slot's last block is a
+        # block like the others, and the one window shows whichever it is
+        slot, block, tail, flags, total = live_blocks(pos, S, block_s,
+                                                      block_s)
+        block = jnp.where(flags & _TAIL != 0, tail, block) % (S // block_s)
 
-        def rows_map(b, j, pos_ref):
-            # a dead block repeats the last live one: no new DMA
-            return (b, jnp.minimum(j, pos_ref[b] // block_s), 0)
+        def slot_map(i, slot_ref, *_):
+            return (slot_ref[i], 0, 0)
 
-        kern = functools.partial(kernel, block_s=block_s, scale=scale,
-                                 rank=rank)
+        def rows_map(i, slot_ref, block_ref, *_):
+            return (slot_ref[i], block_ref[i], 0)
+
+        kern = functools.partial(kernel, scale=scale, rank=rank)
         return per_platform(functools.partial(
             pl.pallas_call,
             kern,
             grid_spec=pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=1,
-                grid=(B, S // block_s),
+                num_scalar_prefetch=4,
+                grid=(total,),
                 in_specs=[
-                    pl.BlockSpec((1, H, W), lambda b, j, pos_ref: (b, 0, 0)),
+                    pl.BlockSpec((1, H, W), slot_map),
                     pl.BlockSpec((1, block_s, W), rows_map),
                 ],
-                out_specs=pl.BlockSpec((1, H, rank),
-                                       lambda b, j, pos_ref: (b, 0, 0)),
+                out_specs=pl.BlockSpec((1, H, rank), slot_map),
                 scratch_shapes=[
                     pltpu.VMEM((H, rank), jnp.float32),
                     pltpu.VMEM((H, 1), jnp.float32),
@@ -832,7 +939,7 @@ def _latent_call():
                 ]),
             out_shape=jax.ShapeDtypeStruct((B, H, rank), q.dtype),
             name="latent_decode_attention",
-        ), pos, q, cache)
+        ), slot, block, flags, pos, q, cache)
 
     return call
 
@@ -861,11 +968,13 @@ def latent_decode_attention(q, cache, pos, rank, scale, block_s=None):
     and returns ``o_lat [B, heads, rank]``, ``sum_s att_i(s) c_s``, in
     ``q``'s dtype; the caller applies ``W_uv,i``.
 
-    The grid is (slot, block of rows). A block is fetched ONCE and serves
-    as the key of every head (all its columns) and as their value (the
+    A grid step is one block of one slot's rows. A block is fetched ONCE and
+    serves as the key of every head (all its columns) and as their value (the
     first ``rank``), so a position costs ``rank + rope`` values of traffic
-    whatever the number of heads. ``pos`` is scalar-prefetched: a block past
-    a slot's live length is neither fetched nor computed. Scores, the
+    whatever the number of heads. The grid is ONE dimension over the live
+    blocks of all slots (:func:`live_blocks`, as :func:`decode_attention`'s,
+    with no finer tail): a block past a slot's live length is no grid step.
+    Scores, the
     running maximum and sum and the output accumulate in float32. Needs
     ``rank`` in whole 128-lane slabs and a block of whole 16-row tiles that
     divides ``S``; forward only."""

@@ -86,6 +86,97 @@ def test_decode_kernel_never_reads_rows_past_pos(alibi):
     assert np.isfinite(np.asarray(idle)).all()
 
 
+@pytest.mark.parametrize("alibi", [False, True])
+@pytest.mark.parametrize("pos", [
+    (0, 0, 0),                           # the grid is ``slots`` long
+    (DBLK - 1, DBLK, DS - 1),            # a block's two edges, every block
+    (5, DS + 9, 2 * DBLK)])              # an idle slot among live ones
+def test_decode_kernel_visits_only_live_blocks(pos, alibi):
+    """The grid runs over the live blocks alone (PR 35): NaN in every block
+    past a slot's last live one, whole or tail, reaches nothing, where a
+    block that was fetched, or a step that was taken, would put it into the
+    output. An idle slot counts past the cache: held inside it, all its
+    blocks live."""
+    from mxtpu.ops.pallas_attention import decode_attention
+    q, kc, vc = _decode_case(jnp.float32, seed=9)
+    newest = np.minimum(np.asarray(pos), DS - 1)
+    past = _past_the_live_blocks(newest, DS, DBLK)
+    p = jnp.asarray(pos, jnp.int32)
+    out = decode_attention(q, jnp.where(past, jnp.nan, kc),
+                           jnp.where(past, jnp.nan, vc), p, DH, alibi=alibi,
+                           block_s=DBLK)
+    ref = _np_decode_reference(q, kc, vc, newest, DH, alibi)
+    np.testing.assert_allclose(np.asarray(out), ref, atol=2e-5, rtol=2e-5)
+
+
+def _past_the_live_blocks(newest, S, block_s):
+    """[B, S, 1]: True for the rows no live block holds: past the slot's
+    whole blocks and the tail blocks over what is left up to ``newest``."""
+    from mxtpu.ops.pallas_attention import tail_rows
+    tail_s = tail_rows(block_s)
+    assert tail_s < block_s
+    rows = np.asarray(newest) + 1
+    whole = rows // block_s * block_s
+    held = whole + -(-(rows - whole) // tail_s) * tail_s
+    return np.arange(S)[None, :, None] >= held[:, None, None]
+
+
+def _live_blocks_by_a_loop(pos, S, block_s, tail_s):
+    """``(slot, block, tail, flags)`` of every grid step: a slot's whole
+    blocks, then tail blocks over the rest; each window names what it last
+    showed where the step does not use it."""
+    steps, block, tail = [], 0, 0
+    for b, p in enumerate(pos):
+        rows = min(max(int(p), 0), S - 1) + 1
+        whole = rows // block_s
+        n = whole + -(-(rows - whole * block_s) // tail_s)
+        for k in range(n):
+            if k < whole:
+                block = b * (S // block_s) + k
+            else:
+                tail = b * (S // tail_s) + whole * (block_s // tail_s) \
+                    + k - whole
+            steps.append((b, block, tail,
+                          (k == 0) + 2 * (k == n - 1) + 4 * (k >= whole)))
+    return steps
+
+
+@pytest.mark.parametrize("S,block_s,tail_s,pos", [
+    (256, 64, 16, (0, 0, 0)),            # one tail block a slot
+    (256, 64, 16, (63, 64, 255)),        # a block's two edges, the last row
+    (256, 64, 16, (255, 255, 255)),      # every whole block of every slot
+    (256, 64, 16, (254, 254, 254)),      # the most steps a slot can have
+    (256, 64, 16, (5, 300, 128)),        # an idle slot counting past the cache
+    (32, 16, 16, (5, 40, 31, 16)),       # a ring before and past its first
+                                         # turn; the tail is the block
+    (128, 128, 32, (0, 500, 127, 3)),    # one block a slot (K-EXAONE's rings)
+    (2048, 128, 32, tuple(range(7, 2048, 131))),
+    (3072, 512, 128, tuple(range(0, 3072, 41)))])
+def test_live_blocks_against_a_plain_loop(S, block_s, tail_s, pos):
+    """Slot, whole block, tail block and flags of every grid step up to the
+    total, and the entries past it: one more entry than the most steps the
+    slots can have (a pipeline reads one step ahead of the last, also when
+    every slot has the most), each repeating the last step, so inside the
+    arrays."""
+    from mxtpu.ops.pallas_attention import live_blocks
+    *tables, total = jax.jit(lambda p: live_blocks(p, S, block_s, tail_s))(
+        jnp.asarray(pos, jnp.int32))
+    want = _live_blocks_by_a_loop(pos, S, block_s, tail_s)
+    n = len(pos) * (S // block_s - 1 + block_s // tail_s) + 1
+    assert int(total) == len(want) < n
+    for table in tables:
+        assert table.shape == (n,) and table.dtype == jnp.int32
+    got = list(zip(*(np.asarray(t).tolist() for t in tables)))
+    assert got[:len(want)] == want
+    assert got[len(want):] == [want[-1]] * (n - len(want))
+    if pos == (254,) * 3:
+        assert len(want) == n - 1
+    # no loop in the compiled step: comparisons against the running sum
+    text = jax.jit(lambda p: live_blocks(p, S, block_s, tail_s)).lower(
+        jnp.asarray(pos, jnp.int32)).compile().as_text()
+    assert "while(" not in text
+
+
 def _op_inputs(T, heads, hd, S=DS, seed=5, dtype=jnp.bfloat16):
     rng = np.random.RandomState(seed)
     D = heads * hd

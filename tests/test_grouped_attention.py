@@ -110,6 +110,60 @@ def test_prefill_then_decode_equals_the_definition(name):
     assert (on_kernel > 0) == name.startswith("kernel")
 
 
+def _ring_rows_definition(q, kc, vc, pos, heads, kv_heads, window):
+    """float64, a row at a time: row ``r`` of a ring of ``S`` rows holds the
+    newest position at or below ``pos`` that is ``r`` modulo ``S`` (with
+    ``window`` 0 the cache is full: row ``r`` is position ``r``); a slot
+    attends what lies within ``window`` positions of ``pos``."""
+    q, kc, vc = (np.asarray(a, np.float64) for a in (q, kc, vc))
+    B, S, D = kc.shape
+    hd = D // kv_heads
+    out = np.zeros((B, heads * hd))
+    for b in range(B):
+        p = int(pos[b])
+        at = np.array([p - (p - r) % S if window else r for r in range(S)])
+        live = (at >= 0) & (at <= p) & ((p - at < window) | (window == 0))
+        for h in range(heads):
+            c = slice(h // (heads // kv_heads) * hd,
+                      (h // (heads // kv_heads) + 1) * hd)
+            s = kc[b, live, c] @ q[b, 0, h * hd:(h + 1) * hd] / np.sqrt(hd)
+            w = np.exp(s - s.max())
+            out[b, h * hd:(h + 1) * hd] = (w / w.sum()) @ vc[b, live, c]
+    return out[:, None]
+
+
+@pytest.mark.parametrize("name,S,window,pos", [
+    ("full-all-at-0", 128, 0, (0, 0, 0, 0)),
+    ("full-block-edges", 128, 0, (15, 16, 63, 64)),
+    ("full-idle-slot-among-live", 128, 0, (5, 128 + 9, 127, 70)),
+    ("ring-first-turn", 128, 40, (0, 47, 64, 126)),
+    ("ring-past-its-first-turn", 128, 40, (128, 200, 1000, 5)),
+    ("ring-of-one-block", 64, 64, (0, 17, 64, 300))])
+def test_grouped_kernel_visits_only_live_blocks(name, S, window, pos):
+    """``decode_attention`` itself, 8 query heads over 2 key/value heads of
+    128, blocks of 64 rows and tail blocks of 16, on the live grid (PR 35).
+    NaN fills every row past a slot's last live block, whole or tail (a ring
+    that has not turned yet has such rows; one that has, or an idle slot held
+    at a full cache's end, has none): it may reach nothing."""
+    from mxtpu.ops.pallas_attention import decode_attention
+    from test_decode_attention import _past_the_live_blocks
+    heads, kv_heads, hd, blk = 8, 2, 128, 64
+    rng = np.random.default_rng(sum(map(ord, name)))
+    q = jnp.asarray(rng.standard_normal((4, 1, heads * hd)), jnp.float32)
+    kc, vc = (jnp.asarray(rng.standard_normal((4, S, kv_heads * hd)),
+                          jnp.float32) for _ in range(2))
+    newest = np.minimum(np.asarray(pos), S - 1)
+    past = _past_the_live_blocks(newest, S, blk)
+    assert past.any(axis=(1, 2)).tolist() == [n < S - 16 for n in newest]
+    out = decode_attention(q, jnp.where(past, jnp.nan, kc),
+                           jnp.where(past, jnp.nan, vc),
+                           jnp.asarray(pos, jnp.int32), heads, block_s=blk,
+                           num_kv_heads=kv_heads, window=window)
+    want = _ring_rows_definition(q, kc, vc, pos if window else newest, heads,
+                                 kv_heads, window)
+    np.testing.assert_allclose(np.asarray(out), want, atol=2e-3, rtol=2e-3)
+
+
 def test_padding_never_reaches_the_ring():
     """A prompt padded to a bucket four times the ring leaves exactly the
     rows an exact-length prefill leaves."""

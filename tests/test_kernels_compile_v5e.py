@@ -191,6 +191,33 @@ def test_decode_path_at_the_cells_shapes_copies_no_cache(v5e):
         assert functools.reduce(int.__mul__, dims) < B * S * D, m.group(0)
 
 
+def test_two_layers_of_a_decode_step_share_one_table_of_live_blocks(v5e):
+    """The grid of ``decode_attention`` is the list of live blocks (PR 35),
+    four tables and their length reckoned from ``pos``: two layers of a
+    BLOOM-shaped step hand their kernels the SAME arrays (XLA merged the
+    identical expressions), and the tables brought no loop."""
+    from mxtpu.ops.nn import cached_attention
+    B, S, D, H = (CELL[k] for k in "BSDH")
+    row, _k, _v, cache, _vc, pos = _decode_step_avals(v5e, B, S, D)
+
+    def two_layers(x, kc1, vc1, kc2, vc2, pos):
+        for kc, vc in ((kc1, vc1), (kc2, vc2)):
+            x = cached_attention(x, x, x, kc, vc, pos, num_heads=H,
+                                 alibi=True)[0]
+        return x
+
+    text = jax.jit(two_layers).trace(row, cache, cache, cache, cache,
+                                     pos).lower(
+        lowering_platforms=("tpu",)).compile().as_text()
+    assert not re.search(r"\bwhile\(", text)
+    calls = re.findall(r"decode_attention\S* = \S+ custom-call\(([^)]*)\)",
+                       text)
+    assert len(calls) == 2
+    # the grid's length, slot, block, tail, flags, pos: the same operands
+    tables = [[a.strip() for a in c.split(",")][:6] for c in calls]
+    assert tables[0] == tables[1] and len(set(tables[0])) == 6, tables
+
+
 @pytest.mark.parametrize("heads,d,cache_dtype", [
     (12, 1536, jnp.bfloat16), (16, 2048, jnp.float32),
     (2, 256, jnp.bfloat16)])

@@ -157,6 +157,32 @@ def test_kernel_equals_the_formula(dtype, S, block_s, tol):
         assert np.max(np.abs(np.asarray(got[b], np.float64) - want)) < tol, b
 
 
+@pytest.mark.parametrize("pos", [
+    (0, 0, 0, 0),                    # the grid is ``slots`` long
+    (15, 16, 63, 31),                # a block's two edges, the last row
+    (5, 64 + 40, 32, 0)])            # an idle slot among live ones
+def test_kernel_visits_only_live_blocks(pos):
+    """The grid runs over the live blocks alone (PR 35): NaN in every block
+    past a slot's last live one reaches nothing. An idle slot counts past
+    the cache: held inside it, all its blocks live."""
+    B, S, W, blk = 4, 64, RANK + ROPE + 8, 16
+    k = jax.random.split(jax.random.PRNGKey(7), 2)
+    q = jax.random.normal(k[0], (B, H, W), jnp.float32)
+    cache = jax.random.normal(k[1], (B, S, W), jnp.float32)
+    newest = np.minimum(np.asarray(pos), S - 1)
+    past = np.arange(S)[None, :, None] // blk > (newest // blk)[:, None, None]
+    got = jax.jit(lambda q, c, p: latent_decode_attention(
+        q, c, p, RANK, 0.2, block_s=blk))(
+            q, jnp.where(past, jnp.nan, cache), jnp.asarray(pos, jnp.int32))
+    q64, c64 = np.asarray(q, np.float64), np.asarray(cache, np.float64)
+    for b in range(B):
+        s = 0.2 * q64[b] @ c64[b, :newest[b] + 1].T
+        att = np.exp(s - s.max(-1, keepdims=True))
+        att /= att.sum(-1, keepdims=True)
+        want = att @ c64[b, :newest[b] + 1, :RANK]
+        assert np.max(np.abs(np.asarray(got[b], np.float64) - want)) < 1e-5, b
+
+
 def test_kernel_refuses_shapes_it_cannot_take():
     q = jnp.zeros((2, H, 72), jnp.float32)
     cache = jnp.zeros((2, 32, 72), jnp.float32)

@@ -95,6 +95,8 @@ def test_sparse_embedding_trains_with_lazy_sgd():
     """End to end: SparseEmbedding + Trainer(sgd) converges on a toy
     classification task; the optimizer touches stored rows only."""
     np.random.seed(1)
+    mx.random.seed(1)   # the initialiser's draw: else whatever the worker's
+                        # earlier tests left decides a bound met by 1%
     vocab, dim, classes = 120, 8, 4
     net = gluon.nn.Sequential()
     emb = gluon.contrib.nn.SparseEmbedding(vocab, dim, nnz_max=32)
