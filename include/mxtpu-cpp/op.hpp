@@ -1884,7 +1884,8 @@ inline Symbol latent_attention(const std::string &name,
     double rope_beta_fast = 32.0,
     double rope_beta_slow = 1.0,
     int rope_orig_len = 4096,
-    double norm_eps = 1e-06) {
+    double norm_eps = 1e-06,
+    double pos_scale_beta = 0.0) {
   Operator op("latent_attention");
   op.SetParam("num_heads", std::to_string(num_heads));
   op.SetParam("nope_dim", std::to_string(nope_dim));
@@ -1897,6 +1898,7 @@ inline Symbol latent_attention(const std::string &name,
   op.SetParam("rope_beta_slow", NumStr(rope_beta_slow));
   op.SetParam("rope_orig_len", std::to_string(rope_orig_len));
   op.SetParam("norm_eps", NumStr(norm_eps));
+  op.SetParam("pos_scale_beta", NumStr(pos_scale_beta));
   op.SetInput("query", query);
   op.SetInput("kv_row", kv_row);
   op.SetInput("c_gain", c_gain);
@@ -2140,12 +2142,14 @@ inline Symbol moe_ffn_held(const std::string &name,
     int top_k = 1,
     int expert_first = 0,
     double scale = 1.0,
+    const std::string & scoring = "sigmoid",
     Symbol load = Symbol(),
     Symbol valid_len = Symbol()) {
   Operator op("moe_ffn_held");
   op.SetParam("top_k", std::to_string(top_k));
   op.SetParam("expert_first", std::to_string(expert_first));
   op.SetParam("scale", NumStr(scale));
+  if (scoring != "None") op.SetParam("scoring", scoring);
   op.SetInput("data", data);
   op.SetInput("router_weight", router_weight);
   op.SetInput("select_bias", select_bias);

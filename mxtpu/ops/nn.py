@@ -1052,6 +1052,18 @@ def latent_decode_nodes():
     return _LATENT_DECODE_NODES.default().value
 
 
+_LATENT_BLOCKWISE_NODES = _obs.counter(
+    "ops.latent_attention.chunk_blockwise",
+    "latent_attention nodes traced onto the block-wise chunk path (the "
+    "kernel latent_prefill_attention)")
+
+
+def latent_blockwise_nodes():
+    """How many ``latent_attention`` nodes this process has traced onto the
+    block-wise chunk path so far."""
+    return _LATENT_BLOCKWISE_NODES.default().value
+
+
 def yarn_frequencies(rope_dim, theta, factor=1.0, beta_fast=32.0,
                      beta_slow=1.0, orig_len=4096):
     """The rotary pairs' angular frequencies under YaRN, ``[rope_dim / 2]``
@@ -1098,7 +1110,7 @@ def latent_attention(query, kv_row, c_gain, kv_up_weight, cache, pos,
                      num_heads=1, nope_dim=128, rope_dim=64, v_dim=128,
                      scale=0.0, rope_theta=10000.0, rope_factor=1.0,
                      rope_beta_fast=32.0, rope_beta_slow=1.0,
-                     rope_orig_len=4096, norm_eps=1e-6):
+                     rope_orig_len=4096, norm_eps=1e-6, pos_scale_beta=0.0):
     """Causal attention over a latent cache. Returns ``(out, cache_next)``.
 
     ``query [B, T, heads x (nope_dim + rope_dim)]``: head ``i``'s ``[q_nope
@@ -1115,9 +1127,18 @@ def latent_attention(query, kv_row, c_gain, kv_up_weight, cache, pos,
     in front of each kernel); ``pos [B]`` the write offset. ``score_i(t, s)
     = scale (q_nope,i . k_nope,i(s) + q_rope,i . k_rope(s))`` for ``s <= pos
     + t`` (``scale`` 0: ``(nope_dim + rope_dim)^-1/2``), float32 softmax,
-    ``out [B, T, heads x v_dim]``.
+    ``out [B, T, heads x v_dim]``. ``pos_scale_beta`` not 0: the query at
+    absolute position ``p`` is first multiplied by ``g(p) = 1 +
+    pos_scale_beta ln(1 + floor(p / rope_orig_len))`` (a score's scale that
+    grows with the position by whole original contexts), in every path.
 
-    A chunk expands keys and values from the rows of the whole cache. One
+    A chunk expands keys and values. Where query, key and value heads are
+    one width of whole 128-lane slabs (``_latent_blockwise_path``) it
+    expands its OWN ``T`` rows once and attends them tile by tile on the
+    kernel ``latent_prefill_attention``, then, block after block of the
+    cache, the live rows in front of it (none at ``pos`` 0, a prefill), so
+    its work follows ``pos + T`` and no ``[B, heads, T, S]`` scores exist;
+    any other chunk keeps the dense formulas over the whole cache. One
     row a sample absorbs the expansion instead, ``q_lat,i = W_uk,i^T
     q_nope,i`` and ``o_i = W_uv,i sum_s att c_s``, writes its row through
     ``cache_write_row`` and attends on ``latent_decode_attention``: the same
@@ -1137,7 +1158,13 @@ def latent_attention(query, kv_row, c_gain, kv_up_weight, cache, pos,
     q = query.reshape(B, T, H, spec.nope + spec.rope)
     q_nope = q[..., :spec.nope]
     q_rope = _rotate(q[..., spec.nope:].astype(jnp.float32), q_abs,
-                     spec.freqs).astype(query.dtype)
+                     spec.freqs)
+    beta, g = float(pos_scale_beta), None
+    if beta:
+        g = 1.0 + beta * jnp.log1p(jnp.floor(
+            q_abs.astype(jnp.float32) / float(rope_orig_len)))    # [B, T]
+        q_rope = q_rope * g[:, :, None, None]
+    q_rope = q_rope.astype(query.dtype)
     row32 = kv_row.astype(jnp.float32)
     c = _rms_head(row32[..., :rank], c_gain, spec.norm_eps)
     k_rope = _rotate(row32[..., rank:], q_abs, spec.freqs)
@@ -1145,7 +1172,14 @@ def latent_attention(query, kv_row, c_gain, kv_up_weight, cache, pos,
                         cache.shape[2]).astype(cache.dtype)
     w_up = kv_up_weight.reshape(H, spec.nope + spec.v_dim, rank)
     if _latent_decode_path(query, cache, rank):
-        return _latent_decode_step(q_nope, q_rope, rows, w_up, cache, p, spec)
+        return _latent_decode_step(q_nope, q_rope, rows, w_up, cache, p, spec,
+                                   g)
+    if g is not None:
+        q_nope = (q_nope.astype(jnp.float32)
+                  * g[:, :, None, None]).astype(query.dtype)
+    if _latent_blockwise_path(query, cache, spec):
+        return _latent_chunk_blockwise(q_nope, q_rope, rows, w_up, cache, p,
+                                       spec)
     new_cache = _scatter_rows(cache, rows, p)
     seen = new_cache.astype(query.dtype)
     kv = jnp.einsum("bsr,hor->bsho", seen[..., :rank], w_up.astype(query.dtype),
@@ -1165,24 +1199,103 @@ def latent_attention(query, kv_row, c_gain, kv_up_weight, cache, pos,
     return out.reshape(B, T, H * spec.v_dim).astype(query.dtype), new_cache
 
 
+def _ambient_mesh():
+    """Whether a mesh or sequence parallelism is ambient: a kernel is one
+    device's program, so the latent paths that run one stay off then."""
+    from ..parallel.mesh import current_mesh
+    return bool(_SEQ_PARALLEL) or current_mesh() is not None
+
+
 def _latent_decode_path(query, cache, rank):
     """Whether this call's shapes put it on the latent decode kernel: one
     row a sample, a rank of whole 128-lane slabs, a block that divides the
-    cache, and no ambient mesh (a kernel is one device's program)."""
-    if query.shape[1] != 1 or rank % 128:
-        return False
-    from ..parallel.mesh import current_mesh
-    if _SEQ_PARALLEL or current_mesh() is not None:
+    cache, and no ambient mesh (a kernel is one device's program). The
+    published row need not fill the cache's columns: the kernel's query is
+    padded with zeros to the cache's width (``rank + rope_dim`` values in
+    640 columns, or 320 in 384)."""
+    if query.shape[1] != 1 or rank % 128 or _ambient_mesh():
         return False
     from .pallas_attention import latent_block
-    return latent_block(cache.shape[1]) is not None
+    return latent_block(cache.shape[1], cache.shape[2]
+                        * jnp.dtype(cache.dtype).itemsize) is not None
 
 
-def _latent_decode_step(q_nope, q_rope, rows, w_up, cache, p, spec):
+def _latent_blockwise_path(query, cache, spec):
+    """Whether this chunk's shapes put it on the block-wise path: query and
+    key heads (``nope + rope``) as wide as value heads, in whole 128-lane
+    slabs (one tile shape serves all three); whole tiles of 8 query rows;
+    a block of the cache that divides it; and no ambient mesh (a kernel is
+    one device's program)."""
+    from .pallas_attention import PREFILL_BLOCK_K, prefill_block
+    width = spec.nope + spec.rope
+    if (width != spec.v_dim or width % 128 or query.shape[1] % 8
+            or _ambient_mesh()):
+        return False
+    return prefill_block(cache.shape[1], PREFILL_BLOCK_K) is not None
+
+
+def _latent_chunk_blockwise(q_nope, q_rope, rows, w_up, cache, p, spec):
+    """A chunk on the kernel ``latent_prefill_attention``: its own ``T``
+    rows, expanded once into per-head keys ``[k_nope,i ; k_rope]`` and
+    values, attended causally; then the cache's live rows in front of it, a
+    block at a time for as many blocks as the furthest sample's ``pos``
+    needs (none at ``pos`` 0), each block expanded, attended and merged by
+    the rows' log-sum-exp. Work and memory follow ``pos + T``."""
+    from .pallas_attention import (PREFILL_BLOCK_K, latent_prefill_attention,
+                                   prefill_block)
+    _LATENT_BLOCKWISE_NODES.inc()
+    B, T, H, _ = q_nope.shape
+    S = cache.shape[1]
+    dtype, rank, d = q_nope.dtype, spec.rank, spec.v_dim
+    w = w_up.astype(dtype)
+
+    def expand(seen):
+        """``seen [B, N, W]`` rows of the cache to keys and values ``[B, N,
+        H x d]``, head ``i``'s ``[k_nope,i ; k_rope]`` and ``v_i``."""
+        seen = seen.astype(dtype)
+        kv = jnp.einsum("bsr,hor->bsho", seen[..., :rank], w,
+                        preferred_element_type=jnp.float32).astype(dtype)
+        k_rope = jnp.broadcast_to(
+            seen[:, :, None, rank:rank + spec.rope],
+            kv.shape[:3] + (spec.rope,))
+        keys = jnp.concatenate([kv[..., :spec.nope], k_rope], axis=-1)
+        return (keys.reshape(kv.shape[:2] + (H * d,)),
+                kv[..., spec.nope:].reshape(kv.shape[:2] + (H * d,)))
+
+    new_cache = _scatter_rows(cache, rows, p)
+    q = jnp.concatenate([q_nope, q_rope], axis=-1).reshape(B, T, H * d)
+    # the chunk's rows as the cache holds them (rounded to its dtype)
+    out, lse = latent_prefill_attention(q, *expand(rows), 0, T, H,
+                                        spec.scale)
+    block = prefill_block(S, PREFILL_BLOCK_K)
+
+    def in_front(carry):
+        j, out, lse = carry
+        seen = lax.dynamic_slice_in_dim(new_cache, j * block, block, axis=1)
+        o2, lse2 = latent_prefill_attention(
+            q, *expand(seen), S, jnp.clip(p - j * block, 0, block), H,
+            spec.scale)
+        both = jnp.logaddexp(lse, lse2)
+
+        def weigh(o, l):    # [B, T, H x d] by [B, H, T, 1]
+            share = jnp.exp(l - both)[..., 0].swapaxes(1, 2)
+            return o.reshape(B, T, H, d) * share[..., None]
+        merged = (weigh(out, lse) + weigh(o2.astype(jnp.float32), lse2))
+        return j + 1, merged.reshape(B, T, H * d), both
+
+    _, out, _ = lax.while_loop(
+        lambda carry: carry[0] * block < jnp.max(p), in_front,
+        (jnp.zeros((), jnp.int32), out.astype(jnp.float32), lse))
+    return out.astype(dtype), new_cache
+
+
+def _latent_decode_step(q_nope, q_rope, rows, w_up, cache, p, spec, g=None):
     """The absorbed form on two kernels: the step's row goes into the cache
     in place (``cache_write_row``: the attention kernel's blocks of whole
     16-row tiles are its blocks too), then ``latent_decode_attention`` reads
-    each live row once for all heads."""
+    each live row once for all heads. ``g [B, 1]``: the position's query
+    scale, which multiplies the absorbed query in float32 (``q_rope``
+    carries it already)."""
     from .pallas_attention import cache_write_row, latent_decode_attention
     B, S, W = cache.shape
     dtype = q_nope.dtype
@@ -1194,7 +1307,10 @@ def _latent_decode_step(q_nope, q_rope, rows, w_up, cache, p, spec):
     # no bfloat16 product with float32 out for "bhd,hdr->bhr"
     q_lat = jnp.einsum("hbd,hdr->hbr", q_nope[:, 0].swapaxes(0, 1),
                        w_uk.astype(dtype), preferred_element_type=jnp.float32
-                       ).swapaxes(0, 1).astype(dtype)
+                       ).swapaxes(0, 1)
+    if g is not None:
+        q_lat = q_lat * g[:, :, None]
+    q_lat = q_lat.astype(dtype)
     q_cat = _pad_columns(jnp.concatenate([q_lat, q_rope[:, 0]], -1), W)
     o_lat = latent_decode_attention(q_cat, new_cache, p, spec.rank,
                                     spec.scale)
@@ -1358,12 +1474,16 @@ SUM_PUBLISHERS = {"moe_load": publish_moe_load}
 @register("moe_ffn_held", num_outputs=2)
 def moe_ffn_held(data, router_weight, select_bias, gate_weight, up_weight,
                  down_weight, load=None, valid_len=None, top_k=1,
-                 expert_first=0, scale=1.0):
+                 expert_first=0, scale=1.0, scoring="sigmoid"):
     """Top-``top_k`` expert layer over ALL the router's experts, computed
     on the experts this device holds (``parallel/moe.py::moe_ffn_held``):
     ``data [B, T, D]`` (or ``[N, D]``); ``router_weight [E, D]``;
     ``select_bias [E]``; ``gate_weight, up_weight [held, D, F]``,
-    ``down_weight [held, F, D]``, the experts ``expert_first ..``. Returns
+    ``down_weight [held, F, D]``, the experts ``expert_first ..``.
+    ``scoring``: the router's scores are the ``"sigmoid"`` of its logits
+    (the default) or their ``"softmax"`` over all ``E`` experts; either way
+    the ``top_k`` largest of ``score + select_bias`` are chosen and their
+    scores renormalised (``parallel/moe.py::route_topk``). Returns
     ``(y, load_next)``: ``y`` like ``data``, the held experts' weighted
     SiLU-gated outputs; ``load_next = load + counts`` with ``counts [1,
     held + MOE_LOAD_EXTRA]`` int32: the assignments of each held expert,
@@ -1383,7 +1503,8 @@ def moe_ffn_held(data, router_weight, select_bias, gate_weight, up_weight,
                 < valid_len.astype(jnp.int32).reshape(-1, 1)).reshape(-1)
     y, counts = _held(data.reshape(-1, shape[-1]), router_weight,
                       select_bias, gate_weight, up_weight, down_weight,
-                      int(top_k), int(expert_first), float(scale), rows)
+                      int(top_k), int(expert_first), float(scale), rows,
+                      str(scoring))
     one_row = data.ndim == 3 and shape[1] == 1
     run = jnp.stack([jnp.sum(counts[:-1] > 0, dtype=counts.dtype),
                      jnp.ones((), counts.dtype)])

@@ -851,11 +851,15 @@ def decode_attention(q, k_cache, v_cache, pos, num_heads, alibi=False,
 # ``rank``) at once
 # ---------------------------------------------------------------------------
 
-# Cache rows a grid step reads. 32 query rows a slot keep a step bound by the
-# MXU's tile loads, not by its 640 KiB of rows: 256 rows take as long a row and
-# twice the steps, and tail blocks of a quarter, which :func:`decode_attention`
-# gains by, cost Xing4.0's cell 2.4% (timed on the v5e, PERF.md PR 35)
-LATENT_BLOCK_S = 512
+# Bytes of cache rows a grid step reads, as the power of two of rows that fits:
+# 512 rows of 640 columns (bfloat16), 1024 of 384. 32 query rows a slot keep a
+# step bound by the MXU's tile loads, not by its rows' bytes: at 640 columns
+# 256 rows take as long a row and twice the steps, and tail blocks of a
+# quarter, which :func:`decode_attention` gains by, cost Xing4.0's cell 2.4%
+# (timed on the v5e, PERF.md PR 35); at 384 columns, 96 slots of 3,600 live
+# rows, six calls take 3.59 ms at 512 rows, 2.84 at 1024 and 2.90 at 2048,
+# and at 640 columns 1024 rows (2.30 ms) gain nothing on 512 (2.25; PR 36)
+LATENT_BLOCK_BYTES = 768 * 1024
 
 
 @functools.cache
@@ -908,7 +912,7 @@ def _latent_call():
     def call(q, cache, pos, rank, block_s, scale):
         B, S, W = cache.shape
         H = q.shape[1]
-        # no finer tail (LATENT_BLOCK_S says why): a slot's last block is a
+        # no finer tail (LATENT_BLOCK_BYTES says why): a slot's last block is a
         # block like the others, and the one window shows whichever it is
         slot, block, tail, flags, total = live_blocks(pos, S, block_s,
                                                       block_s)
@@ -944,12 +948,14 @@ def _latent_call():
     return call
 
 
-def latent_block(S, block_s=None):
+def latent_block(S, row_bytes, block_s=None):
     """The rows of cache one grid step of :func:`latent_decode_attention`
-    reads, or None when no block of whole 16-row tiles divides ``S``:
-    ``LATENT_BLOCK_S``, halved until it does."""
+    reads, or None when no block of whole 16-row tiles divides ``S``: the
+    power of two of rows of ``row_bytes`` that fills ``LATENT_BLOCK_BYTES``,
+    halved until it divides ``S``."""
     if block_s is None:
-        block_s = min(LATENT_BLOCK_S, S)
+        rows = max(LATENT_BLOCK_BYTES // int(row_bytes), 16)
+        block_s = min(1 << (rows.bit_length() - 1), S)
         while block_s > 16 and S % block_s:
             block_s //= 2
     if S % block_s or block_s % 16:
@@ -979,7 +985,7 @@ def latent_decode_attention(q, cache, pos, rank, scale, block_s=None):
     ``rank`` in whole 128-lane slabs and a block of whole 16-row tiles that
     divides ``S``; forward only."""
     B, S, W = cache.shape
-    blk = latent_block(S, block_s)
+    blk = latent_block(S, W * jnp.dtype(cache.dtype).itemsize, block_s)
     if int(rank) % 128 or W <= int(rank) or blk is None or q.shape[2] != W:
         raise MXNetError(
             "latent_decode_attention: the rank %d must be whole 128-lane "
@@ -991,3 +997,190 @@ def latent_decode_attention(q, cache, pos, rank, scale, block_s=None):
     p = jnp.clip(pos.astype(jnp.int32).reshape(-1), 0, S - 1)
     return _latent_call()(q.astype(cache.dtype), cache, p, int(rank), blk,
                           float(scale))
+
+
+# ---------------------------------------------------------------------------
+# a chunk's causal attention over expanded latent rows (a prefill, PR 36):
+# the flash tiling, forward only, on operands as they are stored; queries,
+# keys and values ride as [B, T, heads x d], the layout the projections give
+# them, so nothing is transposed and no [heads, T, S] scores exist.
+# ``ops.nn.latent_attention``'s chunk path takes it where query, key and
+# value heads are one width of whole slabs. (Described here and not in the
+# module's docstring, and absent from ``__all__``: the kernels above carry
+# their source lines into their compiled bodies, and lines that stay put
+# keep the accepted programs' compile-cache keys.)
+# ---------------------------------------------------------------------------
+
+PREFILL_BLOCK_Q, PREFILL_BLOCK_K = 1024, 1024
+
+
+def prefill_block(n, most):
+    """The largest power of two up to ``most`` that divides ``n`` rows, or
+    None under a tile of 8."""
+    block = most
+    while block >= 8 and n % block:
+        block //= 2
+    return block if block >= 8 else None
+
+
+@functools.cache
+def _prefill_call():
+    """Build the prefill kernel's pallas_call wrapper on first use."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    from .pallas_util import per_platform
+
+    def last_live(i, lead, kv_len, block_q, block_k):
+        """The last key block a block of queries sees: the one that holds
+        its last row's own position, and no block past the live keys."""
+        return jnp.maximum(jnp.minimum(
+            (i * block_q + block_q - 1 + lead) // block_k,
+            (kv_len - 1) // block_k), 0)
+
+    def kernel(lead_ref, len_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
+               acc_ref, m_ref, l_ref, *, scale, block_q, block_k, nk):
+        b, i, j = pl.program_id(0), pl.program_id(2), pl.program_id(3)
+        lead, kv_len = lead_ref[b], len_ref[b]
+
+        @pl.when(j == 0)
+        def _init():
+            acc_ref[:] = jnp.zeros_like(acc_ref)
+            m_ref[:] = jnp.full_like(m_ref, _NEG)
+            l_ref[:] = jnp.zeros_like(l_ref)
+
+        def attend(masked):
+            k, v = k_ref[0], v_ref[0]
+            s = jax.lax.dot_general(
+                q_ref[0], k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale
+            if masked:
+                qi = i * block_q + jax.lax.broadcasted_iota(
+                    jnp.int32, s.shape, 0)
+                ki = j * block_k + jax.lax.broadcasted_iota(
+                    jnp.int32, s.shape, 1)
+                seen = (ki <= qi + lead) & (ki < kv_len)
+                s = jnp.where(seen, s, _NEG)
+            m_prev = m_ref[:]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+            pr = jnp.exp(s - m_new)
+            if masked:      # a row that has seen no key yet: exp(0) = 1
+                pr = jnp.where(seen, pr, 0.0)
+            corr = jnp.exp(m_prev - m_new)
+            l_ref[:] = l_ref[:] * corr + jnp.sum(pr, axis=-1, keepdims=True)
+            m_ref[:] = m_new
+            acc_ref[:] = acc_ref[:] * corr + jax.lax.dot_general(
+                pr.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+
+        # a block above the diagonal or past the live keys is no work (and
+        # no fetch: its index is the last live block's); one wholly under
+        # both needs no mask
+        live = (j <= last_live(i, lead, kv_len, block_q, block_k)) \
+            & (kv_len > 0)
+        whole = ((j + 1) * block_k - 1 <= i * block_q + lead) \
+            & ((j + 1) * block_k <= kv_len)
+
+        @pl.when(live & whole)
+        def _whole():
+            attend(False)
+
+        @pl.when(live & ~whole)
+        def _edge():
+            attend(True)
+
+        @pl.when(j == nk - 1)
+        def _fin():
+            l = l_ref[:]
+            l_safe = jnp.where(l == 0.0, 1.0, l)
+            o_ref[0] = (acc_ref[:] / l_safe).astype(o_ref.dtype)
+            # a row that saw no key keeps lse = _NEG, so a merge ignores it
+            lse_ref[0, 0] = jnp.where(l == 0.0, _NEG,
+                                      m_ref[:] + jnp.log(l_safe))
+
+    @functools.partial(jax.jit, static_argnums=(5, 6, 7, 8))
+    def call(q, k, v, lead, kv_len, heads, scale, block_q, block_k):
+        B, T, D = q.shape
+        d = D // heads
+        nq, nk = T // block_q, k.shape[1] // block_k
+
+        def q_map(b, h, i, j, *_):
+            return (b, i, h)
+
+        def k_map(b, h, i, j, lead_ref, len_ref):
+            return (b, jnp.minimum(j, last_live(
+                i, lead_ref[b], len_ref[b], block_q, block_k)), h)
+
+        kern = functools.partial(kernel, scale=scale, block_q=block_q,
+                                 block_k=block_k, nk=nk)
+        return per_platform(functools.partial(
+            pl.pallas_call,
+            kern,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=2,
+                grid=(B, heads, nq, nk),
+                in_specs=[
+                    pl.BlockSpec((1, block_q, d), q_map),
+                    pl.BlockSpec((1, block_k, d), k_map),
+                    pl.BlockSpec((1, block_k, d), k_map),
+                ],
+                out_specs=[
+                    pl.BlockSpec((1, block_q, d), q_map),
+                    pl.BlockSpec((1, 1, block_q, 1),
+                                 lambda b, h, i, j, *_: (b, h, i, 0)),
+                ],
+                scratch_shapes=[
+                    pltpu.VMEM((block_q, d), jnp.float32),
+                    pltpu.VMEM((block_q, 1), jnp.float32),
+                    pltpu.VMEM((block_q, 1), jnp.float32),
+                ]),
+            out_shape=[
+                jax.ShapeDtypeStruct((B, T, D), q.dtype),
+                jax.ShapeDtypeStruct((B, heads, T, 1), jnp.float32),
+            ],
+            name="latent_prefill_attention",
+        ), lead, kv_len, q, k, v)
+
+    return call
+
+
+def latent_prefill_attention(q, k, v, lead, kv_len, num_heads, scale,
+                             block_q=None, block_k=None):
+    """A chunk's queries against one stretch of expanded keys and values,
+    tile by tile with a running softmax: no ``[heads, T, S]`` scores.
+
+    ``q [B, T, heads x d]``, ``k, v [B, N, heads x d]`` (head ``h`` in
+    columns ``h d .. (h + 1) d - 1``, the layout the projections write, so
+    nothing is transposed); ``lead [B]``, ``kv_len [B]`` int32: sample
+    ``b``'s query row ``t`` sees key row ``n`` where ``n <= t + lead[b]``
+    and ``n < kv_len[b]`` (a chunk against its own rows: ``lead`` 0,
+    ``kv_len`` ``T``; against rows that all lie before it: ``lead >= N``
+    and ``kv_len`` the live ones). Returns ``(out [B, T, heads x d], lse
+    [B, heads, T, 1])``: the softmax-weighted values in ``q``'s dtype and
+    the log of each row's sum of ``exp(score)`` in float32 (``-1e30`` for a
+    row that saw no key), by which two stretches' results merge: ``lse' =
+    logaddexp(lse1, lse2)``, ``o' = o1 exp(lse1 - lse') + o2 exp(lse2 -
+    lse')``.
+
+    Products take their operands as they are stored (bfloat16 on the MXU)
+    and accumulate in float32, as do the running maximum and sum. A key
+    block above a query block's diagonal or past ``kv_len`` is neither
+    computed nor fetched (its index is the last live block's). Needs ``d``
+    in whole 128-lane slabs and blocks that divide ``T`` and ``N``
+    (:func:`prefill_block`); forward only. In a device trace the kernel is
+    ``latent_prefill_attention``."""
+    B, T, D = q.shape
+    H = int(num_heads)
+    bq = block_q or prefill_block(T, PREFILL_BLOCK_Q)
+    bk = block_k or prefill_block(k.shape[1], PREFILL_BLOCK_K)
+    if (D % H or (D // H) % 128 or not bq or not bk or T % bq
+            or k.shape[1] % bk or k.shape != v.shape or k.shape[2] != D):
+        raise MXNetError(
+            "latent_prefill_attention: heads of %s columns must be whole "
+            "128-lane slabs, alike for queries, keys and values, and blocks "
+            "of 8 rows or more must divide %d query and %d key rows"
+            % (D / H, T, k.shape[1]))
+    def as_i32(x):      # a scalar or ``[B]``, a sample each
+        return jnp.broadcast_to(jnp.asarray(x, jnp.int32).reshape(-1), (B,))
+
+    return _prefill_call()(q, k, v, as_i32(lead), as_i32(kv_len), H,
+                           float(scale), int(bq), int(bk))

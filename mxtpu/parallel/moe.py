@@ -108,16 +108,24 @@ def expert_sharding_rules(extra=None):
 # expert and go through one grouped matrix product per weight.
 # ---------------------------------------------------------------------------
 
-def route_topk(x, router_w, select_bias, top_k, scale=1.0):
+def route_topk(x, router_w, select_bias, top_k, scale=1.0, scoring="sigmoid"):
     """``x [N, D]``, ``router_w [E, D]``, ``select_bias [E]``. Scores are
-    the sigmoid of ``x router_w^T`` in float32; the
+    ``scoring`` of the logits ``x router_w^T`` in float32: their
+    ``"sigmoid"``, each expert's own, or their ``"softmax"`` over all ``E``
+    experts; the
     ``top_k`` experts with the largest ``score + select_bias`` are chosen
     (the bias only selects); their scores, renormalised to sum to one and
     times ``scale``, are the weights. Returns ``(experts [N, k] int32,
     weights [N, k] float32)``."""
     logits = jnp.einsum("nd,ed->ne", x, router_w,
                         preferred_element_type=jnp.float32)
-    s = jax.nn.sigmoid(logits)
+    if scoring == "sigmoid":
+        s = jax.nn.sigmoid(logits)
+    elif scoring == "softmax":
+        s = jax.nn.softmax(logits, axis=-1)
+    else:
+        raise ValueError("route_topk: scoring %r is neither 'sigmoid' nor "
+                         "'softmax'" % (scoring,))
     _top, experts = jax.lax.top_k(s + select_bias.astype(jnp.float32), top_k)
     w = jnp.take_along_axis(s, experts, axis=1)
     w = scale * w / jnp.sum(w, axis=1, keepdims=True)
@@ -165,7 +173,7 @@ def grouped_matmul(rows, w, sizes):
 
 
 def moe_ffn_held(x, router_w, select_bias, w_gate, w_up, w_down, top_k,
-                 expert_first=0, scale=1.0, rows=None):
+                 expert_first=0, scale=1.0, rows=None, scoring="sigmoid"):
     """The held experts' part of a SiLU-gated expert layer.
 
     ``x [N, D]``; ``router_w [E, D]`` over ALL ``E`` experts;
@@ -177,10 +185,11 @@ def moe_ffn_held(x, router_w, select_bias, w_gate, w_up, w_down, top_k,
     of them. A token none of whose experts is held gets zeros: what the
     other devices' experts add is theirs to add. ``rows [N]`` (bool) marks
     the tokens that are real; the others (a padded chunk's tail) are
-    neither computed nor counted."""
+    neither computed nor counted. ``scoring`` is :func:`route_topk`'s."""
     n, d = x.shape
     held = w_gate.shape[0]
-    experts, weights = route_topk(x, router_w, select_bias, top_k, scale)
+    experts, weights = route_topk(x, router_w, select_bias, top_k, scale,
+                                  scoring)
     order, sizes, n_held = held_assignments(experts, held, expert_first,
                                             rows)
     n_rows = n if rows is None else jnp.sum(rows, dtype=jnp.int32)

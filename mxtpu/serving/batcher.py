@@ -439,6 +439,14 @@ _GEN_COUNTERS = {
         "serve.gen.rows_discarded", "slot-steps computed for a sequence "
         "that had left its slot the step before", ("inst",)),
 }
+# The process's own, not a scheduler's: ``stop()`` drops a scheduler's series,
+# and these are read after it (``stats()`` has the scheduler's share).
+_GEN_PREFILL_ROWS = _obs.counter(
+    "serve.gen.prefill_rows", "prompt rows prefilled (true lengths), by "
+    "all schedulers of the process")
+_GEN_PREFILL_ROWS_PADDED = _obs.counter(
+    "serve.gen.prefill_rows_padded", "rows the prefill programs ran for "
+    "them: each prompt's bucket")
 _GEN_GAUGES = {
     "slots_active": _obs.gauge(
         "serve.gen.slots_active", "in-flight sequences across lanes",
@@ -582,6 +590,7 @@ class GenerateScheduler:
         inst = "g%d" % next(_GEN_INST)
         self._c = {f: m.labels(inst) for f, m in _GEN_COUNTERS.items()}
         self._g = {f: m.labels(inst) for f, m in _GEN_GAUGES.items()}
+        self._prefill_rows = [0, 0]      # true, padded; under _cv
         self._thread = threading.Thread(target=self._run, daemon=True,
                                         name="mxtpu-serve-generate")
         self._thread.start()
@@ -718,6 +727,12 @@ class GenerateScheduler:
     def _prefill_into(self, req, lane, slot):
         self._c["prefills"].inc()
         plen = int(req.prompt.shape[0])
+        padded = self._engine.gen_bucket_for(plen)
+        with self._cv:
+            self._prefill_rows[0] += plen
+            self._prefill_rows[1] += padded
+        _GEN_PREFILL_ROWS.default().inc(plen)
+        _GEN_PREFILL_ROWS_PADDED.default().inc(padded)
         try:
             with _obs.span("serve.gen.prefill", rid=req.rid, plen=plen):
                 first, rows = self._engine.gen_prefill(
@@ -917,6 +932,8 @@ class GenerateScheduler:
         out = {f: s.value for f, s in self._c.items()}
         out.update({f: s.value for f, s in self._g.items()})
         with self._cv:
+            out["prefill_rows"], out["prefill_rows_padded"] = \
+                self._prefill_rows
             out["queued"] = len(self._queue)
             out["active"] = self._active
         return out

@@ -216,6 +216,8 @@ class InferenceEngine:
                        "gen_prefill_row_write": 0,
                        "gen_decode_latent_path": 0,
                        "gen_prefill_latent_path": 0,
+                       "gen_decode_latent_blockwise": 0,
+                       "gen_prefill_latent_blockwise": 0,
                        "gen_decode_hyper_mix": 0,
                        "gen_prefill_hyper_mix": 0}
         if warm:
@@ -467,13 +469,18 @@ class InferenceEngine:
         ``n_layer`` each for a decode program whose shapes engage them, 0
         for a prefill. The ``latent_attention`` nodes traced onto their
         kernel, each of which writes its row through the row-write kernel
-        too (``gen_<program>_latent_path``), and the ``hyper_mix`` nodes
+        too (``gen_<program>_latent_path``), those traced onto the
+        block-wise chunk path and its kernel ``latent_prefill_attention``
+        (``gen_<program>_latent_blockwise``: a layer each for every prefill
+        bucket compiled, 0 for a decode program), and the ``hyper_mix`` nodes
         traced at all (``gen_<program>_hyper_mix``: two a layer in every
         program of a model with hyper-connections)."""
         from ..ops import nn
         counts = {"gen_%s_attn_path" % program: nn.decode_path_nodes,
                   "gen_%s_row_write" % program: nn.row_write_nodes,
                   "gen_%s_latent_path" % program: nn.latent_decode_nodes,
+                  "gen_%s_latent_blockwise" % program:
+                      nn.latent_blockwise_nodes,
                   "gen_%s_hyper_mix" % program: nn.hyper_mix_nodes}
         before = {field: read() for field, read in counts.items()}
         yield

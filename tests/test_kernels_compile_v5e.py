@@ -340,3 +340,87 @@ def test_latent_kernel_compiles_at_other_blocks(v5e, block_s):
             q, cache, pos, c["rank"], 0.14468, block_s=block_s),
         S_((c["B"], c["H"], 640), jnp.bfloat16),
         S_((c["B"], c["S"], 640), jnp.bfloat16), S_((c["B"],), jnp.int32))
+
+
+# -- latent attention at mistral-small-4-119b-2603's serving sizes: a row of
+# 320 values in 384 columns, 96 slots of 10,240 rows; a prefill of 8,192 ------
+
+MISTRAL4 = dict(B=96, S=10240, W=384, H=32, nope=64, rope=64, vd=128,
+                rank=256)
+
+
+def _mistral4_attention(v5e, B, T, donate=True):
+    """``latent_attention`` as the ``mistral4`` symbol calls it, ``T`` rows a
+    sample over the cell's cache, compiled for the v5e."""
+    from mxtpu.ops.nn import latent_attention
+    c = MISTRAL4
+    S_ = functools.partial(jax.ShapeDtypeStruct, sharding=v5e)
+    bf = jnp.bfloat16
+    return jax.jit(
+        lambda q, row, g, w, cache, pos: latent_attention(
+            q, row, g, w, cache, pos, num_heads=c["H"], nope_dim=c["nope"],
+            rope_dim=c["rope"], v_dim=c["vd"], scale=0.19497,
+            rope_factor=128.0, rope_orig_len=8192, pos_scale_beta=0.1),
+        donate_argnums=(4,) if donate else ()).trace(
+            S_((B, T, c["H"] * (c["nope"] + c["rope"])), bf),
+            S_((B, T, c["rank"] + c["rope"]), bf), S_((c["rank"],), bf),
+            S_((c["H"] * (c["nope"] + c["vd"]), c["rank"]), bf),
+            S_((B, c["S"], c["W"]), bf), S_((B,), jnp.int32)).lower(
+                lowering_platforms=("tpu",)).compile()
+
+
+def test_latent_decode_step_over_a_384_column_cache(v5e):
+    """96 slots, 32 heads, a cache of 10,240 rows of 320 values padded to
+    384 columns (three 128-lane slabs): the op is the Mosaic kernel behind
+    the row-write kernel, the 755 MB cache is written in place, no loop,
+    nothing cache-sized copied or kept."""
+    from mxtpu.ops.nn import latent_decode_nodes
+    c = MISTRAL4
+    before = latent_decode_nodes()
+    compiled = _mistral4_attention(v5e, c["B"], 1)
+    assert latent_decode_nodes() == before + 1
+    text = compiled.as_text()
+    assert not re.search(r"\bwhile\(", text)
+    assert len(re.findall(
+        r"latent_decode_attention\S* = \S+ custom-call\(", text)) == 1
+    assert len(re.findall(r"cache_write_row\S* = \S+ custom-call\(", text)) == 1
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == c["B"] * c["S"] * c["W"] * 2 == 754974720
+    assert mem.temp_size_in_bytes < 16 * 2 ** 20
+    for m in re.finditer(r"= \w+\[([\d,]+)\]\S* copy\(", text):
+        dims = [int(d) for d in m.group(1).split(",")]
+        assert functools.reduce(int.__mul__, dims) < 2 ** 24, m.group(0)
+
+
+def test_latent_prefill_of_8192_rows_builds_no_scores_over_the_cache(v5e):
+    """One sample, a chunk of 8,192 rows into a cache of 10,240: the chunk
+    attends on the kernel ``latent_prefill_attention`` (once for its own
+    rows, once inside the loop over the cache's blocks in front of it), and
+    the program's temporaries are the chunk's own expanded keys and values,
+    a few of 67 MB, where scores ``[32, 8192, 10240]`` in float32 alone
+    would be 10.7 GB and keys and values of all 10,240 rows 252 MB."""
+    from mxtpu.ops.nn import latent_blockwise_nodes
+    c = MISTRAL4
+    before = latent_blockwise_nodes()
+    compiled = _mistral4_attention(v5e, 1, 8192, donate=False)
+    assert latent_blockwise_nodes() == before + 1
+    text = compiled.as_text()
+    assert len(re.findall(
+        r"latent_prefill_attention\S* = \([^=]*\) custom-call\(", text)) == 2
+    assert "latent_decode_attention" not in text
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 1 * 2 ** 30
+    # no array of heads x T x S, or of all S rows expanded a head, anywhere
+    for m in re.finditer(r"= \w+\[([\d,]+)\]", text):
+        dims = [int(d) for d in m.group(1).split(",")]
+        assert functools.reduce(int.__mul__, dims) <= 8192 * 32 * 192, m.group(0)
+
+
+@pytest.mark.parametrize("T", [256, 2048])
+def test_latent_prefill_kernel_compiles_at_other_buckets(v5e, T):
+    from mxtpu.ops.pallas_attention import latent_prefill_attention
+    S_ = functools.partial(jax.ShapeDtypeStruct, sharding=v5e)
+    text = _compile(
+        lambda q, k, v, n: latent_prefill_attention(q, k, v, 0, n, 32, 0.19497),
+        *[S_((1, T, 32 * 128), jnp.bfloat16)] * 3, S_((1,), jnp.int32))
+    assert "tpu_custom_call" in text
