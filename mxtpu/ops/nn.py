@@ -1441,6 +1441,18 @@ _MOE_EXPERTS_HIT_RUN = _obs.gauge(
     "held experts with at least one assignment, a run of the layer (decode "
     "step or chunk) on average, between the last two readings", ("layer",))
 
+_MOE_WINDOW_NODES = _obs.counter(
+    "ops.moe_ffn.held_window",
+    "moe_ffn_held nodes traced with their held rows walked in windows of "
+    "the sorted order (parallel/moe.py::held_window)")
+
+
+def held_window_nodes():
+    """How many ``moe_ffn_held`` nodes this process has traced onto the walk
+    in windows so far."""
+    return _MOE_WINDOW_NODES.default().value
+
+
 # columns of ``moe_ffn_held``'s counts after the held experts' own
 MOE_LOAD_EXTRA = 5      # all assignments; experts hit, runs (one row a
 #                         sample); experts hit, runs (chunks)
@@ -1495,8 +1507,11 @@ def moe_ffn_held(data, router_weight, select_bias, gate_weight, up_weight,
     ``valid_len [B]``: rows ``t >= valid_len[b]`` of a padded ``[B, T, D]``
     chunk are padding: they are neither computed nor counted, and come
     back zero."""
-    from ..parallel.moe import moe_ffn_held as _held
+    from ..parallel.moe import held_window, moe_ffn_held as _held
     shape = data.shape
+    if held_window(data.size // shape[-1] * int(top_k), gate_weight.shape[0],
+                   router_weight.shape[0]) is not None:
+        _MOE_WINDOW_NODES.default().inc()
     rows = None
     if valid_len is not None and data.ndim == 3:
         rows = (jnp.arange(shape[1], dtype=jnp.int32)[None, :]

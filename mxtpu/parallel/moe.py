@@ -22,7 +22,7 @@ from .mesh import MeshContext, ShardingRules, PartitionSpec, AXIS_EXPERT
 
 __all__ = ["moe_dispatch", "moe_ffn", "expert_sharding_rules",
            "route_topk", "held_assignments", "grouped_matmul",
-           "moe_ffn_held"]
+           "held_window", "moe_ffn_held"]
 
 
 def moe_dispatch(gate_logits, capacity, num_selected=1):
@@ -155,21 +155,64 @@ def held_assignments(experts, experts_held, expert_first, rows=None):
 GROUPED_TILING = (128, 512, 2048)
 
 
-def grouped_matmul(rows, w, sizes):
+def grouped_matmul(rows, w, sizes, tile_rows=GROUPED_TILING[0]):
     """``rows [M, K]``, sorted by group, times ``w [G, K, N]``: the rows of
     group ``i`` (``sizes[i]`` of them, in order) against ``w[i]``, float32
     out. Rows past ``sum(sizes)`` come back undefined. The Pallas kernel is
-    JAX's own ``megablox.gmm``: a tile of rows visits only the groups it
-    holds rows of, so each held expert's matrix is read about once a call
-    whatever the number of rows."""
+    JAX's own ``megablox.gmm``: a tile of ``tile_rows`` rows visits only the
+    groups it holds rows of, so each held expert's matrix is read about
+    once a call whatever the number of rows."""
     from jax.experimental.pallas.ops.tpu.megablox import gmm
     from ..ops.pallas_util import per_platform
     tiling = tuple(min(t, n) for t, n in zip(
-        GROUPED_TILING, (rows.shape[0], rows.shape[1], w.shape[2])))
+        (tile_rows,) + GROUPED_TILING[1:],
+        (rows.shape[0], rows.shape[1], w.shape[2])))
     return per_platform(
         lambda interpret: functools.partial(
             gmm, preferred_element_type=jnp.float32, tiling=tiling,
             interpret=interpret), rows, w, sizes)
+
+
+# A chunk's held rows are computed in windows of the sorted order where one
+# window, the share of the assignments this device expects (``held / E``)
+# with this margin over it, spares at least so many rows of the one pass
+# over all of them: timed on the v5e (PERF.md, PR 37)
+HELD_WINDOW_MARGIN = 1.25
+HELD_WINDOW_MIN_SPARED = 512
+
+
+def held_window(n_assign, held, n_experts):
+    """How :func:`moe_ffn_held` computes ``n_assign`` assignments of which
+    the ``held`` of ``n_experts`` experts' are this device's: ``(rows of a
+    window, rows of a tile of the grouped product)`` where it walks the
+    held rows in windows, ``None`` where it makes one pass over all rows (a
+    decode step's few tiles; every expert held). From shapes alone."""
+    if held >= n_experts:
+        return None
+    rows = math.ceil(n_assign * held / n_experts * HELD_WINDOW_MARGIN)
+    # from 1,024 rows on, tiles of twice the rows: an expert's matrices are
+    # visited by fewer tiles (timed at 128, 256 and 512; PERF.md, PR 37)
+    tile = GROUPED_TILING[0] * (2 if rows >= 1024 else 1)
+    window = -(-rows // tile) * tile
+    if n_assign - window < HELD_WINDOW_MIN_SPARED:
+        return None
+    return window, tile
+
+
+def _held_rows(x, weights, w_gate, w_up, w_down, top_k, tile, order, sizes,
+               n_live):
+    """The assignments ``order [M]`` (whole tiles; the first ``n_live``
+    held, grouped by expert as ``sizes`` says) through their experts:
+    ``(token [M], out [M, D] float32)``, each row weighed, the rows past
+    ``n_live`` zero."""
+    token = order // top_k
+    rows = jnp.take(x, token, axis=0)                        # [M, D]
+    h = (jax.nn.silu(grouped_matmul(rows, w_gate, sizes, tile))
+         * grouped_matmul(rows, w_up, sizes, tile))
+    out = grouped_matmul(h.astype(x.dtype), w_down, sizes, tile)
+    w_sorted = jnp.take(weights.reshape(-1), order)
+    live = jnp.arange(order.shape[0]) < n_live
+    return token, jnp.where(live[:, None], out * w_sorted[:, None], 0.0)
 
 
 def moe_ffn_held(x, router_w, select_bias, w_gate, w_up, w_down, top_k,
@@ -185,7 +228,14 @@ def moe_ffn_held(x, router_w, select_bias, w_gate, w_up, w_down, top_k,
     of them. A token none of whose experts is held gets zeros: what the
     other devices' experts add is theirs to add. ``rows [N]`` (bool) marks
     the tokens that are real; the others (a padded chunk's tail) are
-    neither computed nor counted. ``scoring`` is :func:`route_topk`'s."""
+    neither computed nor counted. ``scoring`` is :func:`route_topk`'s.
+
+    The assignments are sorted, the held ones first and grouped by expert.
+    Where :func:`held_window` gives a window, the held rows are gathered,
+    multiplied, weighed and added back a window of the sorted order at a
+    time, in as many trips as the held rows need (none held: no trip; all
+    on one held expert: ``N k`` rows over the window trips; no capacity
+    either way); else in one pass over all ``N k`` rows."""
     n, d = x.shape
     held = w_gate.shape[0]
     experts, weights = route_topk(x, router_w, select_bias, top_k, scale,
@@ -193,18 +243,33 @@ def moe_ffn_held(x, router_w, select_bias, w_gate, w_up, w_down, top_k,
     order, sizes, n_held = held_assignments(experts, held, expert_first,
                                             rows)
     n_rows = n if rows is None else jnp.sum(rows, dtype=jnp.int32)
-    # whole tiles of rows: the padding lies past the held rows, in no group
-    tile = min(GROUPED_TILING[0], -(-order.shape[0] // 8) * 8)
-    order = jnp.pad(order, (0, -order.shape[0] % tile))
-    token = order // top_k
-    rows = jnp.take(x, token, axis=0)                        # [N k, D]
-    h = (jax.nn.silu(grouped_matmul(rows, w_gate, sizes))
-         * grouped_matmul(rows, w_up, sizes))
-    out = grouped_matmul(h.astype(x.dtype), w_down, sizes)   # [N k, D] f32
-    w_sorted = jnp.take(weights.reshape(-1), order)
-    live = jnp.arange(order.shape[0]) < n_held   # rows past the held ones
-    out = jnp.where(live[:, None], out * w_sorted[:, None], 0.0)
-    y = jnp.zeros((n, d), jnp.float32).at[token].add(out)
+    through = functools.partial(_held_rows, x, weights, w_gate, w_up, w_down,
+                                top_k)
+    walk = held_window(order.shape[0], held, router_w.shape[0])
+    if walk is None:
+        # whole tiles of rows: the padding lies past the held rows, in no
+        # group
+        tile = min(GROUPED_TILING[0], -(-order.shape[0] // 8) * 8)
+        order = jnp.pad(order, (0, -order.shape[0] % tile))
+        token, out = through(tile, order, sizes, n_held)
+        y = jnp.zeros((n, d), jnp.float32).at[token].add(out)
+    else:
+        window, tile = walk
+        order = jnp.pad(order, (0, -order.shape[0] % window))
+        ends = jnp.cumsum(sizes)
+
+        def add_window(i, y):
+            lo = i * window
+            # the part of each held expert's rows that lies in the window
+            cut = jnp.clip(ends, lo, lo + window) \
+                - jnp.clip(ends - sizes, lo, lo + window)
+            token, out = through(
+                tile, jax.lax.dynamic_slice(order, (lo,), (window,)), cut,
+                n_held - lo)
+            return y.at[token].add(out)
+
+        y = jax.lax.fori_loop(0, -(-n_held // window), add_window,
+                              jnp.zeros((n, d), jnp.float32))
     load = jnp.concatenate([sizes, jnp.reshape(n_rows * top_k, (1,))
                             .astype(jnp.int32)])
     return y.astype(x.dtype), load

@@ -219,7 +219,9 @@ class InferenceEngine:
                        "gen_decode_latent_blockwise": 0,
                        "gen_prefill_latent_blockwise": 0,
                        "gen_decode_hyper_mix": 0,
-                       "gen_prefill_hyper_mix": 0}
+                       "gen_prefill_hyper_mix": 0,
+                       "gen_decode_moe_window": 0,
+                       "gen_prefill_moe_window": 0}
         if warm:
             self.warm()
 
@@ -472,16 +474,21 @@ class InferenceEngine:
         too (``gen_<program>_latent_path``), those traced onto the
         block-wise chunk path and its kernel ``latent_prefill_attention``
         (``gen_<program>_latent_blockwise``: a layer each for every prefill
-        bucket compiled, 0 for a decode program), and the ``hyper_mix`` nodes
+        bucket compiled, 0 for a decode program), the ``hyper_mix`` nodes
         traced at all (``gen_<program>_hyper_mix``: two a layer in every
-        program of a model with hyper-connections)."""
+        program of a model with hyper-connections), and the ``moe_ffn_held``
+        nodes traced with their held rows walked in windows
+        (``gen_<program>_moe_window``: an expert layer each for a prefill
+        bucket large enough of a model that holds a share of its experts, 0
+        for a decode program and where every expert is held)."""
         from ..ops import nn
         counts = {"gen_%s_attn_path" % program: nn.decode_path_nodes,
                   "gen_%s_row_write" % program: nn.row_write_nodes,
                   "gen_%s_latent_path" % program: nn.latent_decode_nodes,
                   "gen_%s_latent_blockwise" % program:
                       nn.latent_blockwise_nodes,
-                  "gen_%s_hyper_mix" % program: nn.hyper_mix_nodes}
+                  "gen_%s_hyper_mix" % program: nn.hyper_mix_nodes,
+                  "gen_%s_moe_window" % program: nn.held_window_nodes}
         before = {field: read() for field, read in counts.items()}
         yield
         with self._stats_lock:

@@ -84,3 +84,15 @@ def _test_limit(request):
     finally:
         signal.setitimer(signal.ITIMER_REAL, 0)
         signal.signal(signal.SIGALRM, before)
+
+
+@pytest.fixture
+def tiny_windows(monkeypatch):
+    """``parallel/moe.py::held_window``'s rule brought down to the serving
+    tests' tiny widths for one test: tiles of 8 rows where the chip's are
+    128, 16 rows to spare where the chip's rule asks 512. A chunk of 32
+    rows then walks its held rows in windows; a decode step of 4 slots and
+    a layer that holds every expert still make their one pass."""
+    from mxtpu.parallel import moe
+    monkeypatch.setattr(moe, "GROUPED_TILING", (8,) + moe.GROUPED_TILING[1:])
+    monkeypatch.setattr(moe, "HELD_WINDOW_MIN_SPARED", 16)

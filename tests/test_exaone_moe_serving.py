@@ -129,6 +129,37 @@ def test_expert_sums_count_every_assignment(served):
     assert 0.0 < snap["ops.moe_ffn.experts_hit_run"]["series"]["moe_load1"] <= 4.0
 
 
+def test_counters_say_where_the_held_rows_are_walked_in_windows(
+        served, tiny_windows):
+    """At these widths no program walks windows (a chunk of 32 rows is 128
+    assignments, a step 16). With the rule at these widths' scale the
+    bucket of 32 rows walks them in each of the 7 expert layers (windows of
+    40 rows, 4 of 16 experts held), the decode program in none, and the
+    prefill's first token and expert counts are the one pass's."""
+    from benchmarks.models import exaone_moe as model
+    from mxtpu.serving import InferenceEngine
+    cfg, one_pass, prompt, tokens, _lg = served
+    st = one_pass.stats()
+    assert st["gen_prefill_moe_window"] == st["gen_decode_moe_window"] == 0
+    engine = InferenceEngine(model.symbol(cfg), float32_weights(cfg, 7), {},
+                             {"data": (1,)}, buckets=(1,), dtype="float32",
+                             warm=False)
+    first, rows = engine.gen_prefill(prompt, engine._param_vals,
+                                     engine._aux_vals)
+    engine.gen_decode_program(4)
+    st = engine.stats()
+    assert st["gen_prefill_moe_window"] == 7
+    assert st["gen_decode_moe_window"] == 0
+    assert int(np.asarray(first)[0]) == int(tokens[0])
+    _first, want = one_pass.gen_prefill(prompt, one_pass._param_vals,
+                                        one_pass._aux_vals)
+    sums = one_pass._gen["sum_states"]
+    assert len(sums) == 7
+    for i in sums:
+        assert np.asarray(rows[i]).tolist() == np.asarray(want[i]).tolist()
+        assert np.asarray(rows[i])[0, -5] == 21 * 4
+
+
 def test_a_device_sum_that_wraps_is_read_as_what_it_gained(served):
     """The device's sums are int32 and never reset: one left just under
     2^31 wraps in the next steps, and the reading after takes the difference
@@ -204,6 +235,112 @@ def test_eight_shares_add_up_to_the_uncut_layer():
     assert held_total == 40 * 4          # every assignment is someone's
     np.testing.assert_allclose(np.asarray(parts + shared), np.asarray(whole),
                                atol=2e-5, rtol=2e-5)
+
+
+def windowed_layer(case):
+    """Inputs of one expert layer whose chunk is large enough for the walk
+    in windows (2,048 assignments over 16 experts, 2 held from expert 6: a
+    window of 384 rows in tiles of 128), shaped by ``case``: ``(x [1, N, D],
+    router_weight, select_bias, the three held weights, top_k, valid_len or
+    None, the held rows the case must give or None)``."""
+    rng = np.random.default_rng(21)
+    d, f, wide, lo = 32, 16, 16, 6
+    n, k, valid, n_held = 512, 4, None, None
+    router = rng.standard_normal((wide, d)).astype(np.float32) / 4
+    bias = np.zeros(wide, np.float32)
+    if case == "none held":
+        bias[lo:lo + 2], n_held = -100.0, 0
+    elif case != "an eighth held":
+        # one expert a token, chosen by the bias: every true row is held
+        n, k = 2048, 1
+        bias[lo + 1] = 100.0
+        valid = {"all on one held expert": None, "twice the window": 768,
+                 "one over twice the window": 769,
+                 "an expert across two windows": 600,
+                 "padding that would have been held": 100}[case]
+        n_held = valid or n
+    x = rng.standard_normal((n, d)).astype(np.float32)
+    if case == "an expert across two windows":
+        # rows 0 .. 299 of the sorted order are expert 6's, 300 .. 599 expert
+        # 7's: the window's edge at 384 cuts the second group
+        bias[lo] = 100.0
+        x[:, 0] = np.where(np.arange(n) % 2 == 0, 40.0, -40.0)
+        router[lo], router[lo + 1] = 0.0, 0.0
+        router[lo, 0], router[lo + 1, 0] = 1.0, -1.0
+    held = [0.2 * rng.standard_normal(shape).astype(np.float32)
+            for shape in ((2, d, f), (2, d, f), (2, f, d))]
+    return x[None], router, bias, held, k, lo, valid, n_held
+
+
+def plain_held_layer(x, router, bias, held, k, lo, valid, scale):
+    """The held experts' part by the formula, a token at a time in float64:
+    sigmoid scores, the ``k`` largest of score + bias, their scores
+    renormalised; rows past ``valid`` are nobody's."""
+    x = x[0].astype(np.float64)
+    y = np.zeros_like(x)
+    load = np.zeros(len(held[0]) + 1, np.int64)
+    score = 1 / (1 + np.exp(-(x @ router.T.astype(np.float64))))
+    for t in range(x.shape[0] if valid is None else valid):
+        chosen = np.argsort(-(score[t] + bias), kind="stable")[:k]
+        w = scale * score[t, chosen] / score[t, chosen].sum()
+        load[-1] += k
+        for e, we in zip(chosen - lo, w):
+            if 0 <= e < len(held[0]):
+                h = x[t] @ held[0][e]
+                y[t] += we * ((h / (1 + np.exp(-h))) * (x[t] @ held[1][e])
+                              ) @ held[2][e]
+                load[e] += 1
+    return y, load
+
+
+@pytest.mark.parametrize("case", [
+    "an eighth held", "none held", "all on one held expert",
+    "twice the window", "one over twice the window",
+    "an expert across two windows", "padding that would have been held"])
+def test_the_walk_in_windows_is_the_one_pass_and_the_formula(case,
+                                                             monkeypatch):
+    """A chunk whose shapes take the walk in windows (``held_window``: 384
+    rows a trip here) gives what the one pass over all 2,048 rows gives,
+    with the same ``load`` bit for bit, and what the plain formula gives: no
+    held row is lost where the held rows fill several windows or end on a
+    window's edge, none is invented where none is held or where padding
+    would have been."""
+    from mxtpu.ops import nn
+    from mxtpu.parallel import moe
+    x, router, bias, held, k, lo, valid, n_held = windowed_layer(case)
+    assert moe.held_window(x.shape[1] * k, 2, 16) == (384, 128)
+    args = (jnp.asarray(x), router, bias) + tuple(held)
+    kw = dict(top_k=k, expert_first=lo, scale=2.5,
+              valid_len=None if valid is None else jnp.asarray([valid]))
+    before = nn.held_window_nodes()
+    with jax.default_matmul_precision("highest"):
+        y, load = nn.moe_ffn_held(*args, **kw)
+        assert nn.held_window_nodes() == before + 1
+        monkeypatch.setattr(moe, "held_window", lambda *shapes: None)
+        y_one, load_one = nn.moe_ffn_held(*args, **kw)
+        assert nn.held_window_nodes() == before + 1
+    want, want_load = plain_held_layer(x, router, bias, held, k, lo, valid,
+                                       2.5)
+    load = np.asarray(load)[0]
+    assert load.tolist() == np.asarray(load_one)[0].tolist()
+    assert load[:3].tolist() == want_load.tolist()
+    if n_held is not None:
+        assert load[:2].sum() == n_held
+    trips = -(-int(load[:2].sum()) // 384)
+    assert trips == {"none held": 0, "all on one held expert": 6,
+                     "twice the window": 2, "one over twice the window": 3,
+                     "an expert across two windows": 2,
+                     "padding that would have been held": 1}.get(case, trips)
+    if case == "an expert across two windows":
+        assert load[:2].tolist() == [300, 300]
+    np.testing.assert_allclose(np.asarray(y), np.asarray(y_one), atol=1e-5,
+                               rtol=1e-5)
+    np.testing.assert_allclose(np.asarray(y)[0], want, atol=2e-5, rtol=2e-5)
+    if valid is not None:
+        assert not np.asarray(y)[0, valid:].any()
+        assert np.abs(np.asarray(y)[0, :valid]).min(axis=1).max() > 0
+    if case == "none held":
+        assert not np.asarray(y).any()
 
 
 def test_every_token_on_one_expert_and_none_is_lost():

@@ -424,3 +424,48 @@ def test_latent_prefill_kernel_compiles_at_other_buckets(v5e, T):
         lambda q, k, v, n: latent_prefill_attention(q, k, v, 0, n, 32, 0.19497),
         *[S_((1, T, 32 * 128), jnp.bfloat16)] * 3, S_((1,), jnp.int32))
     assert "tpu_custom_call" in text
+
+
+# -- the expert layer of a prefill chunk (mistral4-longdoc-saturated's largest bucket) --
+
+def _expert_layer(v5e, n, k, d, f, held, wide):
+    from mxtpu.ops.nn import moe_ffn_held
+    S_ = functools.partial(jax.ShapeDtypeStruct, sharding=v5e)
+    bf = jnp.bfloat16
+    return jax.jit(
+        lambda x, r, b, g, u, w: moe_ffn_held(x, r, b, g, u, w, top_k=k,
+                                              scoring="softmax")).trace(
+        S_((1, n, d), bf), S_((wide, d), bf), S_((wide,), jnp.float32),
+        S_((held, d, f), bf), S_((held, d, f), bf), S_((held, f, d), bf)
+    ).lower(lowering_platforms=("tpu",)).compile()
+
+
+def test_a_chunks_expert_layer_walks_its_held_rows_in_windows(v5e,
+                                                              monkeypatch):
+    """The expert layer at Mistral-Small-4's 8,192 bucket (32,768
+    assignments, 4,096 wide, experts of 2,048, 16 of 128 held): the three
+    grouped products sit inside a ``while`` of traced length over windows
+    of 5,120 rows, and the program's temporaries are under the one pass's,
+    which holds ``[32768, 4096]`` in float32 more than once."""
+    from mxtpu.ops.nn import held_window_nodes
+    from mxtpu.parallel import moe
+    shapes = (8192, 4, 4096, 2048, 16, 128)
+    assert moe.held_window(32768, 16, 128) == (5120, 256)
+    # a decode step of the same layer (96 slots) keeps the one pass
+    assert moe.held_window(96 * 4, 16, 128) is None
+    before = held_window_nodes()
+    walked = _expert_layer(v5e, *shapes)
+    assert held_window_nodes() == before + 1
+    text = walked.as_text()
+    kernels = re.findall(r"%gmm\S* = [^\n]*custom-call\([^\n]*"
+                         r"op_name=\"([^\"]*)/pallas_call\"", text)
+    assert len(kernels) == 3 and all("/while/body/" in k for k in kernels)
+    for m in re.finditer(r"= f32\[([\d,]+)\]", text):
+        dims = [int(n) for n in m.group(1).split(",")]
+        assert functools.reduce(int.__mul__, dims) <= 8192 * 4096, m.group(0)
+    monkeypatch.setattr(moe, "held_window", lambda *shapes: None)
+    one_pass = _expert_layer(v5e, *shapes)
+    assert held_window_nodes() == before + 1
+    assert (walked.memory_analysis().temp_size_in_bytes
+            < one_pass.memory_analysis().temp_size_in_bytes)
+    assert "f32[32768,4096]" in one_pass.as_text()

@@ -280,6 +280,8 @@ def test_counters_say_which_path_each_program_took(served):
     assert st["gen_decode_latent_blockwise"] == 0
     assert st["gen_prefill_latent_path"] == 0
     assert st["gen_prefill_latent_blockwise"] == 2 * layers
+    # chunks of 16 and 64 assignments, steps of 8: one pass (held_window)
+    assert st["gen_prefill_moe_window"] == st["gen_decode_moe_window"] == 0
     snap = obs.REGISTRY.snapshot()["metrics"]
     assert snap["ops.latent_attention.chunk_blockwise"]["series"]
     # the process's own counters outlive a stopped scheduler's series
@@ -289,6 +291,34 @@ def test_counters_say_which_path_each_program_took(served):
     assert stats["prefills"] == 6
     assert stats["prefill_rows"] == sum(len(p) for p in prompts) == 73
     assert stats["prefill_rows_padded"] == 3 * 8 + 3 * 32
+
+
+def test_a_prefill_that_walks_its_held_rows_in_windows(served, tiny_windows):
+    """With the rule at these widths' scale the bucket of 32 rows (64
+    assignments, 4 of 16 experts held: windows of 24 rows) walks its held
+    rows in every layer and the decode program (8 assignments) in none; the
+    prefill's first token and its expert counts are the one pass's."""
+    from benchmarks.models import mistral4 as model
+    from mxtpu.serving import InferenceEngine
+    cfg, weights, one_pass, prompts, tokens, _stats = served
+    engine = InferenceEngine(model.symbol(cfg), dict(weights), {},
+                             {"data": (1,)}, buckets=(1,), dtype="float32",
+                             warm=False)
+    first, rows = engine.gen_prefill(prompts[5], engine._param_vals,
+                                     engine._aux_vals)
+    engine.gen_decode_program(4)
+    st = engine.stats()
+    assert st["gen_prefill_moe_window"] == cfg["num_hidden_layers"]
+    assert st["gen_decode_moe_window"] == 0
+    assert st["gen_prefill_latent_blockwise"] == cfg["num_hidden_layers"]
+    assert int(np.asarray(first)[0]) == int(tokens[5][0])
+    _first, want = one_pass.gen_prefill(prompts[5], one_pass._param_vals,
+                                        one_pass._aux_vals)
+    sums = one_pass._gen["sum_states"]
+    assert len(sums) == cfg["num_hidden_layers"]
+    for i in sums:
+        assert np.asarray(rows[i]).tolist() == np.asarray(want[i]).tolist()
+        assert 0 < np.asarray(rows[i])[0, :-5].sum() < 29 * 2
 
 
 def test_eight_shares_add_up_to_the_uncut_layer():

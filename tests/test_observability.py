@@ -290,7 +290,10 @@ def test_kv_stats_keys_unchanged_and_registry_backed():
         snap = obs.REGISTRY.snapshot()
         fam = snap["metrics"]["kv.client.local_reqs"]["series"]
         assert fam, "the store's comms series must be registered"
-        assert kv.stats()["local_reqs"] >= max(fam.values())
+        # this store's series is the newest instance's: a store that an
+        # earlier test of this worker left open keeps a series of its own
+        own = fam["c%d" % max(int(inst[1:]) for inst in fam)]
+        assert kv.stats()["local_reqs"] >= own > 0
         assert snap["metrics"]["kv.server.pushes"]["series"]
     finally:
         kv.close()

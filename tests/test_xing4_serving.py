@@ -221,6 +221,28 @@ def test_a_layer_keeps_one_full_state_of_the_rows_width(served):
     assert by_kind["sum"] == 2 * (8 + 5) * 4
 
 
+def test_a_layer_that_holds_every_expert_makes_one_pass(served, tiny_windows):
+    """Every expert is held, so ``n_held = N k`` and a walk in windows would
+    be the same work in more calls: with the rule brought down to these
+    widths' scale (where a model that holds a share walks its chunks of 32
+    rows in windows) no program of this model takes it."""
+    from benchmarks.models import xing4_0 as model
+    from mxtpu.parallel.moe import held_window
+    from mxtpu.serving import InferenceEngine
+    cfg, weights, _engine, prompts, tokens = served
+    assert held_window(64, 2, 8) is not None and held_window(64, 8, 8) is None
+    engine = InferenceEngine(model.symbol(cfg), dict(weights), {},
+                             {"data": (1,)}, buckets=(1,), dtype="float32",
+                             warm=False)
+    first, _rows = engine.gen_prefill(prompts[5], engine._param_vals,
+                                      engine._aux_vals)
+    engine.gen_decode_program(4)
+    st = engine.stats()
+    assert st["gen_prefill_moe_window"] == st["gen_decode_moe_window"] == 0
+    assert st["gen_prefill_hyper_mix"] == 2 * cfg["num_hidden_layers"]
+    assert int(np.asarray(first)[0]) == int(tokens[5][0])
+
+
 def test_counters_say_which_nodes_took_the_kernels(served):
     """The decode program: every layer's attention on the latent kernel
     (which takes its row through the row-write kernel), two stream mixings
@@ -235,6 +257,7 @@ def test_counters_say_which_nodes_took_the_kernels(served):
     assert st["gen_prefill_latent_path"] == 0
     assert st["gen_prefill_hyper_mix"] == 2 * 2 * layers
     assert st["gen_decode_attn_path"] == st["gen_decode_row_write"] == 0
+    assert st["gen_prefill_moe_window"] == st["gen_decode_moe_window"] == 0
     snap = obs.REGISTRY.snapshot()["metrics"]
     assert snap["ops.latent_attention.decode_path"]["series"]
     assert snap["ops.hyper_mix.nodes"]["series"]
