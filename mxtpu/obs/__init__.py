@@ -35,9 +35,9 @@ from __future__ import annotations
 from .metrics import (REGISTRY, Counter, Gauge, Histogram,  # noqa: F401
                       Registry, counter, gauge, histogram, view,
                       max_series)
-from .trace import (Sampler, active_ctx, adopt, dump_process_trace,  # noqa: F401
-                    merge_traces, sample_rate, span, start_trace,
-                    end_trace, trace_dir, wire_ctx)
+from .trace import (Sampler, active_ctx, adopt, device_run,  # noqa: F401
+                    dump_process_trace, merge_traces, sample_rate, span,
+                    start_trace, end_trace, trace_dir, wire_ctx)
 from .telemetry import (TelemetryAggregator, TelemetryExporter,  # noqa: F401
                         ensure_exporter, telemetry_enabled,
                         telemetry_dir)
@@ -45,7 +45,7 @@ from .telemetry import (TelemetryAggregator, TelemetryExporter,  # noqa: F401
 __all__ = [
     "REGISTRY", "Registry", "Counter", "Gauge", "Histogram",
     "counter", "gauge", "histogram", "view", "max_series",
-    "Sampler", "span", "adopt", "active_ctx", "wire_ctx",
+    "Sampler", "span", "device_run", "adopt", "active_ctx", "wire_ctx",
     "start_trace", "end_trace", "sample_rate", "trace_dir",
     "dump_process_trace", "merge_traces",
     "TelemetryExporter", "TelemetryAggregator", "ensure_exporter",

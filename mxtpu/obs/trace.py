@@ -31,6 +31,12 @@ enclosing span on that thread, and no flow pair is written (nothing
 crosses a process). With neither, a span is one thread-local read and
 one static call that finds nothing.
 
+:func:`device_run` adds the device's side on the same terms: the
+serving engine hands it one output of each run it dispatches, and a
+watcher thread stamps when the run ends, so
+the device's busy and idle time over a whole session lies beside the
+scheduler's spans without the profiler's device plane.
+
 Timestamps are the profiler's: ``perf_counter`` carried to **epoch
 microseconds** by an offset taken once per process
 (``mxtpu.profiler.EPOCH_OFFSET_US``). One process's events are
@@ -52,6 +58,7 @@ import atexit
 import glob
 import json
 import os
+import queue as _queue
 import threading
 import time
 import uuid
@@ -62,7 +69,7 @@ from .. import profiler as _profiler
 from . import metrics as _metrics
 
 __all__ = ["Sampler", "sample_rate", "trace_dir", "start_trace",
-           "active_ctx", "wire_ctx", "adopt", "span",
+           "active_ctx", "wire_ctx", "adopt", "span", "device_run",
            "dump_process_trace", "merge_traces"]
 
 _tls = threading.local()
@@ -324,6 +331,72 @@ def _land(name, t0, t1, trace_id, sid, parent, extra):
         ))
     _spans_recorded.inc(1)
     _maybe_autodump()
+
+
+_device_queue = None       # the watcher's, made with its thread by the first run
+_device_guard = threading.Lock()
+_device_unseen = False     # a run went out unrecorded since the last recorded
+
+
+def device_run(name, out, **args):
+    """``device_run("serve.engine.device.decode", nxt, slots=K)`` right after
+    a program is dispatched: when the run ends on the device, on the spans'
+    clock, for a whole-window device timeline that needs no profiler plane
+    (ISSUE 38).
+
+    ``out`` is one output of the run that nothing donates, or ``None`` for
+    a run whose outputs are all donated into the next (a mark: it lands no
+    event of its own, and the run behind it says ``after`` its kind). One
+    daemon watcher, started by the first recorded run, takes them in
+    dispatch order, waits for each output to be ready and stamps the clock.
+    A run lasts from ``max(enqueued, the previous run's end)`` to that
+    stamp and lands as a span does, but on the watcher's thread and with no
+    annotation (it is known only after the fact), with ``after`` (the last
+    part of the name of what went out before it, ``none`` where unknown)
+    and ``idle_us`` (how long the device had nothing of the runs' queued
+    before it; a mark's run counts there, or inside the run behind it
+    where it still ran when that run went out). It records under the
+    condition a span does; with neither a session nor a sampled context it
+    is one check a dispatch, no thread and no reference. Neither thread
+    ever waits for the other, and the watcher reads no value back."""
+    global _device_queue, _device_unseen
+    if _recording() is None:
+        if _device_queue is not None:
+            _device_unseen = True
+        return
+    item = (name, out, args, _profiler._now_us(), _device_unseen)
+    _device_unseen = False
+    if _device_queue is None:
+        with _device_guard:
+            if _device_queue is None:
+                q = _queue.SimpleQueue()
+                threading.Thread(target=_watch, args=(q,), daemon=True,
+                                 name="mxtpu-obs-device-timeline").start()
+                _device_queue = q
+    _device_queue.put(item)
+
+
+def _watch(q):
+    end = after = None         # the last run's end; what went out last
+    while True:
+        name, out, args, enqueued, unseen = q.get()   # mxlint: allow(blocking-call) — the daemon watcher idles here between runs
+        if unseen:             # the device ran what nobody saw
+            end = after = None
+        kind = name.rpartition(".")[2]
+        if out is None:
+            after = kind
+            continue
+        try:
+            out.block_until_ready()
+        except Exception:      # a failed run: the scheduler reports it
+            out = end = after = None
+            continue
+        stamp, out = _profiler._now_us(), None
+        idle = 0.0 if end is None else max(0.0, enqueued - end)
+        _land(name, enqueued if end is None else max(enqueued, end),
+              stamp, None, _new_id(), None,
+              dict(args, after=after or "none", idle_us=round(idle, 1)))
+        end, after = stamp, kind
 
 
 _dumper_started = [False]

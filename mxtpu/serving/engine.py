@@ -70,6 +70,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from .. import obs as _obs
 from ..base import canonical_dtype
 from ..checkpoint import weight_digest
 from ..context import current_context
@@ -1208,6 +1209,7 @@ class InferenceEngine:
             jax.device_put(padded, dev),
             jax.device_put(_np.asarray([plen], _np.int32), dev),
             param_vals, aux_vals)
+        _obs.device_run("serve.engine.device.prefill", first, rows=L)
         self._note("gen_prefills")
         return first, rows
 
@@ -1221,6 +1223,7 @@ class InferenceEngine:
         nxt, tok_feed, pos, new_states, self._gen_sums = program(
             state[0], state[1], state[2], param_vals, aux_vals,
             self._sums())
+        _obs.device_run("serve.engine.device.decode", nxt, slots=K)
         self._note("gen_steps")
         return nxt, [tok_feed, pos, new_states]
 
@@ -1237,6 +1240,8 @@ class InferenceEngine:
             _np.asarray([plen], _np.int32),
             tuple(rows[i] for i in g["slot_states"]), _np.int32(slot),
             self._sums(), tuple(rows[i] for i in g["sum_states"]))
+        # every output is donated into the next run: a mark, no array
+        _obs.device_run("serve.engine.device.adopt", None)
         return [tok_feed, pos, new_states]
 
     # -- prewarm: export/import the AOT program menu (ISSUE 16) --------

@@ -265,8 +265,9 @@ def test_generate_scheduler_spans(tmp_path, monkeypatch):
             sorted("r%d" % i for i in range(6)), name
         assert all(e["args"]["parent"] in admits for e in got), name
     assert all(int(e["args"]["queued"]) >= 1 for e in admits.values())
-    # one thread, one trace
-    assert len({e["tid"] for e in spans()}) == 1
+    # one thread, one trace (the engine's device runs land on a watcher's)
+    assert len({e["tid"] for e in spans()
+                if e["name"].startswith("serve.gen.")}) == 1
 
 
 def _mlp():
